@@ -55,8 +55,8 @@ def main():
     from raft_stereo_tpu.profiling import chained_seconds_per_call
     from raft_stereo_tpu.telemetry.events import bench_record
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
 
     # Shared versioned run header (telemetry/events.py); the per-(backend,
     # size) lines below are rows under it.
@@ -137,17 +137,8 @@ def main():
                     compiled = chain.lower(variables, img1, img2,
                                            1).compile()
                 ma = compiled.memory_analysis()
-                # peak_memory_in_bytes is TPU-backend; CPU builds of
-                # some jax versions expose only the size fields — fall
-                # back to their sum so the per-device comparison stays
-                # measurable everywhere.
-                peak = getattr(ma, "peak_memory_in_bytes", None)
-                if peak is None:
-                    peak = (ma.temp_size_in_bytes
-                            + ma.argument_size_in_bytes
-                            + ma.output_size_in_bytes)
-                    rec["hbm_is_live_sum"] = True
-                rec["peak_hbm_gib"] = round(peak / 2 ** 30, 3)
+                rec["peak_hbm_gib"] = round(
+                    ma.peak_memory_in_bytes / 2 ** 30, 3)
                 rec["temp_gib"] = round(ma.temp_size_in_bytes / 2 ** 30, 3)
 
                 def make_chain(k):

@@ -616,8 +616,8 @@ def main():
 
     from raft_stereo_tpu.eval.runner import InferenceRunner
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
 
     on_cpu = jax.devices()[0].platform == "cpu"
     cfg, variables, hw, iters = build_model(on_cpu)

@@ -144,6 +144,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     common.setup_logging()
     args = build_parser().parse_args(argv)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
     results = run_eval(args)
     if args.json:
         print(json.dumps(results))

@@ -642,7 +642,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     common.setup_logging()
-    return run_serve(build_parser().parse_args(argv))
+    args = build_parser().parse_args(argv)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
+    return run_serve(args)
 
 
 if __name__ == "__main__":

@@ -200,6 +200,8 @@ def main(argv=None):
     # a process group) but before any jax device query latches the backend.
     from raft_stereo_tpu.parallel import distributed
     distributed.initialize()
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
     model_cfg, train_cfg = configs_from_args(args)
     log.info("model config: %s", model_cfg.to_dict())
     log.info("train config: %s", train_cfg.to_dict())

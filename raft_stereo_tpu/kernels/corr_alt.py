@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from raft_stereo_tpu.kernels.corr_lookup import (ROW_BLK, VMEM_BUDGET,
-                                                 W1_BLK,
+                                                 W1_BLK, log_launch_choice,
                                                  fused_lookup_available,
                                                  hat_sample, hat_scatter,
                                                  row_blk_for,
@@ -385,8 +385,10 @@ def alt_lookup_fused(fmap1: jnp.ndarray, fmap2_pyramid: List[jnp.ndarray],
     per level (which shrinks row blocks for full-res pyramids)."""
     d = fmap1.shape[-1]
     w2s = [f2.shape[2] for f2 in fmap2_pyramid]
-    if (_multi_alt_scoped_bytes(w2s, d, fmap1.dtype.itemsize, radius)
-            <= _MOSAIC_SCOPED_VMEM):
+    single = (_multi_alt_scoped_bytes(w2s, d, fmap1.dtype.itemsize, radius)
+              <= _MOSAIC_SCOPED_VMEM)
+    log_launch_choice(f"alt lookup D={d}", w2s, fmap1.dtype, single)
+    if single:
         static = (radius,
                   tuple(int(sum(w2s[:i])) for i in range(len(w2s))),
                   tuple(int(w) for w in w2s))
@@ -458,8 +460,10 @@ def alt_lookup_fused_q(fmap1_q: jnp.ndarray,
     w2s = [f2.shape[2] for f2 in fmap2_pyramid_q]
     rows = b * h
     inv_sqrt_d = 1.0 / math.sqrt(d)
-    if (_multi_alt_scoped_bytes(w2s, d, fmap1_q.dtype.itemsize, radius)
-            <= _MOSAIC_SCOPED_VMEM):
+    single = (_multi_alt_scoped_bytes(w2s, d, fmap1_q.dtype.itemsize,
+                                      radius) <= _MOSAIC_SCOPED_VMEM)
+    log_launch_choice(f"quantized alt lookup D={d}", w2s, fmap1_q.dtype, single)
+    if single:
         offsets = tuple(int(sum(w2s[:i])) for i in range(len(w2s)))
         widths = tuple(int(w) for w in w2s)
         f2cat = jnp.concatenate(fmap2_pyramid_q, axis=2)

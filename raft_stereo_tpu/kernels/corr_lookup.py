@@ -26,12 +26,15 @@ produced — RAFT-Stereo detaches coords before every lookup
 from __future__ import annotations
 
 import functools
+import logging
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+log = logging.getLogger(__name__)
 
 ROW_BLK = 8       # (batch·H) rows per tile
 W1_BLK = 128      # output pixels per tile (lane-aligned)
@@ -66,6 +69,24 @@ def _lookup_row_bytes(w2: int, radius: int, itemsize: int) -> int:
 _interpret_override: Optional[bool] = None
 
 
+@functools.lru_cache(maxsize=None)
+def log_path_once(message: str) -> None:
+    """Trace-time record of a shape-driven choice between a kernel and its
+    fallback (or between two launch plans), shared by every kernel family
+    of the package.  The cache is the once-per-distinct-message rule: a
+    choice is made per traced shape, not per call."""
+    log.info("kernel path: %s", message)
+
+
+def log_launch_choice(what: str, w2s, dtype, single: bool) -> None:
+    log_path_once(
+        f"{what} W2={'/'.join(str(w) for w in w2s)} "
+        f"{jnp.dtype(dtype).name}: "
+        + ("single all-levels launch" if single else
+           "one launch per level (the all-levels working set exceeds "
+           "the VMEM budget)"))
+
+
 def fused_lookup_available() -> bool:
     if _interpret_override:  # interpret mode works on any backend
         return True
@@ -88,13 +109,13 @@ _interpret = interpret_enabled  # internal alias
 # estimators below are already itemsize-parameterized, so every budget
 # holds unchanged), but a FLOAT grid — denser near zero where the
 # post-softargmax correlation mass lives.  Availability is a separate
-# capability from the fused kernels themselves: the dtype must exist in
-# this jax build AND the backend must execute it (interpret mode counts
-# — CPU parity tests run the same kernel body through the interpreter).
+# capability from the fused kernels themselves: the backend must execute
+# the dtype (interpret mode counts — CPU parity tests run the same kernel
+# body through the interpreter).
 # The grid is OCP E4M3 (``float8_e4m3fn``: finite-only, max 448 — the
 # variant TPU/GPU fp8 units implement), not the IEEE ``float8_e4m3``
 # whose 240 finite max would overflow the 448-referenced scales.
-FP8_CORR_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+FP8_CORR_DTYPE = jnp.float8_e4m3fn
 
 
 def fp8_corr_available() -> bool:
@@ -102,21 +123,11 @@ def fp8_corr_available() -> bool:
     building an fp8 pyramid (models/corr.corr_q_dtype falls back to
     int8 when this is False — same transparent-fallback contract as
     fused_lookup_available)."""
-    if FP8_CORR_DTYPE is None:  # pragma: no cover - all jax>=0.4.31
-        return False
-    if _interpret_override:
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return fused_lookup_available()
 
 
 def _q_dtypes_supported():
-    out = [jnp.dtype(jnp.int8)]
-    if FP8_CORR_DTYPE is not None:
-        out.append(jnp.dtype(FP8_CORR_DTYPE))
-    return tuple(out)
+    return (jnp.dtype(jnp.int8), jnp.dtype(FP8_CORR_DTYPE))
 
 
 def check_q_dtype(pyramid, q_dtype):
@@ -133,9 +144,7 @@ def check_q_dtype(pyramid, q_dtype):
     if bad:
         raise ValueError(
             f"q-entry levels must all be {q_dtype}; got {bad}")
-    if (FP8_CORR_DTYPE is not None
-            and q_dtype == jnp.dtype(FP8_CORR_DTYPE)
-            and not fp8_corr_available()):
+    if q_dtype == jnp.dtype(FP8_CORR_DTYPE) and not fp8_corr_available():
         raise ValueError(
             "fp8 correlation entries are unavailable on this backend "
             "(fp8_corr_available() is False) — quantize int8 instead")
@@ -372,13 +381,52 @@ def _sample_pyramid_fwd(vols, coords, radius):
     return _sample_pyramid(vols, coords, radius), (vols, coords)
 
 
+def _multi_bwd_scoped_bytes(w2s, radius: int, itemsize: int) -> int:
+    """Mosaic's scoped-VMEM allocation for one ``_bwd_kernel_multi``
+    program, calibrated against the v5e compiler (the forward estimator
+    ``_multi_working_set`` says nothing about it — the backward WRITES a
+    tile per level and they are all live at once, the unrolled level loop
+    shares no buffers).  Lanes pad to 128; per padded bin the program
+    holds four fp32 temporaries (hat field, its shifted slice, the
+    product, the accumulator) plus the double-buffered output tile; the
+    cotangent and coordinate blocks are double-buffered too.  Calibration
+    (reported / estimated MiB): fp32 W2 180/90/45/22 16.32 / 17.0, fp32
+    312/156/78/39 22.42 / 23.0, bf16 312/156/78/39 18.67 / 19.0."""
+    fp32 = 4
+    tile = ROW_BLK * W1_BLK
+
+    def padded(n: int, lanes: int = 128) -> int:
+        return -(-n // lanes) * lanes
+
+    bins = sum(padded(w2) for w2 in w2s)
+    taps = padded(len(w2s) * (2 * radius + 1))
+    return (tile * bins * (4 * fp32 + 2 * itemsize)
+            + 2 * tile * (taps * itemsize + padded(1) * fp32))
+
+
 def _sample_pyramid_bwd(radius, residuals, g):
     vols, coords = residuals
     b, h, w1, _ = vols[0].shape
-    dvols = _launch_bwd_multi(coords.reshape(b * h, w1),
-                              g.reshape(b * h, w1, -1),
-                              [v.shape[-1] for v in vols], radius,
-                              vols[0].dtype)
+    w2s = [v.shape[-1] for v in vols]
+    dtype = vols[0].dtype
+    coords2 = coords.reshape(b * h, w1)
+    g2 = g.reshape(b * h, w1, -1)
+    # Mosaic's scoped-VMEM limit is twice the package's per-program
+    # budget (the other half is the pipeline's double buffering).  The
+    # all-levels backward outgrows it before the forward does (fp32 at
+    # the SceneFlow crop: 16.32 MiB), so it falls to one launch per
+    # level — whose row block shrinks to fit — on its own; the forward
+    # keeps its single launch either way.
+    single = (_multi_bwd_scoped_bytes(w2s, radius, dtype.itemsize)
+              <= 2 * VMEM_BUDGET)
+    log_launch_choice("lookup backward", w2s, dtype, single)
+    if single:
+        dvols = _launch_bwd_multi(coords2, g2, w2s, radius, dtype)
+    else:
+        k = 2 * radius + 1
+        dvols = [_launch_bwd(coords2, g2[:, :, i * k:(i + 1) * k], w2,
+                             radius, 1.0 / (2 ** i), dtype)
+                 for i, w2 in enumerate(w2s)]
     return (tuple(d.reshape(b, h, w1, -1) for d in dvols),
             jnp.zeros_like(coords))
 
@@ -412,8 +460,10 @@ def lookup_pyramid_fused(pyramid: List[jnp.ndarray], coords: jnp.ndarray,
     (full-resolution volumes grow ~linearly in W2 and must not turn a
     previously-working eval into a Mosaic VMEM compile failure)."""
     w2s = [v.shape[-1] for v in pyramid]
-    if (len(pyramid) > 1 and _multi_working_set(
-            w2s, radius, pyramid[0].dtype.itemsize) <= VMEM_BUDGET):
+    single = (len(pyramid) > 1 and _multi_working_set(
+        w2s, radius, pyramid[0].dtype.itemsize) <= VMEM_BUDGET)
+    log_launch_choice("lookup", w2s, pyramid[0].dtype, single)
+    if single:
         return _sample_pyramid(tuple(pyramid), coords, radius)
     outs = [_sample_level(vol, coords, radius, 1.0 / (2 ** i))
             for i, vol in enumerate(pyramid)]
@@ -448,8 +498,10 @@ def lookup_pyramid_fused_q(pyramid: List[jnp.ndarray],
     check_q_dtype(pyramid, q_dtype)
     b, h, w1, _ = pyramid[0].shape
     w2s = [v.shape[-1] for v in pyramid]
-    if (len(pyramid) > 1 and _multi_working_set(
-            w2s, radius, pyramid[0].dtype.itemsize) <= VMEM_BUDGET):
+    single = (len(pyramid) > 1 and _multi_working_set(
+        w2s, radius, pyramid[0].dtype.itemsize) <= VMEM_BUDGET)
+    log_launch_choice("quantized lookup", w2s, pyramid[0].dtype, single)
+    if single:
         out = _launch_fwd_multi(
             [v.reshape(b * h, w1, v.shape[-1]) for v in pyramid],
             coords.reshape(b * h, w1), radius, out_dtype=out_dtype)

@@ -64,7 +64,8 @@ from jax.experimental.pallas import tpu as pltpu
 from raft_stereo_tpu.kernels.corr_alt import _precision_for
 from raft_stereo_tpu.kernels.corr_lookup import (VMEM_BUDGET,
                                                  fused_lookup_available,
-                                                 interpret_enabled)
+                                                 interpret_enabled,
+                                                 log_path_once)
 
 ROW_BLK = 8      # default image rows per program
 # The two-view halo assembly reads the first 4 rows of the NEXT row block,
@@ -87,35 +88,45 @@ def _gates_fixed_bytes(cin: int, ch: int, itemsize: int) -> int:
     return 9 * cin * 3 * ch * itemsize + 3 * ch * fp32
 
 
-def _gates_row_bytes(w: int, cin: int, ch: int, itemsize: int) -> int:
-    """Per-row working set of one program (scaled by the row block): the two
-    halo views of ``[h, x]`` and ``cr``, the fp32 ``zr`` accumulator plus
-    one live tap product, the fp32 r / r*h intermediates, the ``[r*h, x]``
-    tile, the fp32 ``qpre`` accumulator + tap product, and both output
-    blocks."""
+def _gates_block_bytes(rb: int, w: int, cin: int, ch: int,
+                       itemsize: int) -> int:
+    """Working set of one program at row block ``rb``, HALO ROWS INCLUDED:
+    the two ``rb``-row views of ``[h, x]`` and ``cr``, the ``rb+4`` halo
+    rows assembled from them, the fp32 ``zr`` accumulator plus one live
+    tap product on ``rb+2`` rows, the fp32 r / r*h intermediates and the
+    ``[r*h, x]`` tile on the same ring, the fp32 ``qpre`` accumulator +
+    tap product, and both output blocks.  At the minimum block the halo
+    doubles the rows a program holds; an estimate that scaled a per-row
+    figure by ``rb`` alone ran 1.5x under what Mosaic allocates at W=180
+    (7.9 MiB estimated, 11.57 MiB reported) and chose launches the v5e's
+    compiler then refused inside the batch-4 training step.  Against the
+    v5e compiler this one reads 10.27 / 11.57 MiB (W=180, bf16, rb 4),
+    9.25 / 10.01 (W=156), 9.42 / 7.42 (W=90, rb 8), 7.61 / 6.02 (W=78,
+    fp32, rb 4)."""
     fp32 = 4
-    return (2 * (w + 4) * cin * itemsize        # hx views i, i+1
-            + 2 * (w + 2) * ch * itemsize       # cr views i, i+1
-            + 2 * (w + 2) * 2 * ch * fp32       # zr_ext acc + tap product
-            + 2 * (w + 2) * ch * fp32           # r, r*h (fp32)
-            + (w + 2) * cin * itemsize          # [r*h, x] tile
-            + 2 * w * ch * fp32                 # qpre acc + tap product
-            + w * 3 * ch * itemsize)            # zr + qpre output blocks
+    return (2 * rb * (w + 4) * cin * itemsize        # hx views i, i+1
+            + 2 * rb * (w + 2) * ch * itemsize       # cr views i, i+1
+            + (rb + 4) * (w + 4) * cin * itemsize    # assembled halo rows
+            + 2 * (rb + 2) * (w + 2) * 2 * ch * fp32  # zr_ext acc + product
+            + 2 * (rb + 2) * (w + 2) * ch * fp32     # r, r*h (fp32)
+            + (rb + 2) * (w + 2) * cin * itemsize    # [r*h, x] tile
+            + 2 * rb * w * ch * fp32                 # qpre acc + product
+            + rb * w * 3 * ch * itemsize)            # zr + qpre out blocks
 
 
 def gru_fused_row_block(w: int, cin: int, ch: int,
                         itemsize: int) -> Optional[int]:
     """Largest power-of-two row block (<= ROW_BLK, >= 4) whose working set
-    fits ``VMEM_BUDGET``; ``None`` when even rb=4 does not fit (very wide
-    levels — full-res W with no W-blocking) and the caller must fall back."""
+    fits ``VMEM_BUDGET``; ``None`` when even rb=4 does not fit (wide
+    levels — there is no W-blocking) and the caller must fall back."""
     fixed = _gates_fixed_bytes(cin, ch, itemsize)
-    per_row = _gates_row_bytes(w, cin, ch, itemsize)
     rb = ROW_BLK
-    while rb > _MIN_ROW_BLK and fixed + rb * per_row > VMEM_BUDGET:
+    while rb >= _MIN_ROW_BLK:
+        if fixed + _gates_block_bytes(rb, w, cin, ch, itemsize) \
+                <= VMEM_BUDGET:
+            return rb
         rb //= 2
-    if fixed + rb * per_row > VMEM_BUDGET:
-        return None
-    return rb
+    return None
 
 
 def gru_fused_should_use(mode: str, *, kernel_size: int, w: int, cin: int,
@@ -123,7 +134,8 @@ def gru_fused_should_use(mode: str, *, kernel_size: int, w: int, cin: int,
     """Dispatch decision for one GRU level at trace time.
 
     ``auto``: use the kernel iff the backend supports it AND the level's
-    working set fits VMEM — silent fallback otherwise (no workload breaks).
+    working set fits VMEM — the Flax path otherwise (no workload breaks),
+    with the choice and its reason logged once per level shape.
     ``on``: force the kernel; raise with the specific reason when it cannot
     run (explicit user intent should not silently degrade).
     ``off``: never (bitwise-preserves the Flax graph)."""
@@ -146,7 +158,19 @@ def gru_fused_should_use(mode: str, *, kernel_size: int, w: int, cin: int,
                 f" Ch={ch}) exceeds the VMEM budget even at the minimum row "
                 "block; use 'auto' for transparent fallback")
         return True
-    return available and rb is not None
+    use = available and rb is not None
+    shape = (f"ConvGRU level W={w} Cin={cin} Ch={ch} "
+             f"{8 * itemsize}-bit")
+    if use:
+        log_path_once(f"{shape}: kernel (row block {rb})")
+    elif not available:
+        log_path_once(f"{shape}: flax (no Pallas backend here, or "
+                      f"kernel_size {kernel_size} != 3)")
+    else:
+        log_path_once(f"{shape}: flax (the working set exceeds the VMEM "
+                      f"budget even at the minimum row block "
+                      f"{_MIN_ROW_BLK}; the kernel does not block along W)")
+    return use
 
 
 # ------------------------------------------------------------------- kernel

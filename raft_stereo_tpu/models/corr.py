@@ -42,6 +42,14 @@ from raft_stereo_tpu.ops.sampler import (linear_sampler_1d,
 CorrFn = Callable[[jnp.ndarray], jnp.ndarray]
 
 
+def over_data_axis(fn, batched):
+    """parallel/data_sharded.over_data_axis, imported at call time
+    (``parallel`` imports this module)."""
+    from raft_stereo_tpu.parallel import data_sharded
+
+    return data_sharded.over_data_axis(fn, batched)
+
+
 # ------------------------------------------------------------ int8 pyramid
 def corr_quant_enabled(cfg: RaftStereoConfig) -> bool:
     """Whether this config stores the correlation pyramid int8
@@ -57,13 +65,17 @@ def corr_q_dtype(cfg: RaftStereoConfig):
     """The quantized correlation grid this trace uses: ``float8_e4m3``
     when the config asks for it AND the backend can run it
     (``fp8_corr_available`` — TPU or kernel-interpret mode), else
-    ``int8``.  The capability fallback is transparent by design: a
-    config with ``quant_corr_fp8=True`` compiles everywhere."""
+    ``int8``.  A config with ``quant_corr_fp8=True`` compiles
+    everywhere; the downgrade is logged once."""
     from raft_stereo_tpu.kernels.corr_lookup import (FP8_CORR_DTYPE,
-                                                     fp8_corr_available)
+                                                     fp8_corr_available,
+                                                     log_path_once)
 
-    if cfg.quant_corr_fp8 and fp8_corr_available():
-        return FP8_CORR_DTYPE
+    if cfg.quant_corr_fp8:
+        if fp8_corr_available():
+            return FP8_CORR_DTYPE
+        log_path_once("quant_corr_fp8 asked for float8_e4m3fn correlation "
+                      "entries but no backend here runs them: int8 grid")
     return jnp.int8
 
 
@@ -203,6 +215,12 @@ def make_corr_fn_alt(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
     use_fused = (alt_fused_available()
                  and alt_fused_fits(fmap2.shape[2], fmap1.shape[-1],
                                     fmap1.dtype.itemsize, cfg.corr_radius))
+    if alt_fused_available() and not use_fused:
+        from raft_stereo_tpu.kernels.corr_lookup import log_path_once
+        log_path_once(
+            f"alt lookup W2={fmap2.shape[2]} D={fmap1.shape[-1]} "
+            f"{fmap1.dtype.name}: XLA sampler (one row of the kernel's "
+            f"backward tile exceeds the VMEM budget)")
     if not use_fused:
         # XLA fallback runs in fp32 like the reference's alt backend
         # (core/raft_stereo.py:95 forces fp32 for it).
@@ -251,10 +269,11 @@ def make_corr_fn_alt(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
                 [s1 * s2 for s2 in s2s], cfg.corr_radius)
 
             def corr_fn(coords):
-                raw = alt_lookup_fused_q(f1_q, f2_qs, coords,
-                                         cfg.corr_radius,
-                                         out_dtype=jnp.float32,
-                                         q_dtype=q_dtype)
+                raw = over_data_axis(
+                    lambda f1, f2s, c: alt_lookup_fused_q(
+                        f1, f2s, c, cfg.corr_radius, out_dtype=jnp.float32,
+                        q_dtype=q_dtype),
+                    (f1_q, f2_qs, coords))
                 return (raw * scale_vec).astype(compute_dtype)
             return corr_fn
         fmap1 = (f1_q.astype(jnp.float32) * s1)
@@ -262,8 +281,10 @@ def make_corr_fn_alt(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
                          for q, s in zip(f2_qs, s2s)]
     elif use_fused:
         def corr_fn(coords):
-            return alt_lookup_fused(fmap1, fmap2_pyramid, coords,
-                                    cfg.corr_radius)
+            return over_data_axis(
+                lambda f1, f2s, c: alt_lookup_fused(f1, f2s, c,
+                                                    cfg.corr_radius),
+                (fmap1, fmap2_pyramid, coords))
         return corr_fn
 
     def corr_fn(coords):
@@ -306,10 +327,11 @@ def make_corr_fn_reg_fused(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
             scale_vec = _tap_scale_vector(scales, cfg.corr_radius)
 
             def corr_fn(coords):
-                raw = lookup_pyramid_fused_q(pyramid_q, coords,
-                                             cfg.corr_radius,
-                                             out_dtype=jnp.float32,
-                                             q_dtype=corr_q_dtype(cfg))
+                raw = over_data_axis(
+                    lambda pyr, c: lookup_pyramid_fused_q(
+                        pyr, c, cfg.corr_radius, out_dtype=jnp.float32,
+                        q_dtype=corr_q_dtype(cfg)),
+                    (pyramid_q, coords))
                 return (raw * scale_vec).astype(compute_dtype)
         else:
             pyramid = _dequantize_levels(pyramid_q, scales, compute_dtype)
@@ -324,7 +346,10 @@ def make_corr_fn_reg_fused(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
         cfg.corr_levels)
     if fused_lookup_available():
         def corr_fn(coords):
-            return lookup_pyramid_fused(pyramid, coords, cfg.corr_radius)
+            return over_data_axis(
+                lambda pyr, c: lookup_pyramid_fused(pyr, c,
+                                                    cfg.corr_radius),
+                (pyramid, coords))
     else:
         def corr_fn(coords):
             return lookup_pyramid_xla(pyramid, coords, cfg.corr_radius)

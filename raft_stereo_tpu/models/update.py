@@ -100,7 +100,9 @@ class ConvGRU(nn.Module):
             cin = h.shape[-1] + x.shape[-1]
             wzr, bzr = _GateConvParams(2 * hd, cin, k, name="convzr")()
             wq, bq = _GateConvParams(hd, cin, k, name="convq")()
-            zr, qpre = gru_gates_fused(h, x, cr, wzr, bzr, wq, bq)
+            from raft_stereo_tpu.parallel.data_sharded import over_data_axis
+            zr, qpre = over_data_axis(gru_gates_fused, (h, x, cr),
+                                      (wzr, bzr, wq, bq))
             # Same remat tags at the same sites as the Flax branch below —
             # tests/test_remat_names.py pins that every config.remat_save
             # name survives in the traced graph on both paths.

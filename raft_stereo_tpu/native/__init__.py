@@ -50,7 +50,8 @@ def _build() -> bool:
         os.replace(tmp, _SO)
         return True
     except (OSError, subprocess.SubprocessError) as e:
-        log.info("native decoder build failed (%s); using Python readers", e)
+        log.warning("native decoder build failed (%s); using the (slower) "
+                    "Python readers", e)
         try:
             os.unlink(tmp)
         except OSError:
@@ -69,8 +70,8 @@ def _load() -> Optional[ctypes.CDLL]:
                 os.path.exists(_SRC)
                 and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
             if not os.path.exists(_SRC):
-                log.info("native decoder source missing at %s; "
-                         "using Python readers", _SRC)
+                log.warning("native decoder source missing at %s; using "
+                            "the (slower) Python readers", _SRC)
                 _build_failed = True
                 return None
             if not _build():
@@ -79,7 +80,8 @@ def _load() -> Optional[ctypes.CDLL]:
         try:
             lib = ctypes.CDLL(_SO)
         except OSError as e:
-            log.info("native decoder load failed (%s)", e)
+            log.warning("native decoder load failed (%s); using the "
+                        "(slower) Python readers", e)
             _build_failed = True
             return None
         lib.pfm_dims.argtypes = [ctypes.c_char_p, _i64, _i64p, _i64p, _i64p]

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from raft_stereo_tpu.config import RaftStereoConfig
-from raft_stereo_tpu.parallel import compat
 from raft_stereo_tpu.models.corr import (_window_coords, build_corr_volume,
                                          pool_last_axis)
 from raft_stereo_tpu.ops.sampler import linear_sampler_1d
@@ -131,7 +130,7 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
 
     # Manual only over ``corr``; the batch axis stays automatic so the outer
     # jit's data-parallel sharding (or a batch of 1 at init) passes through.
-    pyramid = compat.shard_map(
+    pyramid = jax.shard_map(
         build_local, mesh=mesh, axis_names={CORR_AXIS},
         in_specs=(P(), P(None, None, CORR_AXIS, None)),
         out_specs=tuple(P(None, None, None, CORR_AXIS)
@@ -178,7 +177,7 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
                                                 radius)
             return lax.psum(out.astype(jnp.float32), CORR_AXIS)
 
-        lookup = compat.shard_map(
+        lookup = jax.shard_map(
             lookup_local, mesh=mesh, axis_names=set(mesh.axis_names),
             in_specs=(tuple(P(bspec, None, None, CORR_AXIS)
                             for _ in range(num_levels)), P(bspec)),
@@ -199,7 +198,7 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
             # interpolated window.
             return lax.psum(jnp.concatenate(outs, axis=-1), CORR_AXIS)
 
-        lookup = compat.shard_map(
+        lookup = jax.shard_map(
             lookup_local, mesh=mesh, axis_names={CORR_AXIS},
             in_specs=(tuple(P(None, None, None, CORR_AXIS)
                             for _ in range(num_levels)), P()),

@@ -72,10 +72,7 @@ def initialize(coordinator_address: Optional[str] = None,
     backend on first use) — which is why the single-process guard inspects
     only the environment, never jax state.  Idempotent."""
     global _initialized
-    # jax.distributed.is_initialized landed after 0.4.x; on older jax the
-    # module-level flag is the only (per-process, sufficient) guard.
-    jax_says = getattr(jax.distributed, "is_initialized", lambda: False)
-    if _initialized or jax_says():
+    if _initialized or jax.distributed.is_initialized():
         _initialized = True
         return
     if (coordinator_address is None and num_processes is None

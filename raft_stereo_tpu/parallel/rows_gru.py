@@ -67,7 +67,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from raft_stereo_tpu.config import RaftStereoConfig
-from raft_stereo_tpu.parallel import compat
 from raft_stereo_tpu.ops.grids import coords_grid_x
 from raft_stereo_tpu.ops.resize import _interp_matrix
 from raft_stereo_tpu.ops.upsample import convex_upsample
@@ -260,7 +259,7 @@ def rows_sharded_gru_loop(cfg: RaftStereoConfig, dtype, update_params,
     perm_up = [(j + 1, j) for j in range(n - 1)]   # rows from device i+1
 
     @functools.partial(
-        compat.shard_map, mesh=mesh, axis_names={axis},
+        jax.shard_map, mesh=mesh, axis_names={axis},
         in_specs=(param_specs, rows, rows, net_specs, ctx_specs, rows,
                   P(axis), P(axis), mat_specs),
         out_specs=out_specs)
@@ -339,7 +338,7 @@ def rows_sharded_gru_loop(cfg: RaftStereoConfig, dtype, update_params,
             mask0 = jnp.zeros((b, slab, w_f, cfg.mask_channels), dtype)
             # the scan's step returns a device-varying cropped mask; the
             # constant initial carry must carry the same varying type
-            mask0 = compat.pcast_varying(mask0, axis)
+            mask0 = jax.lax.pcast(mask0, (axis,), to="varying")
             (net_o, disp_o, mask_o), _ = jax.lax.scan(
                 step, (tuple(net_l), disp_l, mask0), None, length=iters)
             flow_up_w = upsample(window(disp_o, 0), window(mask_o, 0))

@@ -41,7 +41,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from raft_stereo_tpu.models.banded import (_HALO, _segment, chan_combine,
                                            masked_moments, trunk_tail)
-from raft_stereo_tpu.parallel import compat
 from raft_stereo_tpu.parallel.mesh import DATA_AXIS
 
 # Halo rows exchanged with each neighbor: must cover the receptive-field
@@ -108,7 +107,7 @@ def rows_sharded_trunk_apply(trunk_params, batch_stats, x, norm_fn, dtype,
     # (parallel/corr_sharded.py) — making this trunk usable inside the
     # data-sharded TRAINING step, not just replicated-batch inference.
     @functools.partial(
-        compat.shard_map, mesh=mesh, axis_names={axis},
+        jax.shard_map, mesh=mesh, axis_names={axis},
         in_specs=(param_specs[0], param_specs[1], P(None, axis)),
         out_specs=(P(None, axis), P(None, axis)))
     def segment_sharded(tp, bs, slab):

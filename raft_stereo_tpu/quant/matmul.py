@@ -42,6 +42,7 @@ from typing import Any, Optional, Sequence, Tuple, Union
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 
 from raft_stereo_tpu.quant.core import (dynamic_scale, is_quantized_leaf,
                                         quantize_symmetric)
@@ -155,7 +156,7 @@ def int8_matmul_report(closed) -> dict:
                 else:
                     stats["other_matmuls"] += 1
                 if any(v in dequant_outs for v in eqn.invars
-                       if not isinstance(v, jax.core.Literal)):
+                       if not isinstance(v, Literal)):
                     stats["dequant_fed_matmuls"] += 1
             for sub in eqn.params.values():
                 for j in subjaxprs(sub):

@@ -70,7 +70,7 @@ import os
 import pickle
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 log = logging.getLogger(__name__)
 
@@ -191,7 +191,11 @@ class ExecutableDiskCache:
         return sum(size for _, size, _ in self._entries())
 
     # ----------------------------------------------------------------- load
-    def load(self, key: str):
+    def load(self, key: str, devices: Optional[Sequence] = None):
+        """The stored executable loaded onto ``devices`` — the devices it
+        was compiled for (None = the default device, what a plain
+        ``jax.jit(f).lower(...).compile()`` targets) — or None on a
+        miss."""
         if self.disabled:
             return None
         path = self._path(key)
@@ -213,9 +217,15 @@ class ExecutableDiskCache:
                 self.misses += 1
             return None
         try:
+            import jax
             from jax.experimental import serialize_executable
+            # deserialize_and_load defaults to EVERY device of the
+            # backend; an executable compiled for one device (or one xl
+            # group) must be loaded onto exactly those.
             exe = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=(list(devices) if devices
+                                   else jax.devices()[:1]))
         except Exception:
             log.warning("could not deserialize cached executable %s "
                         "(backend/jax drift past the fingerprint?); "
@@ -438,20 +448,19 @@ class SessionHandoffStore:
         return removed
 
 
-def enable_persistent_compilation_cache(cache_dir: str) -> bool:
+def enable_persistent_compilation_cache(cache_dir: str) -> None:
     """Point jax's own persistent compilation cache at ``cache_dir`` —
-    covers compiles outside the engine's AOT path (best-effort; False
-    when this jax build does not support it)."""
+    covers compiles outside the engine's AOT path.  Yields to
+    ``JAX_COMPILATION_CACHE_DIR`` like every other site
+    (profiling.setup_compilation_cache): with the variable set, the
+    directory is the environment's."""
     import jax
 
-    try:
+    from raft_stereo_tpu.profiling import COMPILE_CACHE_ENV
+
+    if not os.environ.get(COMPILE_CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir",
                           os.path.abspath(os.path.expanduser(cache_dir)))
-        # Cache every compile, not just the slow ones: serving prewarm is
-        # many medium-size compiles, each below the default 1s floor.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        return True
-    except Exception:  # pragma: no cover - older jax
-        log.warning("jax persistent compilation cache unsupported by "
-                    "this jax build", exc_info=True)
-        return False
+    # Cache every compile, not just the slow ones: serving prewarm is
+    # many medium-size compiles, each below the default 1s floor.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -23,7 +23,7 @@ becomes a number:
   endpoints (telemetry/http.py ``handle_debug_get``).
 
 Degradation contract: a backend that returns nothing from
-``cost_analysis``/``memory_analysis`` (or raises — older jax, exotic
+``cost_analysis``/``memory_analysis`` (or raises — exotic
 plugins) yields a compile-time-only record with ``degraded=True``; the
 DISPATCH path never errors because of cost accounting, and when no
 ``CompileRegistry`` is attached at all the callers keep their exact
@@ -66,12 +66,6 @@ DEVICE_PEAK_GBPS: "collections.OrderedDict[str, float]" = (
         ("h100", 3350.0), ("a100", 2039.0),
     ]))
 
-# Ridge fallback when the device is unknown (CPU CI runs): the TPU v5e
-# ridge (~197e12 / 819e9).  Classification on unknown hardware is then a
-# TPU-class statement, which is what this repo optimizes for; the report
-# records which source the ridge came from.
-DEFAULT_RIDGE_FLOPS_PER_BYTE = 240.0
-
 
 def _local_device_kind() -> str:
     try:
@@ -88,6 +82,12 @@ def _lookup(table: "collections.OrderedDict[str, float]",
     for needle, value in table.items():
         if needle in kind:
             return value
+    if "tpu" in kind:
+        # A chip this repo runs on but has no peak for is a hole in the
+        # table, not a reason to report against another chip's roofline.
+        raise ValueError(
+            f"device_kind {kind!r} is not in telemetry/costs.py's peak "
+            f"tables; add its published peaks there")
     return None
 
 
@@ -95,8 +95,9 @@ def peak_flops_for(device_kind: Optional[str] = None,
                    override_tflops: Optional[float] = None
                    ) -> Optional[float]:
     """Peak FLOP/s for MFU's denominator: the override wins, then the auto
-    table keyed by ``device_kind`` (default: local device 0); None when
-    unknown (MFU gauges then stay 0 rather than report fiction)."""
+    table keyed by ``device_kind`` (default: local device 0).  None for a
+    device with no published peak here (the CPU: MFU is then "not
+    measured", the gauges stay 0); a TPU missing from the table raises."""
     if override_tflops is not None:
         return float(override_tflops) * 1e12
     peak = _lookup(DEVICE_PEAK_TFLOPS, device_kind)
@@ -115,18 +116,19 @@ def peak_bytes_per_s_for(device_kind: Optional[str] = None,
 
 def ridge_flops_per_byte(peak_flops: Optional[float],
                          peak_bytes_per_s: Optional[float]
-                         ) -> Tuple[float, str]:
-    """The roofline ridge point and where it came from
-    ("device" | "default")."""
+                         ) -> Tuple[Optional[float], str]:
+    """The roofline ridge point and where it came from: ``(ridge,
+    "device")``, or ``(None, "unknown")`` on a device without published
+    peaks — never another chip's ridge."""
     if peak_flops and peak_bytes_per_s:
         return peak_flops / peak_bytes_per_s, "device"
-    return DEFAULT_RIDGE_FLOPS_PER_BYTE, "default"
+    return None, "unknown"
 
 
 def classify_bound(flops: Optional[float], bytes_accessed: Optional[float],
-                   ridge: float) -> str:
+                   ridge: Optional[float]) -> str:
     """Roofline classification: arithmetic intensity vs the ridge point."""
-    if not flops or not bytes_accessed:
+    if not flops or not bytes_accessed or not ridge:
         return "unknown"
     return "compute" if flops / bytes_accessed >= ridge else "memory"
 
@@ -199,16 +201,13 @@ def executable_cost(compiled) -> Dict[str, Any]:
     """Extract flops/bytes/memory from a ``jax.stages.Compiled`` (or
     anything quacking like one), degrading field-by-field: an analysis that
     raises or returns nothing leaves its fields None and flips
-    ``degraded`` — never an exception (the satellite contract: CPU/older
-    jax must not break the dispatch path)."""
+    ``degraded`` — never an exception (the satellite contract: cost
+    analysis must not break the dispatch path)."""
     out: Dict[str, Any] = {"flops": None, "bytes_accessed": None,
                            "transcendentals": None, "memory": None,
                            "degraded": False}
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one dict/partition
-            cost = cost[0] if cost else {}
-        cost = dict(cost or {})
+        cost = dict(compiled.cost_analysis() or {})
     except Exception:
         cost = {}
     if cost:

@@ -22,6 +22,7 @@ from raft_stereo_tpu.config import TrainConfig
 from raft_stereo_tpu.data.device_jitter import (JitterParams,
                                                 apply_photometric,
                                                 params_for_datasets)
+from raft_stereo_tpu.parallel.data_sharded import data_sharding
 from raft_stereo_tpu.parallel.mesh import DATA_AXIS
 from raft_stereo_tpu.training.anomaly import (SKIP_KEY, SKIP_NONFINITE_KEY,
                                               SKIP_SPIKE_KEY, AnomalyPolicy)
@@ -177,10 +178,17 @@ def make_train_step(train_cfg: TrainConfig, mesh: Optional[Mesh] = None,
     if mesh is None:
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
+    def step_on_mesh(*args):
+        # The body runs at trace time: the Pallas kernel calls inside it
+        # are batch-split over ``data`` by hand (parallel/data_sharded.py),
+        # everything else by XLA from the shardings below.
+        with data_sharding(mesh):
+            return step(*args)
+
     repl = NamedSharding(mesh, P())
     data = NamedSharding(mesh, P(DATA_AXIS))
     return jax.jit(
-        step,
+        step_on_mesh,
         in_shardings=(repl, data) + ((repl,) if n_out == 3 else ()),
         out_shardings=(repl,) * n_out,
         donate_argnums=(0,) if donate else (),

@@ -74,9 +74,7 @@ def main() -> int:
                                  recorder=recorder, costs=costs).start()
     print(f"metrics endpoint: {server.url} (artifacts: {tmp})")
 
-    # InstanceNorm's optimization_barrier has no CPU differentiation rule
-    # in some jax versions, hence fnet_norm="none" (the hermetic tests'
-    # workaround too).
+    # fnet_norm="none": the smallest encoder (the tests' tiny config too)
     model_cfg = RaftStereoConfig(n_gru_layers=1, hidden_dims=(32,),
                                  fnet_dim=64, fnet_norm="none")
     train_cfg = TrainConfig(batch_size=2, train_iters=2,

@@ -95,11 +95,10 @@ def main() -> int:
     from _hermetic import force_cpu
 
     force_cpu(1)
-    import jax
     import numpy as np
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
 
     from early_exit_report import model_config, trained_variables
     from golden_data import disparity_field, textured_image, warp_right

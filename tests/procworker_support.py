@@ -33,3 +33,22 @@ class KillOnceDataset:
                 os.fsync(f.fileno())
             os.kill(os.getpid(), signal.SIGKILL)
         return {"x": np.full((2, 2), float(i) + 100.0 * epoch)}
+
+
+class BackendProbeDataset:
+    """Every sample reports its process id and whether THAT process has
+    initialised a jax backend — a chip belongs to one process at a time,
+    so a loader worker spawned by the process that trains on it must
+    never reach for a device."""
+
+    def __len__(self):
+        return 8
+
+    def __getitem__(self, i, epoch=0):
+        import sys
+
+        # No public query exists; a worker that never imported the module
+        # cannot have initialised anything.
+        bridge = sys.modules.get("jax._src.xla_bridge")
+        up = bool(bridge is not None and bridge.backends_are_initialized())
+        return {"pid": np.array(os.getpid()), "backend_up": np.array(up)}

@@ -197,8 +197,11 @@ def test_peak_table_and_override():
     assert peak_flops_for("cpu", override_tflops=2.0) == 2e12
     ridge, src = ridge_flops_per_byte(197e12, 819e9)
     assert src == "device" and ridge == pytest.approx(240.5, abs=0.5)
-    _, src = ridge_flops_per_byte(None, None)
-    assert src == "default"
+    # no published peak: no ridge, never another chip's
+    assert ridge_flops_per_byte(None, None) == (None, "unknown")
+    assert classify_bound(1e9, 1e6, None) == "unknown"
+    with pytest.raises(ValueError, match="peak tables"):
+        peak_flops_for("TPU v9 hypothetical")
     assert classify_bound(1e9, 1e6, 240.0) == "compute"
     assert classify_bound(1e6, 1e6, 240.0) == "memory"
     assert classify_bound(None, 1e6, 240.0) == "unknown"
@@ -404,8 +407,13 @@ def test_cost_report_tool_phases_sum_and_classify(tmp_path):
     import tools.cost_report as cost_report
 
     out = str(tmp_path / "COST_REPORT_test.json")
+    # The CPU has no published peak, so the roofline the phases are
+    # classified against is named: the v5e's (without the two flags every
+    # bound is "unknown" here — never a borrowed TPU default).
     assert cost_report.main(["--config", "tiny", "--height", "64",
                              "--width", "96", "--iters", "2",
+                             "--device_peak_tflops", "197",
+                             "--device_peak_gbps", "819",
                              "--out", out]) == 0
     with open(out) as f:
         rep = json.load(f)
@@ -437,7 +445,12 @@ def test_unrolled_gru_matches_scan(tiny_model):
     d_scan, f_scan = model.apply(variables, i1, i2, iters=2, test_mode=True)
     d_un, f_un = model.apply(variables, i1, i2, iters=2, test_mode=True,
                              unroll_gru=True)
+    # Same math, two programs: XLA fuses the scan body and the unrolled
+    # body differently, and the untrained GRU amplifies that
+    # reassociation noise ~5x per iteration (measured 5.6e-5 abs at 2
+    # iterations under jaxlib 0.9.0 on flows of order 10 px) — so the
+    # tolerance is the 2-iteration noise floor, not bitwise.
     np.testing.assert_allclose(np.asarray(f_scan), np.asarray(f_un),
-                               atol=1e-5, rtol=1e-5)
+                               atol=2e-4, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(d_scan), np.asarray(d_un),
-                               atol=1e-5, rtol=1e-5)
+                               atol=2e-4, rtol=1e-5)

@@ -1337,3 +1337,43 @@ def test_router_passthrough_byte_identical_real_engine(tiny_model):
         router.stop()
         server.shutdown()
         svc.close()
+
+
+# ------------------------------------------------- one process per chip
+_ROUTER_PROBE = r"""
+import sys, time
+from raft_stereo_tpu.cli import route
+from raft_stereo_tpu.serving.fleet import RouterHTTPServer
+
+args = route.build_parser().parse_args([
+    "--replica", "http://127.0.0.1:9", "--port", "0",
+    "--health_poll_s", "0.05", "--health_timeout_s", "0.2",
+    "--autoscale_cmd", sys.executable + " -c pass {name} {port}"])
+router = route.build_router(args).start()
+autoscaler = route.build_autoscaler(args, router)
+server = RouterHTTPServer(router, host=args.host, port=args.port,
+                          max_workers=args.http_workers)
+time.sleep(0.3)                       # a few health polls
+router.fleet_status()
+router.stop()
+bridge = sys.modules.get("jax._src.xla_bridge")
+print("BACKEND_UP", bool(bridge is not None
+                         and bridge.backends_are_initialized()))
+"""
+
+
+def test_router_process_stays_off_the_device():
+    """The router (and its autoscaler) start ``raft-serve`` children that
+    each need a chip; a chip belongs to one process at a time, so the
+    router process itself must never initialise a jax backend — checked
+    in a fresh interpreter that builds everything ``raft-route`` builds
+    (this pytest process has long since initialised its CPU backend)."""
+    import subprocess
+    import sys
+
+    out = subprocess.run([sys.executable, "-c", _ROUTER_PROBE],
+                         capture_output=True, text=True, timeout=120,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "BACKEND_UP False" in out.stdout, out.stdout

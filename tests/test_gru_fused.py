@@ -160,10 +160,9 @@ def test_remat_scan_vjp_parity(interpret_mode, rng):
     """The custom VJP composes with the model's exact training structure —
     nn.remat(policy=save_only_these_names("gru_gates", ...)) around an
     nn.scan of the update block: loss and gradients agree between fused and
-    Flax paths.  (Exercised at the update-block level: this environment's
-    jax lacks a differentiation rule for the encoders' optimization_barrier,
-    but the remat/scan/VJP composition under test lives entirely in the
-    update block.)"""
+    Flax paths.  (Exercised at the update-block level: the
+    remat/scan/VJP composition under test lives entirely in the update
+    block.)"""
     cfg = RaftStereoConfig(hidden_dims=(16, 16), n_gru_layers=2,
                            fnet_dim=32, corr_levels=2, corr_radius=3)
     net, ctx, corr, flow = _update_block_io(rng, cfg)
@@ -249,8 +248,12 @@ def test_capability_and_fit_gating(rng):
             "on", kernel_size=3, w=64, cin=96, ch=32, itemsize=4)
     # VMEM fit: a realistic level fits; an absurdly wide one must not, and
     # the row block never shrinks below the two-view minimum of 4.
-    rb = gru_fused.gru_fused_row_block(180, 384, 128, 2)
+    rb = gru_fused.gru_fused_row_block(90, 384, 128, 2)
     assert rb is not None and 4 <= rb <= 8
+    # The SceneFlow crop's finest level: 11.57 MiB on the v5e at rb=4 (the
+    # halo doubles the rows), refused inside the batch-4 training step —
+    # the estimate counts the halo rows and sends it to the Flax path.
+    assert gru_fused.gru_fused_row_block(180, 384, 128, 2) is None
     assert gru_fused.gru_fused_row_block(200_000, 384, 128, 4) is None
     # "on" + unfittable working set raises even where the kernel exists.
     corr_lookup._interpret_override = True

@@ -160,3 +160,65 @@ def test_bench_regression_warnings():
     kinds = " ".join(w["warning"] for w in warns)
     assert "per_gru_iter_ms" in kinds
     assert "north-star" in kinds
+
+
+# ------------------------------------------------- the compile-cache rule
+@pytest.fixture
+def _restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    before_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      before_min)
+
+
+def test_compile_cache_goes_to_a_fixed_path_in_the_checkout(
+        monkeypatch, _restore_cache_dir):
+    monkeypatch.delenv(profiling.COMPILE_CACHE_ENV, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert profiling.setup_compilation_cache() == os.path.join(
+        repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        repo, ".jax_cache")
+    # the same path every time: a pid, a time or a temp name would never hit
+    assert profiling.setup_compilation_cache() == os.path.join(
+        repo, ".jax_cache")
+
+
+def test_compile_cache_yields_to_the_environment(monkeypatch, tmp_path,
+                                                 _restore_cache_dir):
+    """With JAX_COMPILATION_CACHE_DIR set no code names another directory:
+    neither the helper nor serving's --executable_cache_dir hook."""
+    from raft_stereo_tpu.serving.persist import (
+        enable_persistent_compilation_cache)
+
+    jax.config.update("jax_compilation_cache_dir", "/from/the/environment")
+    monkeypatch.setenv(profiling.COMPILE_CACHE_ENV, "/from/the/environment")
+    assert profiling.setup_compilation_cache() == "/from/the/environment"
+    enable_persistent_compilation_cache(str(tmp_path / "store"))
+    assert jax.config.jax_compilation_cache_dir == "/from/the/environment"
+    monkeypatch.delenv(profiling.COMPILE_CACHE_ENV)
+    enable_persistent_compilation_cache(str(tmp_path / "store"))
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "store")
+
+
+def test_no_other_site_names_a_cache_directory():
+    """One rule, one helper: outside profiling.py and serving/persist.py
+    nothing in the tree touches jax_compilation_cache_dir."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    allowed = {os.path.join("raft_stereo_tpu", "profiling.py"),
+               os.path.join("raft_stereo_tpu", "serving", "persist.py"),
+               os.path.join("tests", "test_profiling.py")}
+    hits = []
+    for root, dirs, files in os.walk(repo):
+        dirs[:] = [d for d in dirs if not d.startswith((".", "_"))
+                   and d != "chiprun_out"]
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(root, name)
+            with open(path, errors="replace") as f:
+                if "jax_compilation_cache_dir" in f.read():
+                    hits.append(os.path.relpath(path, repo))
+    assert set(hits) <= allowed, hits

@@ -283,8 +283,6 @@ def test_rows_sharded_train_loop_auto_wires(tmp_path, rng):
 def test_rows_sharded_two_axis_mesh(rng):
     """Rows sharded over 'data' while a 'corr' axis coexists on the same
     mesh — the precondition for composing with the W2-sharded volume."""
-    from conftest import require_corr_mesh
-    require_corr_mesh()
     from raft_stereo_tpu.parallel.mesh import make_mesh
 
     trunk = _Trunk("instance", downsample=2, dtype=jnp.float32)

@@ -117,8 +117,7 @@ class _SyntheticDataset:
 
 
 def _tiny_cfgs(num_steps=5, train_iters=2, gru_telemetry=True):
-    # fnet_norm="none": InstanceNorm's optimization_barrier lacks a CPU
-    # differentiation rule in this jax version.
+    # fnet_norm="none": the smallest encoder
     mcfg = RaftStereoConfig(n_gru_layers=1, hidden_dims=(32,), fnet_dim=64,
                             fnet_norm="none")
     tcfg = TrainConfig(batch_size=2, train_iters=train_iters,

@@ -287,6 +287,24 @@ def test_loader_process_worker_respawn(tmp_path):
     assert os.path.exists(marker)
 
 
+def test_loader_process_workers_stay_off_the_device():
+    """One process per chip: the trainer holds the device and spawns the
+    loader's workers, so a worker that initialised a jax backend would
+    fail or hang on a TPU host.  Every sample is decoded in a child, and
+    no child has a backend up (importing jax is fine, touching a device
+    is not)."""
+    import procworker_support as sup   # importable by spawn children
+
+    loader = StereoLoader(sup.BackendProbeDataset(), batch_size=2,
+                          num_workers=2, shuffle=False, epochs=1,
+                          worker_type="process")
+    batches = list(loader)
+    assert len(batches) == 4
+    pids = {int(p) for b in batches for p in b["pid"]}
+    assert os.getpid() not in pids
+    assert not any(bool(u) for b in batches for u in b["backend_up"])
+
+
 # --------------------------------------------------- prefetcher (satellite 1)
 def test_prefetcher_reraises_and_stays_terminal():
     from raft_stereo_tpu.training.train_loop import _DevicePrefetcher

@@ -1,0 +1,276 @@
+"""Compile the main path's kernels at published widths for a DESCRIBED
+TPU v5e — no chip attached, nothing runs.
+
+Interpret mode (every other kernel test in this suite) cannot see what the
+chip's compiler refuses: a scoped-VMEM overflow, a misaligned slice, a
+Mosaic kernel under a mesh that XLA will not partition.  These compiles
+can, at no chip time.  Rules this file keeps (on-chip-measurement guide,
+section 2): the topology is described only inside the module-scoped
+fixture below — never at import, never in a ``skipif``/``parametrize``
+argument — the compiles run in the test's own process, jax's persistent
+cache is off around them, and all of them live in this one file (libtpu
+belongs to the one xdist worker that is handed it).
+
+The ``*_available()`` gates ask ``jax.default_backend()``, which is
+``cpu`` here: kernel tests call the launch functions directly, and the
+whole-program tests steer the gates from the test (``kernels_on``), not
+through an option of the program.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+RADIUS = 4
+K = 2 * RADIUS + 1
+# 1/4-res correlation shapes: (batch*H rows, W1, per-level W2)
+ACCURACY_KITTI = (96, 312, (312, 156, 78, 39))     # 384x1248, 4 levels
+REALTIME_KITTI = (48, 156, (156, 78))              # 1/8-res, 2 levels
+SCENEFLOW_TRAIN = (8 * 80, 180, (180, 90, 45, 22))  # batch 8, 320x720
+MIDDLEBURY_F = (496, 720, 256)                     # 1/4-res H, W, fnet D
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip; keep it out.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def data_mesh(topo):
+    from raft_stereo_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(n_data=4, devices=topo.devices[:4])
+
+
+@pytest.fixture
+def kernels_on(monkeypatch):
+    """Steer the capability gates as the chip would answer them.
+    ``kernels_on("lookup")`` opens the correlation kernels only;
+    ``kernels_on("lookup", "gru")`` the fused ConvGRU gates too."""
+    from raft_stereo_tpu.kernels import corr_alt, corr_lookup, gru_fused
+
+    def steer(*families):
+        if "lookup" in families:
+            monkeypatch.setattr(corr_lookup, "fused_lookup_available",
+                                lambda: True)
+            monkeypatch.setattr(corr_alt, "fused_lookup_available",
+                                lambda: True)
+        if "gru" in families:
+            monkeypatch.setattr(gru_fused, "fused_lookup_available",
+                                lambda: True)
+    return steer
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_calls(compiled):
+    from chip_smoke import kernel_launches    # the smoke's own reading
+
+    return kernel_launches(compiled.as_text())
+
+
+def _pyramid(shape, dtype, sharding):
+    rows, w1, w2s = shape
+    # (B=1, H=rows, W1, W2): the entries flatten (B, H) to rows themselves
+    return ([_sds((1, rows, w1, w2), dtype, sharding) for w2 in w2s],
+            _sds((1, rows, w1), jnp.float32, sharding))
+
+
+# ------------------------------------------------------------- the lookups
+@pytest.mark.parametrize("shape,dtype,launches", [
+    # fp32 at KITTI width: the four levels' tiles do not fit one program's
+    # VMEM budget together, so the lookup runs one launch per level
+    (ACCURACY_KITTI, jnp.float32, 4),
+    (REALTIME_KITTI, jnp.bfloat16, 1),
+], ids=["accuracy-kitti-fp32", "realtime-kitti-bf16"])
+def test_lookup_compiles(one_chip, shape, dtype, launches):
+    from raft_stereo_tpu.kernels.corr_lookup import lookup_pyramid_fused
+
+    pyramid, coords = _pyramid(shape, dtype, one_chip)
+    compiled = jax.jit(
+        lambda pyr, c: lookup_pyramid_fused(pyr, c, RADIUS)
+    ).lower(pyramid, coords).compile()
+    assert len(_kernel_calls(compiled)) == launches
+
+
+@pytest.mark.parametrize("shape,q_dtype", [
+    (ACCURACY_KITTI, jnp.int8),
+    (REALTIME_KITTI, jnp.float8_e4m3fn),
+], ids=["accuracy-kitti-int8", "realtime-kitti-fp8"])
+def test_quantized_lookup_compiles(one_chip, monkeypatch, shape, q_dtype):
+    from raft_stereo_tpu.kernels import corr_lookup
+
+    # check_q_dtype asks the fp8 capability gate, cpu here
+    monkeypatch.setattr(corr_lookup, "fused_lookup_available", lambda: True)
+    pyramid, coords = _pyramid(shape, q_dtype, one_chip)
+    compiled = jax.jit(
+        lambda pyr, c: corr_lookup.lookup_pyramid_fused_q(
+            pyr, c, RADIUS, out_dtype=jnp.float32, q_dtype=q_dtype)
+    ).lower(pyramid, coords).compile()
+    assert len(_kernel_calls(compiled)) == 1
+
+
+def test_alt_lookup_compiles_at_middlebury_f(one_chip):
+    from raft_stereo_tpu.kernels.corr_alt import alt_lookup_fused
+
+    h, w, d = MIDDLEBURY_F
+    f1 = _sds((1, h, w, d), jnp.bfloat16, one_chip)
+    f2s = [_sds((1, h, w // 2 ** i, d), jnp.bfloat16, one_chip)
+           for i in range(4)]
+    coords = _sds((1, h, w), jnp.float32, one_chip)
+    compiled = jax.jit(
+        lambda a, bs, c: alt_lookup_fused(a, bs, c, RADIUS)
+    ).lower(f1, f2s, coords).compile()
+    assert _kernel_calls(compiled)
+
+
+@pytest.mark.parametrize("dtype,launches", [
+    (jnp.bfloat16, 1),
+    # fp32: the all-levels backward asks Mosaic for 16.32 MiB of scoped
+    # VMEM against a 16 MiB limit, so it runs one launch per level.
+    (jnp.float32, 4),
+], ids=["bf16", "fp32"])
+def test_lookup_backward_compiles_at_sceneflow_crop(one_chip, dtype,
+                                                    launches):
+    from raft_stereo_tpu.kernels.corr_lookup import lookup_pyramid_fused
+
+    pyramid, coords = _pyramid(SCENEFLOW_TRAIN, dtype, one_chip)
+    rows, w1, w2s = SCENEFLOW_TRAIN
+    g = _sds((1, rows, w1, len(w2s) * K), dtype, one_chip)
+
+    def pullback(pyr, c, g):
+        _, vjp = jax.vjp(lambda p: lookup_pyramid_fused(p, c, RADIUS), pyr)
+        return vjp(g)
+
+    compiled = jax.jit(pullback).lower(pyramid, coords, g).compile()
+    assert len(_kernel_calls(compiled)) == launches
+
+
+# ---------------------------------------------------------- the ConvGRU gates
+def _gru_operands(b, h, w, sharding_b, sharding_w, ch=128, cx=128):
+    act = lambda c: _sds((b, h, w, c), jnp.bfloat16, sharding_b)  # noqa: E731
+    cin = ch + cx
+    return (act(ch), act(cx), act(ch)), (
+        _sds((3, 3, cin, 2 * ch), jnp.float32, sharding_w),
+        _sds((2 * ch,), jnp.float32, sharding_w),
+        _sds((3, 3, cin, ch), jnp.float32, sharding_w),
+        _sds((ch,), jnp.float32, sharding_w))
+
+
+def test_gru_gates_compile(one_chip):
+    from raft_stereo_tpu.kernels.gru_fused import gru_gates_fused
+
+    acts, weights = _gru_operands(1, 24, 78, one_chip, one_chip)
+    compiled = jax.jit(gru_gates_fused).lower(*acts, *weights).compile()
+    assert len(_kernel_calls(compiled)) == 1
+
+
+def test_gru_gates_split_over_data_mesh(data_mesh):
+    """The gate kernel under a 4-device data mesh: each device launches
+    it on its own quarter of the batch."""
+    from raft_stereo_tpu.kernels.gru_fused import gru_gates_fused
+    from raft_stereo_tpu.parallel.data_sharded import (data_sharding,
+                                                       over_data_axis)
+
+    acts, weights = _gru_operands(8, 24, 78,
+                                  NamedSharding(data_mesh, P("data")),
+                                  NamedSharding(data_mesh, P()))
+
+    def gates(*ops):
+        with data_sharding(data_mesh):
+            return over_data_axis(gru_gates_fused, ops[:3], ops[3:])
+
+    compiled = jax.jit(gates).lower(*acts, *weights).compile()
+    calls = _kernel_calls(compiled)
+    assert len(calls) == 1
+    assert "bf16[2,24,78,256]" in calls[0]    # zr of 2 of the 8 images
+
+
+# ------------------------------------------------------------ whole programs
+def test_accuracy_forward_compiles(one_chip, kernels_on):
+    """The served program (eval/runner.make_forward) at the KITTI bucket,
+    32 iterations, published widths."""
+    from raft_stereo_tpu.config import RaftStereoConfig
+    from raft_stereo_tpu.eval.runner import make_forward
+    from raft_stereo_tpu.models.raft_stereo import RAFTStereo
+    from raft_stereo_tpu.training.state import init_model_variables
+
+    kernels_on("lookup", "gru")
+    cfg = RaftStereoConfig()
+    variables = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: init_model_variables(
+            cfg, jax.random.PRNGKey(0))))
+    image = _sds((1, 384, 1248, 3), np.uint8, one_chip)
+    compiled = make_forward(RAFTStereo(cfg), 32).lower(
+        variables, image, image).compile()
+    assert _kernel_calls(compiled)
+    # one 16 GB chip holds it with room for the batch ladder
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_data_parallel_train_step_compiles(data_mesh, kernels_on):
+    """``make_train_step(train_cfg, mesh=<data=4>)`` with the default
+    ``corr_backend="reg_fused"`` at the published SceneFlow crop (2 of the
+    22 iterations; the scan body compiles once whatever the count): before
+    parallel/data_sharded.py this failed to lower — "Mosaic kernels cannot
+    be automatically partitioned".  The ConvGRU kernel stays gated off
+    here (each of its three level shapes costs ~25 s of Mosaic compile);
+    its split is pinned above."""
+    from raft_stereo_tpu.config import RaftStereoConfig, TrainConfig
+    from raft_stereo_tpu.training.state import create_train_state
+    from raft_stereo_tpu.training.step import make_train_step
+
+    kernels_on("lookup")
+    model_cfg = RaftStereoConfig(mixed_precision=True)
+    train_cfg = TrainConfig(batch_size=8, image_size=(320, 720),
+                            train_iters=2)
+    h, w = train_cfg.image_size
+    b = train_cfg.batch_size
+    repl = NamedSharding(data_mesh, P())
+    split = NamedSharding(data_mesh, P("data"))
+    state = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, repl),
+        jax.eval_shape(lambda: create_train_state(
+            model_cfg, train_cfg, jax.random.PRNGKey(0),
+            image_shape=(1, h, w, 3))))
+    batch = {"image1": _sds((b, h, w, 3), np.uint8, split),
+             "image2": _sds((b, h, w, 3), np.uint8, split),
+             "flow": _sds((b, h, w), jnp.float32, split),
+             "valid": _sds((b, h, w), jnp.float32, split)}
+    # Least optimisation effort: partitioning and the Mosaic compile are
+    # what this test is about, and XLA's full effort on the step's convs
+    # costs 225 s against 39 s.
+    compiled = make_train_step(train_cfg, mesh=data_mesh).lower(
+        state, batch).compile(compiler_options={
+            "exec_time_optimization_effort": -1.0,
+            "memory_fitting_effort": -1.0})
+    calls = _kernel_calls(compiled)
+    assert calls
+    rows = b * (h // 4) // 4    # a quarter of the batch's 1/4-res rows
+    assert all(f"bf16[{rows},180,180]" in c for c in calls), calls
+    assert "all-gather" not in compiled.as_text()

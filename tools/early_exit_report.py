@@ -276,8 +276,8 @@ def main(argv=None) -> int:
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
 
     from raft_stereo_tpu.config import REQUEST_TIERS
     from raft_stereo_tpu.eval.runner import InferenceRunner

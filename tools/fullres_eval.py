@@ -73,8 +73,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
 
     from raft_stereo_tpu.config import RaftStereoConfig
     from raft_stereo_tpu.eval.runner import InferenceRunner

@@ -54,8 +54,8 @@ def main():
     from raft_stereo_tpu.models.raft_stereo import RAFTStereo
     from raft_stereo_tpu.profiling import chained_seconds_per_call
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
 
     rng = np.random.default_rng(0)
     base = RaftStereoConfig(corr_backend="alt")  # volume-free: stem dominates

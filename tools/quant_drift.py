@@ -31,7 +31,7 @@ What runs:
                         exactly the activation quantization.
 4. **The gate**: worst |ΔEPE| of the int8 AND int8_mxu tiers at the
    d<=96 band must stay within ``--gate_px`` (default 0.05 px — the
-   same budget PRODUCT_r05 accepted for the fp16 fetch).  The record
+   same budget the fp16 fetch was accepted at).  The record
    carries a ``gate`` object with a per-mode breakdown;
    scripts/quant_smoke.py asserts it in CI.
 
@@ -175,8 +175,8 @@ def main(argv=None) -> int:
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    from raft_stereo_tpu.profiling import setup_compilation_cache
+    setup_compilation_cache()
 
     from drift_common import evaluate_variants, make_band_scenes
     from early_exit_report import model_config

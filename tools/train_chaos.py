@@ -71,10 +71,9 @@ N_SAMPLES = 32
 BATCH = 2
 
 
-# fnet_norm="batch": this container's jax (0.4.x) has no differentiation
-# rule for the instance norm's optimization_barrier, so the chaos model
-# uses the frozen-batch-norm encoder — same train-loop code paths, and
-# the anomaly machinery under test is norm-agnostic.
+# fnet_norm="batch": the chaos model uses the frozen-batch-norm encoder —
+# same train-loop code paths, and the anomaly machinery under test is
+# norm-agnostic.
 def tiny_model_cfg() -> RaftStereoConfig:
     return RaftStereoConfig(n_gru_layers=1, hidden_dims=(32,), fnet_dim=64,
                             corr_levels=2, corr_radius=3, fnet_norm="batch")
