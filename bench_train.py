@@ -11,8 +11,8 @@ one TPU chip:
 * compiled FLOPs per step from XLA cost analysis -> achieved TFLOP/s and MFU
   against the chip's bf16 peak;
 * peak HBM from device memory stats (when the runtime reports them);
-* optionally (--trace) a profiler trace whose top device ops are summarized
-  by tools/trace_summary.py into docs/TRAIN_PROFILE.md.
+* optionally (--trace) a profiler trace of one jitted step
+  (``benchmark/trace_reduce.py`` reduces such a trace to numbers).
 
 Prints ONE JSON line compatible with bench.py's contract.  ``vs_baseline``
 compares against the reference's published training protocol the only way
@@ -97,7 +97,7 @@ def main():
 
     if args.trace:
         # Trace-only mode: one warm + one traced step through the plain
-        # jitted step (summarize with tools/trace_summary.py).
+        # jitted step (reduce with benchmark/trace_reduce.py).
         jitted = jax.jit(step, donate_argnums=())
         _, m = jitted(state, batch)
         float(m["loss"])

@@ -1,0 +1,9 @@
+"""Share of the traced stretch that the worker's spans cover
+(``host_spans``) in which the device was idle while the worker
+was in the engine's own phases (``serve.assemble`` / ``upload`` / ``fetch`` /
+``account`` / ``respond``), in the cell judged on throughput."""
+from benchmark.host_spans import ENGINE_SPANS, idle_share_pct
+
+
+def read(observed):
+    return idle_share_pct(observed, ENGINE_SPANS)

@@ -21,6 +21,7 @@ import numpy as np
 from raft_stereo_tpu.config import RaftStereoConfig
 from raft_stereo_tpu.models.raft_stereo import RAFTStereo
 from raft_stereo_tpu.ops.padding import InputPadder
+from raft_stereo_tpu.telemetry.spans import Phases
 
 log = logging.getLogger(__name__)
 
@@ -40,6 +41,10 @@ warnings.filterwarnings(
 # EPE.  Eval/demo runs at or past this depth flip the correlation features to
 # fp32 (everything else stays bf16) unless the caller opts out.
 DEEP_ITERS_FP32_CORR = 16
+
+# Host phases of one ``__call__`` / ``run_batch``, in order (spans
+# ``infer.<name>``).
+RUNNER_PHASES = ("stack_pad", "upload", "execute", "fetch", "unpad")
 
 
 def effective_inference_config(config: RaftStereoConfig, iters: int,
@@ -506,6 +511,12 @@ class InferenceRunner:
         self.cost_registry = cost_registry
         self.cost_site = cost_site
         self.donate_images = donate_images
+        # Host phases of a call (telemetry/spans.py): ``infer.*`` events on
+        # the profiler's clock, and ``infer_phase_seconds{phase=}`` where
+        # the caller's cost registry brings a metrics registry.
+        self.phases = Phases("infer.", RUNNER_PHASES,
+                             getattr(cost_registry, "metrics", None),
+                             "infer_phase_seconds")
         self._compiled: Dict[Tuple[int, int], any] = {}
         # Streaming (warm-start) programs live in their own small cache:
         # they carry an extra state output (and, warm, an extra input),
@@ -603,28 +614,19 @@ class InferenceRunner:
         way the reference's 50-image discard absorbs cuDNN autotune
         (reference: evaluate_stereo.py:77-82)."""
         assert image1.ndim == 3 and image1.shape == image2.shape
-        t0 = time.perf_counter()
-        padder = InputPadder((1,) + image1.shape, divis_by=self.divis_by)
-        l, r, t, b = padder.pads
-        # Host-side replicate pad (NumPy — microseconds) and caller-dtype
-        # upload: KITTI/eval images arrive uint8, so the per-image copy is
-        # 4x smaller; the cast to float happens on device inside the
-        # compiled program.
-        spec = ((t, b), (l, r), (0, 0))
-        p1 = np.pad(np.asarray(image1), spec, mode="edge")
-        p2 = np.pad(np.asarray(image2), spec, mode="edge")
-        fwd = self._forward_for(p1.shape[:2])
-        out = fwd(self.variables, jnp.asarray(p1[None]),
-                  jnp.asarray(p2[None]))
-        if self.early_exit:
-            out, iters_used = out
-            self._note_iters_used(iters_used)
-        flow_padded = np.asarray(out)[0]
-        flow = padder.unpad(flow_padded[None])[0]  # pure NumPy slicing
-        if flow.dtype != np.float32:               # half-precision fetch
-            flow = flow.astype(np.float32)
-        elapsed = time.perf_counter() - t0
-        return np.ascontiguousarray(flow), elapsed
+
+        def padded(pads):
+            # Host-side replicate pad (NumPy — microseconds) and caller-dtype
+            # upload: KITTI/eval images arrive uint8, so the per-image copy
+            # is 4x smaller; the cast to float happens on device inside the
+            # compiled program.
+            l, r, t, b = pads
+            spec = ((t, b), (l, r), (0, 0))
+            return (np.pad(np.asarray(image1), spec, mode="edge")[None],
+                    np.pad(np.asarray(image2), spec, mode="edge")[None])
+
+        flows, elapsed = self._run_padded(image1.shape, 1, padded)
+        return flows[0], elapsed
 
     def run_batch(self, images1, images2) -> Tuple[np.ndarray, float]:
         """Batched product mode: ONE host->device upload, ONE compiled
@@ -643,23 +645,48 @@ class InferenceRunner:
                    for im in (*images1, *images2)), \
             "run_batch requires same-shape pairs; pad upstream or use " \
             "per-image calls for mixed shapes"
-        t0 = time.perf_counter()
-        padder = InputPadder((1,) + shape, divis_by=self.divis_by)
-        l, r, t, b = padder.pads
-        spec = ((0, 0), (t, b), (l, r), (0, 0))
-        p1 = np.pad(np.stack(images1), spec, mode="edge")
-        p2 = np.pad(np.stack(images2), spec, mode="edge")
-        fwd = self._forward_for(p1.shape[1:3], batch=len(images1))
-        out = fwd(self.variables, jnp.asarray(p1), jnp.asarray(p2))
-        if self.early_exit:
-            out, iters_used = out
-            self._note_iters_used(iters_used)
-        flows_padded = np.asarray(out)
-        flows = padder.unpad(flows_padded)
-        if flows.dtype != np.float32:              # half-precision fetch
-            flows = flows.astype(np.float32)
-        elapsed = time.perf_counter() - t0
-        return np.ascontiguousarray(flows), elapsed
+
+        def padded(pads):
+            l, r, t, b = pads
+            spec = ((0, 0), (t, b), (l, r), (0, 0))
+            return (np.pad(np.stack(images1), spec, mode="edge"),
+                    np.pad(np.stack(images2), spec, mode="edge"))
+
+        return self._run_padded(shape, len(images1), padded)
+
+    def _run_padded(self, shape, n: int, padded
+                    ) -> Tuple[np.ndarray, float]:
+        """The product path of ``__call__`` and ``run_batch``, each step a
+        host phase (``infer.stack_pad`` / ``upload`` / ``execute`` /
+        ``fetch`` / ``unpad``, telemetry/spans.py).  ``padded(pads)`` makes
+        the two (n, Hp, Wp, 3) host arrays.  The seconds run from the first
+        phase's start to the end of ``fetch``, as they always have: the
+        contiguous copy of the unpadded view is outside them."""
+
+        def phase(name: str, **attrs):
+            return self.phases.phase(name, batch_size=n, **attrs)
+
+        with phase("stack_pad") as first:
+            padder = InputPadder((1,) + tuple(shape), divis_by=self.divis_by)
+            p1, p2 = padded(padder.pads)
+            fwd = self._forward_for(p1.shape[1:3], batch=n)
+        with phase("upload", bytes=p1.nbytes + p2.nbytes):
+            # returns at once; the launch waits for the copy, in ``execute``
+            d1, d2 = jnp.asarray(p1), jnp.asarray(p2)
+        with phase("execute"):
+            out = jax.block_until_ready(fwd(self.variables, d1, d2))
+        with phase("fetch") as fetch:
+            if self.early_exit:
+                out, iters_used = out
+                self._note_iters_used(iters_used)
+            flows_padded = np.asarray(out)
+            fetch.set(bytes=flows_padded.nbytes)
+            flows = padder.unpad(flows_padded)     # pure NumPy slicing
+            if flows.dtype != np.float32:          # half-precision fetch
+                flows = flows.astype(np.float32)
+        with phase("unpad"):
+            flows = np.ascontiguousarray(flows)
+        return flows, fetch.t_end - first.t_start
 
     # ------------------------------------------------------------- streaming
     def _stream_forward_for(self, padded_hw: Tuple[int, int], warm: bool,

@@ -96,6 +96,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import logging
 import threading
 import time
@@ -104,7 +105,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from raft_stereo_tpu import profiling
 from raft_stereo_tpu.config import (RaftStereoConfig, RequestTier,
                                     parse_tier)
 from raft_stereo_tpu.eval.runner import (early_exit_enabled,
@@ -129,12 +129,20 @@ from raft_stereo_tpu.serving.sessions import (SessionsDisabled, SessionStore,
                                               frame_delta, frame_thumbnail,
                                               handoff_session_ids,
                                               parse_handoff_blob)
+from raft_stereo_tpu.telemetry.spans import Phases, SpanTracer, clock
 
 log = logging.getLogger(__name__)
 
 # The model's divisibility constraint: every pad grid must be a multiple
 # of this, and the adaptive policy can never refine below it.
 MODEL_DIVIS = 32
+
+# Host phases of one dispatch, in the order the worker thread runs them, and
+# of one request on its HTTP thread (spans ``serve.<name>``,
+# docs/architecture.md "Tracing").
+WORKER_PHASES = ("wait_work", "assemble", "upload", "execute", "fetch",
+                 "account", "respond")
+HTTP_PHASES = ("decode", "admission", "encode")
 
 # Executable families a (bucket, batch, tier) compiles under
 # (eval/runner.make_forward): the base sessionless program, the
@@ -864,6 +872,13 @@ class _XlTier:
     groups: List[_XlGroup]
 
 
+def _host_bytes(tree) -> int:
+    """Bytes of the NumPy leaves of ``tree`` (``None`` leaves count 0)."""
+    import jax
+
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+
+
 class BucketPolicy:
     """Maps a raw image (H, W) to its padded dispatch bucket (Hp, Wp).
 
@@ -983,8 +998,6 @@ class ServingEngine:
                  tracer=None):
         import jax
 
-        from raft_stereo_tpu.telemetry.spans import SpanTracer
-
         self.serve_cfg = serve_cfg
         # Request-path span tracer (telemetry/spans.py).  At the default
         # sample rate 0.0 every start_trace returns None and the span
@@ -1008,6 +1021,14 @@ class ServingEngine:
         self.devices = list(devices)
         self.metrics = ServingMetrics(registry,
                                       max_batch=serve_cfg.max_batch)
+        # Host phases (telemetry/spans.py): every boundary of a dispatch on
+        # the worker thread and of a request on its HTTP thread, as a span
+        # on the profiler's clock, ``serve_phase_seconds{phase=}`` and the
+        # sampled request's trace, from one pair of clock reads each.
+        self.phases = Phases("serve.", WORKER_PHASES + HTTP_PHASES,
+                             self.metrics.registry, "serve_phase_seconds",
+                             self.tracer)
+        self._dispatch_seq = itertools.count(1)
         # Compile-cost registry (telemetry/costs.py): one per engine,
         # shared by all workers — same bucket => same program => one cost
         # record per (shape, batch) key.  None (default) leaves the jit
@@ -1128,7 +1149,7 @@ class ServingEngine:
             max_queue=serve_cfg.max_queue, metrics=self.metrics,
             edf=serve_cfg.edf_scheduler,
             edf_max_slack_s=serve_cfg.edf_max_slack_ms / 1e3,
-            latency_fn=self._dispatch_latency_estimate)
+            latency_fn=self._dispatch_latency_estimate, clock=clock)
         # ---- XL tier: mesh-sharded device groups (round 17) ------------
         # ``self.xl`` is an _XlTier (mesh spec + per-group meshes +
         # replicated variables) or None — None either because no xl_mesh
@@ -1874,7 +1895,7 @@ class ServingEngine:
         already happened.  None (the default) keeps the local-sampling
         behavior byte-for-byte.
         """
-        t_admit = time.perf_counter()
+        t_admit = clock()
         model = self.resolve_model(model)
         left, right = np.asarray(left), np.asarray(right)
         if left.ndim != 3 or left.shape != right.shape:
@@ -1984,7 +2005,7 @@ class ServingEngine:
                            raw_shape=tuple(left.shape[:2]),
                            frame_index=frame_index, scene_cut=scene_cut,
                            frame_delta=frame_delta_v, ctx_init=ctx_init)
-        now = time.monotonic()
+        now = clock()
         deadline_ms = (deadline_ms if deadline_ms is not None
                        else self.serve_cfg.default_deadline_ms)
         req = Request(bucket=(hp, wp), payload=payload,
@@ -2021,11 +2042,13 @@ class ServingEngine:
         else:
             trace = self.tracer.start_trace("serve.request",
                                             **trace_attrs)
+        # (admission starts in whichever submit took the request and ends
+        # here: no scope covers it, so it has no event on the profiler)
+        self.phases.record("admission", t_admit, now,
+                           () if trace is None else (trace,),
+                           bucket=str(req.bucket))
         if trace is not None:
             req.trace = trace
-            self.tracer.add_span("serve.admission", trace,
-                                 t_admit, time.perf_counter(),
-                                 bucket=str(req.bucket))
             req.queue_span = self.tracer.start_span("serve.queue", trace)
             req.future.add_done_callback(
                 lambda f, r=req: self._finish_request_trace(r, f))
@@ -2165,7 +2188,7 @@ class ServingEngine:
             queue_wait_s=max(res.queue_wait_s for res in results),
             device_s=max(res.device_s for res in results),
             fetch_s=max(res.fetch_s for res in results),
-            total_s=time.perf_counter() - t_admit,
+            total_s=clock() - t_admit,
             batch_size=max(res.batch_size for res in results),
             iters_used=max(iters) if iters else None,
             tier=tier, requested_tier=requested_tier,
@@ -2245,7 +2268,7 @@ class ServingEngine:
                 # the draft rather than double every request's cost).
                 res.draft_tier = draft_tier
                 res.draft_confidence = conf
-                res.total_s = time.perf_counter() - t_admit
+                res.total_s = clock() - t_admit
                 if self._cascade_drafts is not None:
                     self._cascade_drafts.inc()
                 agg.set_result(res)
@@ -2271,7 +2294,7 @@ class ServingEngine:
                 res2.escalated = True
                 res2.draft_tier = draft_tier
                 res2.draft_confidence = conf
-                res2.total_s = time.perf_counter() - t_admit
+                res2.total_s = clock() - t_admit
                 agg.set_result(res2)
 
             ereq.future.add_done_callback(on_escalated)
@@ -2358,7 +2381,7 @@ class ServingEngine:
             queue_wait_s=max(res.queue_wait_s for res in results),
             device_s=max(res.device_s for res in results),
             fetch_s=max(res.fetch_s for res in results),
-            total_s=time.perf_counter() - t_admit,
+            total_s=clock() - t_admit,
             batch_size=max(res.batch_size for res in results),
             iters_used=max(iters) if iters else None,
             tier=final.tier, requested_tier=final.requested_tier,
@@ -2416,7 +2439,7 @@ class ServingEngine:
             raise SessionsDisabled(
                 "this engine runs without a session store — construct it "
                 "with ServeConfig(sessions=True) to stream")
-        t_admit = time.perf_counter()
+        t_admit = clock()
         tier, requested_tier = self._admit_tier(tier, degradable)
         left, right = np.asarray(left), np.asarray(right)
         if left.ndim != 3 or left.shape != right.shape:
@@ -3354,7 +3377,15 @@ class ServingEngine:
                     return
                 time.sleep(min(delay, 0.05))
                 continue
-            batch = self.queue.pop(want=want, sizes=sizes)
+            # The worker waits for work: an empty queue, or requests the
+            # batcher holds back for a fuller batch (``queued_at_start``
+            # tells them apart).
+            with self.phases.phase("wait_work",
+                                   queued_at_start=self.queue.depth) as wait:
+                batch = self.queue.pop(want=want, sizes=sizes)
+                wait.set(popped=len(batch or ()))
+                wait.traces = [r.trace for r in batch or ()
+                               if r.trace is not None]
             if batch is None:       # queue closed: worker shutdown
                 return
             try:
@@ -3384,7 +3415,7 @@ class ServingEngine:
                       unresolved=len(pending),
                       error=f"{type(exc).__name__}: {exc}")
         retry: List[Request] = []
-        now_pc = time.perf_counter()
+        now_pc = clock()
         for r in pending:
             r.attempts += 1
             if getattr(r.payload, "session", None) is not None:
@@ -3409,7 +3440,7 @@ class ServingEngine:
         for r in retry:
             if r.trace is not None:
                 self.tracer.add_span(
-                    "serve.retry", r.trace, now_pc, time.perf_counter(),
+                    "serve.retry", r.trace, now_pc, clock(),
                     attempt=r.attempts, device=widx,
                     backoff_ms=round(backoff_s * 1e3, 3),
                     error=type(exc).__name__)
@@ -3511,47 +3542,52 @@ class ServingEngine:
 
     def _run_chunk(self, widx: int, batch: List[Request]) -> None:
         import jax
+        import jax.tree_util as jtu
 
-        t_pickup = time.monotonic()
-        waits = [t_pickup - r.t_enqueue for r in batch]
         bucket = batch[0].bucket
         # The queue groups by (bucket, tier, family, model): every
         # member of this chunk shares all four coordinates.
         tier = batch[0].tier
         family = batch[0].family
         model = batch[0].model
-        bundle = self._models[model]
-        cache_tier = self._cache_tier(tier, model)
         n = len(batch)
         xl = family == FAMILY_XL
-        if xl:
-            group = self._xl_group(widx)
-            device = group.sharding   # replicated upload over the mesh
-            device_label = f"xl:{group.label}"
-        else:
-            device = self.devices[widx]
-            device_label = str(device)
+        # Sampled requests: the phases below share the chunk's time window
+        # but land in each request's own trace (a trace stays
+        # self-contained).
+        traces = [r.trace for r in batch if r.trace is not None]
 
-        # Sampled requests: the queue leg ends at worker pickup; the
-        # dispatch/fetch spans below share the chunk's time window but land
-        # in each request's own trace (a trace stays self-contained).
-        sampled = [r for r in batch if r.trace is not None]
-        p_pickup = time.perf_counter() if sampled else 0.0
-        for r in sampled:
-            if r.queue_span is not None and r.queue_span.t_end is None:
-                r.queue_span.set_attr("batch_size", n)
-                self.tracer.finish(r.queue_span)
+        def phase(name: str, **attrs):
+            return self.phases.phase(name, traces, batch_size=n, **attrs)
 
-        # Fault injection (serving/chaos.py): one attribute check when
-        # chaos is off — the no-chaos dispatch path is the round-12
-        # program, bitwise-unchanged (tests/test_resilience.py).  The
-        # injected exceptions propagate into the worker loop's recovery
-        # path exactly like organic faults.
-        if self.chaos is not None:
-            self.chaos.on_compile(widx)
-            self.chaos.on_dispatch(widx)
+        with phase("assemble", bucket=str(bucket),
+                   seq=next(self._dispatch_seq)) as assemble:
+            # The queue leg ends at worker pickup: this phase's start.
+            pickup = assemble.t_start
+            for r in batch:
+                if r.queue_span is not None and r.queue_span.t_end is None:
+                    r.queue_span.set_attr("batch_size", n)
+                    r.queue_span.t_end = pickup
+                    self.tracer.finish(r.queue_span)
+            bundle = self._models[model]
+            cache_tier = self._cache_tier(tier, model)
+            if xl:
+                group = self._xl_group(widx)
+                device = group.sharding   # replicated upload over the mesh
+                device_label = f"xl:{group.label}"
+            else:
+                device = self.devices[widx]
+                device_label = str(device)
 
-        with profiling.annotate("serve.device"):
+            # Fault injection (serving/chaos.py): one attribute check when
+            # chaos is off — the no-chaos dispatch path is the round-12
+            # program, bitwise-unchanged (tests/test_resilience.py).  The
+            # injected exceptions propagate into the worker loop's recovery
+            # path exactly like organic faults.
+            if self.chaos is not None:
+                self.chaos.on_compile(widx)
+                self.chaos.on_dispatch(widx)
+
             # ONE batch-n dispatch through the (bucket, n) executable.
             # n == 1 is the identical program the solo InferenceRunner
             # compiles (make_forward), so that bucket stays bitwise-equal
@@ -3561,42 +3597,43 @@ class ServingEngine:
                                     family=family, model=model)
             adaptive = False if xl else early_exit_enabled(
                 bundle.tier_models[cache_tier].config)
-            p1 = np.stack([r.payload.left for r in batch])
-            p2 = np.stack([r.payload.right for r in batch])
-            args = [self._vars_for(widx, cache_tier, model),
-                    jax.device_put(p1, device),
-                    jax.device_put(p2, device)]
+            variables = self._vars_for(widx, cache_tier, model)
+            host_args = [np.stack([r.payload.left for r in batch]),
+                         np.stack([r.payload.right for r in batch])]
             if family in _WARM_FAMILIES:
                 # Warm session frames: the batch's previous-frame states
                 # stack into the program's flow_init input.
-                fi = np.stack([r.payload.flow_init for r in batch]
-                              ).astype(np.float32)
-                args.append(jax.device_put(fi, device))
+                host_args.append(
+                    np.stack([r.payload.flow_init for r in batch]
+                             ).astype(np.float32))
             if family in _H_IN_FAMILIES:
                 # Hidden warm start: the batch members' per-level hidden
                 # trees stack leaf-wise (frames of DIFFERENT sessions
                 # batch together; each leaf is per-image along axis 0).
-                import jax.tree_util as jtu
-                hidden_stacked = jtu.tree_map(
+                host_args.append(jtu.tree_map(
                     lambda *xs: np.stack(xs),
-                    *[r.payload.hidden_init for r in batch])
-                args.append(jax.device_put(hidden_stacked, device))
+                    *[r.payload.hidden_init for r in batch]))
             if family in _CTX_REUSE_FAMILIES:
                 # Context reuse: the batch members' cached bundles stack
                 # leaf-wise (frames of DIFFERENT static-scene sessions
                 # batch together; each leaf is per-image along axis 0).
-                import jax.tree_util as jtu
-                ctx_stacked = jtu.tree_map(
+                host_args.append(jtu.tree_map(
                     lambda *xs: np.stack(xs),
-                    *[r.payload.ctx_init for r in batch])
-                args.append(jax.device_put(ctx_stacked, device))
+                    *[r.payload.ctx_init for r in batch]))
+
+        with phase("upload", bytes=_host_bytes(host_args)) as upload:
+            # ``device_put`` returns at once: the runtime lays the arrays
+            # out and copies them behind it, and the launch waits for that
+            # inside ``execute``.
+            args = [variables] + [jax.device_put(a, device)
+                                  for a in host_args]
+
+        with phase("execute") as execute:
             out = fwd(*args)
             # The device leg's stop clock.
             jax.block_until_ready(out)
-        t_ready = time.monotonic()
-        p_ready = time.perf_counter() if sampled else 0.0
 
-        with profiling.annotate("serve.fetch"):
+        with phase("fetch") as fetch:
             flow_low_padded = None
             ctx_out = None
             hidden_out = None
@@ -3604,14 +3641,12 @@ class ServingEngine:
                 # The ctx-saving cold program appends the context bundle
                 # LAST (eval/runner.make_forward): peel it off, fetch it
                 # to host leaves (numpy; bf16 leaves ride as ml_dtypes).
-                import jax.tree_util as jtu
                 out, ctx_dev = out[:-1], out[-1]
                 ctx_out = jtu.tree_map(lambda x: np.asarray(x), ctx_dev)
             if family in _H_OUT_FAMILIES:
                 # The hidden tree rides just before the ctx bundle
                 # (return order: flow_up, flow_low[, iters][, conf]
                 # [, hidden][, ctx]) — now the LAST remaining element.
-                import jax.tree_util as jtu
                 out, hidden_dev = out[:-1], out[-1]
                 hidden_out = jtu.tree_map(lambda x: np.asarray(x),
                                           hidden_dev)
@@ -3646,127 +3681,137 @@ class ServingEngine:
                     (flows, flow_low), iters_used = out, self.serve_cfg.iters
                 flow_low_padded = np.asarray(flow_low)  # (n, Hp/f, Wp/f)
             flows_padded = np.asarray(flows)      # (n, Hp, Wp)
-        t_fetched = time.monotonic()
-        p_fetched = time.perf_counter() if sampled else 0.0
-        for r in sampled:
-            self.tracer.add_span(
-                "serve.dispatch", r.trace, p_pickup, p_ready,
-                bucket=str(bucket), batch_size=n, device=device_label,
-                iters_used=iters_used, attempt=r.attempts + 1,
-                **({"tier": tier} if tier is not None else {}))
-            self.tracer.add_span("serve.fetch", r.trace, p_ready, p_fetched,
-                                 batch_size=n)
+            fetch.set(bytes=_host_bytes(
+                [flows_padded, flow_low_padded, conf_padded, hidden_out,
+                 ctx_out]))
 
-        device_s = t_ready - t_pickup
-        fetch_s = t_fetched - t_ready
-        # Per-group dispatch-latency EWMA: the EDF scheduler's bounded
-        # slack subtracts this from the nearest deadline.
-        self._note_dispatch_latency(batch[0].group_key,
-                                    device_s + fetch_s)
-        self.metrics.observe_dispatch(n)
-        if xl:
-            self.metrics.xl_dispatches.inc()
-            self._note_xl_hbm(bucket, n)
-        # Trip-count telemetry: every dispatch lands in the per-tier
-        # infer_gru_iters_used histogram (fixed-depth paths report the
-        # configured depth, so tier histograms are directly comparable)
-        # and early-exit dispatches accumulate the iterations they saved.
-        self.metrics.observe_iters_used(
-            "xl" if xl else (tier or "default"), iters_used,
-            self.serve_cfg.iters, n_requests=n)
-        self.metrics.device_time.observe(device_s)
-        self.metrics.fetch_time.observe(fetch_s)
-        # Padding-waste accounting + the policy feedback loop: every
-        # dispatched pixel beyond the requests' real image pixels is pure
-        # waste at fixed GRU depth.  With the engine's exact-occupancy
-        # batch axis the only waste left is spatial padding — which is
-        # exactly what BucketPolicy.note adapts on.
-        real_px = sum(r.payload.padder.ht * r.payload.padder.wd
-                      for r in batch)
-        dispatched_px = n * bucket[0] * bucket[1]
-        self.metrics.observe_padding(bucket, real_px, dispatched_px)
-        self.policy.note(bucket, real_px, dispatched_px)
-        # MFU numerator: the batch-n executable's model flops, once per
-        # dispatch.  NOTE XLA's cost_analysis counts a loop body ONCE
-        # regardless of trip count (scan and while alike —
-        # tools/cost_report.py records both undercounts), so this
-        # numerator never overstates under early exit; scale phase flops
-        # by the observed iters_used for honest per-phase MFU
-        # (cost_report --observed_iters).
-        if self._mfu is not None:
-            rec = self.compiled_cost(bucket, batch=n, tier=tier,
-                                     family=family, model=model)
-            if rec is not None and rec.flops:
-                self.metrics.dispatched_flops.inc(rec.flops)
-                self._mfu.note(rec.flops)
-        self.metrics.note_batch_done()
-        if model is not None:
-            # Per-model request accounting (named models only: the
-            # implicit model's /metrics stay byte-identical to pre-
-            # registry builds).
-            self.metrics.observe_model_request(bundle.name, bundle.version,
-                                               n_requests=n)
-        self._note_warm(widx, bucket, n, cache_tier, family, model)
-        for i, (r, fp, wait) in enumerate(zip(batch, flows_padded, waits)):
-            exemplar = r.trace.trace_id if r.trace is not None else None
-            p_respond = time.perf_counter() if exemplar is not None else 0.0
-            flow = r.payload.padder.unpad(fp[None])[0]
-            if flow.dtype != np.float32:             # half-precision fetch
-                flow = flow.astype(np.float32)
-            total = t_fetched - r.t_enqueue
-            self.metrics.queue_wait.observe(wait, exemplar=exemplar)
-            self.metrics.total_latency.observe(total, exemplar=exemplar)
-            self.metrics.completed.inc()
-            ctx_i = None
-            if ctx_out is not None:
-                # Per-member slice of the batch's returned bundle: the
-                # session stores a batch-axis-free copy it can stack
-                # into any later dispatch.
-                import jax.tree_util as jtu
-                ctx_i = jtu.tree_map(lambda leaf, j=i: leaf[j], ctx_out)
-            hidden_i = None
-            if hidden_out is not None:
-                import jax.tree_util as jtu
-                hidden_i = jtu.tree_map(lambda leaf, j=i: leaf[j],
-                                        hidden_out)
-            conf_i = None
-            conf_mean = None
-            if conf_padded is not None:
-                conf_i = r.payload.padder.unpad(
-                    conf_padded[i][None])[0]
-                if conf_i.dtype != np.float32:
-                    conf_i = conf_i.astype(np.float32)
-                conf_i = np.ascontiguousarray(conf_i)
-                conf_mean = float(conf_i.mean())
-                if self.quality is not None:
-                    self.quality.observe(tier or "default",
-                                         bundle.coord, conf_mean,
-                                         exemplar=exemplar)
-            r.future.set_result(ServeResult(
-                flow=np.ascontiguousarray(flow), queue_wait_s=wait,
-                device_s=device_s, fetch_s=fetch_s, total_s=total,
-                batch_size=n, iters_used=iters_used,
-                tier="xl" if xl else tier,
-                mesh=self.xl.label if xl else None,
-                requested_tier=r.requested_tier, attempts=r.attempts + 1,
-                session_id=r.session_id,
-                frame_index=r.payload.frame_index,
-                warm=(family in _WARM_FAMILIES),
-                scene_cut=r.payload.scene_cut,
-                frame_delta=r.payload.frame_delta,
-                flow_low=(np.ascontiguousarray(flow_low_padded[i])
-                          if flow_low_padded is not None else None),
-                ctx_cached=(family in _CTX_REUSE_FAMILIES),
-                ctx=ctx_i,
-                hidden=hidden_i,
-                warm_hidden=(family in _H_IN_FAMILIES),
-                model=bundle.name,
-                model_version=bundle.version,
-                confidence=conf_i, confidence_mean=conf_mean,
-                trace_id=exemplar))
-            if exemplar is not None:
-                self.tracer.add_span("serve.respond", r.trace, p_respond,
-                                     time.perf_counter())
+        with phase("account"):
+            # The legs the histograms, the response headers and the sampled
+            # trace have always reported, from the phases' own readings:
+            # device = pickup -> outputs ready (stack and upload included).
+            device_s = assemble.seconds + upload.seconds + execute.seconds
+            fetch_s = fetch.seconds
+            for r in batch:
+                if r.trace is not None:
+                    self.tracer.add_span(
+                        "serve.dispatch", r.trace, pickup, execute.t_end,
+                        bucket=str(bucket), batch_size=n,
+                        device=device_label, iters_used=iters_used,
+                        attempt=r.attempts + 1,
+                        **({"tier": tier} if tier is not None else {}))
+            # Per-group dispatch-latency EWMA: the EDF scheduler's bounded
+            # slack subtracts this from the nearest deadline.
+            self._note_dispatch_latency(batch[0].group_key,
+                                        device_s + fetch_s)
+            self.metrics.observe_dispatch(n)
+            if xl:
+                self.metrics.xl_dispatches.inc()
+                self._note_xl_hbm(bucket, n)
+            # Trip-count telemetry: every dispatch lands in the per-tier
+            # infer_gru_iters_used histogram (fixed-depth paths report the
+            # configured depth, so tier histograms are directly comparable)
+            # and early-exit dispatches accumulate the iterations they
+            # saved.
+            self.metrics.observe_iters_used(
+                "xl" if xl else (tier or "default"), iters_used,
+                self.serve_cfg.iters, n_requests=n)
+            self.metrics.device_time.observe(device_s)
+            self.metrics.fetch_time.observe(fetch_s)
+            # Padding-waste accounting + the policy feedback loop: every
+            # dispatched pixel beyond the requests' real image pixels is
+            # pure waste at fixed GRU depth.  With the engine's
+            # exact-occupancy batch axis the only waste left is spatial
+            # padding — which is exactly what BucketPolicy.note adapts on.
+            real_px = sum(r.payload.padder.ht * r.payload.padder.wd
+                          for r in batch)
+            dispatched_px = n * bucket[0] * bucket[1]
+            self.metrics.observe_padding(bucket, real_px, dispatched_px)
+            self.policy.note(bucket, real_px, dispatched_px)
+            # MFU numerator: the batch-n executable's model flops, once per
+            # dispatch.  NOTE XLA's cost_analysis counts a loop body ONCE
+            # regardless of trip count (scan and while alike —
+            # tools/cost_report.py records both undercounts), so this
+            # numerator never overstates under early exit; scale phase
+            # flops by the observed iters_used for honest per-phase MFU
+            # (cost_report --observed_iters).
+            if self._mfu is not None:
+                rec = self.compiled_cost(bucket, batch=n, tier=tier,
+                                         family=family, model=model)
+                if rec is not None and rec.flops:
+                    self.metrics.dispatched_flops.inc(rec.flops)
+                    self._mfu.note(rec.flops)
+            self.metrics.note_batch_done()
+            if model is not None:
+                # Per-model request accounting (named models only: the
+                # implicit model's /metrics stay byte-identical to pre-
+                # registry builds).
+                self.metrics.observe_model_request(
+                    bundle.name, bundle.version, n_requests=n)
+            self._note_warm(widx, bucket, n, cache_tier, family, model)
+
+        # One span a dispatch on the profiler and in the histogram; a
+        # sampled request gets its own, around its own answer.
+        with self.phases.phase("respond", batch_size=n):
+            for i, (r, fp) in enumerate(zip(batch, flows_padded)):
+                exemplar = r.trace.trace_id if r.trace is not None else None
+                t_respond = clock() if exemplar is not None else 0.0
+                flow = r.payload.padder.unpad(fp[None])[0]
+                if flow.dtype != np.float32:         # half-precision fetch
+                    flow = flow.astype(np.float32)
+                wait = pickup - r.t_enqueue
+                total = fetch.t_end - r.t_enqueue
+                self.metrics.queue_wait.observe(wait, exemplar=exemplar)
+                self.metrics.total_latency.observe(total, exemplar=exemplar)
+                self.metrics.completed.inc()
+                ctx_i = None
+                if ctx_out is not None:
+                    # Per-member slice of the batch's returned bundle: the
+                    # session stores a batch-axis-free copy it can stack
+                    # into any later dispatch.
+                    ctx_i = jtu.tree_map(lambda leaf, j=i: leaf[j], ctx_out)
+                hidden_i = None
+                if hidden_out is not None:
+                    hidden_i = jtu.tree_map(lambda leaf, j=i: leaf[j],
+                                            hidden_out)
+                conf_i = None
+                conf_mean = None
+                if conf_padded is not None:
+                    conf_i = r.payload.padder.unpad(
+                        conf_padded[i][None])[0]
+                    if conf_i.dtype != np.float32:
+                        conf_i = conf_i.astype(np.float32)
+                    conf_i = np.ascontiguousarray(conf_i)
+                    conf_mean = float(conf_i.mean())
+                    if self.quality is not None:
+                        self.quality.observe(tier or "default",
+                                             bundle.coord, conf_mean,
+                                             exemplar=exemplar)
+                r.future.set_result(ServeResult(
+                    flow=np.ascontiguousarray(flow), queue_wait_s=wait,
+                    device_s=device_s, fetch_s=fetch_s, total_s=total,
+                    batch_size=n, iters_used=iters_used,
+                    tier="xl" if xl else tier,
+                    mesh=self.xl.label if xl else None,
+                    requested_tier=r.requested_tier,
+                    attempts=r.attempts + 1,
+                    session_id=r.session_id,
+                    frame_index=r.payload.frame_index,
+                    warm=(family in _WARM_FAMILIES),
+                    scene_cut=r.payload.scene_cut,
+                    frame_delta=r.payload.frame_delta,
+                    flow_low=(np.ascontiguousarray(flow_low_padded[i])
+                              if flow_low_padded is not None else None),
+                    ctx_cached=(family in _CTX_REUSE_FAMILIES),
+                    ctx=ctx_i,
+                    hidden=hidden_i,
+                    warm_hidden=(family in _H_IN_FAMILIES),
+                    model=bundle.name,
+                    model_version=bundle.version,
+                    confidence=conf_i, confidence_mean=conf_mean,
+                    trace_id=exemplar))
+                if exemplar is not None:
+                    self.tracer.add_span("serve.respond", r.trace,
+                                         t_respond, clock())
 
     # ---------------------------------------------------------- fleet hooks
     def set_brownout_floor(self, level: int) -> int:

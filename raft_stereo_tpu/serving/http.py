@@ -100,6 +100,13 @@ Endpoints:
   serving process (telemetry/trace.py); optional JSON body
   ``{"duration_ms": N}``; replies with the trace directory, 409 while a
   window is already open.
+* Host phases (telemetry/spans.py ``Phases``): this module scopes
+  ``serve.decode`` (body read + decode) and ``serve.encode`` (encode +
+  write) on the request's thread; the engine scopes ``serve.wait_work`` /
+  ``assemble`` / ``upload`` / ``execute`` / ``fetch`` / ``account`` /
+  ``respond`` on the worker's.  Each is an event in a ``/debug/trace``
+  capture, on the device events' clock, and a
+  ``serve_phase_seconds{phase=}`` histogram in ``/metrics``.
 * ``GET /debug/spans`` / ``GET /debug/stacks`` / ``GET|POST
   /debug/flightrecorder`` / ``GET /debug/compiles`` — the same debug
   surface the training endpoint serves (telemetry/http.py
@@ -462,10 +469,11 @@ def make_handler(service: StereoService,
                 length = int(self.headers.get("Content-Length", 0))
                 if not 0 < length <= MAX_BODY_BYTES:
                     raise ValueError(f"Content-Length {length} out of range")
-                body = self.rfile.read(length)
-                left, right = _decode_pair(
-                    body, self.headers.get("Content-Type",
-                                           "application/x-npz"))
+                with service.phases.phase("decode", bytes=length):
+                    body = self.rfile.read(length)
+                    left, right = _decode_pair(
+                        body, self.headers.get("Content-Type",
+                                               "application/x-npz"))
                 deadline_hdr = self.headers.get("X-Deadline-Ms")
                 deadline_ms: Optional[float] = (
                     float(deadline_hdr) if deadline_hdr else None)
@@ -595,14 +603,6 @@ def make_handler(service: StereoService,
                 log.exception("inference failed")
                 self._reply_json(500, {"error": str(e)})
                 return
-            try:
-                payload, ctype = _encode_disparity(
-                    result.disparity, fmt, confidence=result.confidence)
-            except ValueError as e:
-                # conf_png on a result without a confidence map (xl
-                # tier, or a confidence-off engine): client error.
-                self._reply_json(400, {"error": str(e)})
-                return
             headers = [
                 ("X-Queue-Wait-Ms", f"{result.queue_wait_s * 1e3:.2f}"),
                 ("X-Device-Ms", f"{result.device_s * 1e3:.2f}"),
@@ -656,7 +656,17 @@ def make_handler(service: StereoService,
                 if result.frame_delta is not None:
                     headers.append(("X-Frame-Delta",
                                     f"{result.frame_delta:.2f}"))
-            self._reply(200, payload, ctype, extra_headers=headers)
+            with service.phases.phase("encode") as encode:
+                try:
+                    payload, ctype = _encode_disparity(
+                        result.disparity, fmt, confidence=result.confidence)
+                except ValueError as e:
+                    # conf_png on a result without a confidence map (xl
+                    # tier, or a confidence-off engine): client error.
+                    self._reply_json(400, {"error": str(e)})
+                    return
+                encode.set(bytes=len(payload))
+                self._reply(200, payload, ctype, extra_headers=headers)
 
         def do_DELETE(self):
             url = urlparse(self.path)

@@ -125,7 +125,9 @@ def handle_debug_get(path: str, query: str,
         if "exemplars=1" in query:
             exemplars = {}
             if registry is not None:
-                for name, inst in sorted(registry.items()):
+                # by name only: members of a labelled family share theirs
+                for name, inst in sorted(registry.items(),
+                                         key=lambda kv: kv[0]):
                     ex = getattr(inst, "exemplars", None)
                     if ex is not None and ex():
                         exemplars[name] = ex()

@@ -40,6 +40,15 @@ Adoption honors the upstream sampling decision (the codec only travels on
 sampled traces), so a replica at ``sample_rate=0`` still records adopted
 traces — and still records nothing at all when no header arrives, which
 keeps the zero-overhead-when-disabled contract intact.
+
+**Host phases (PR 25).**  ``Phases.phase(name, ...)`` scopes one host
+phase of a dispatch (serving engine) or of a call (inference runner): it
+opens a ``jax.profiler.TraceAnnotation`` — the profiler writes that into
+its own trace, on the device events' time base — and reads ``clock`` once
+on entry and once on exit.  The same two readings feed the always-on
+``<family>_phase_seconds{phase=...}`` histogram and, through ``add_span``,
+the trace of every sampled request the phase served.  With no capture open
+an annotation is a flag check; with sampling off no span is made.
 """
 
 from __future__ import annotations
@@ -49,12 +58,21 @@ import dataclasses
 import random
 import threading
 import time
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Iterable, List, Optional, Sequence
+
+# Importing jax initialises no backend (the fleet router imports this module
+# and must never hold the chip: tests/test_host_phases.py).
+from jax.profiler import TraceAnnotation
+
+# The one clock of spans and phases.  On POSIX CPython it is the clock
+# ``time.monotonic`` reads too (pytime.c), but callers take no notice: the
+# serving engine stamps its queue with this one.
+clock = time.perf_counter
 
 # Monotonic->wall anchor taken once at import: Chrome trace timestamps are
 # microseconds on one consistent clock, and anchoring perf_counter to wall
 # time makes span timestamps comparable with event-log ``ts`` fields.
-_ANCHOR_PERF = time.perf_counter()
+_ANCHOR_PERF = clock()
 _ANCHOR_WALL = time.time()
 
 
@@ -139,7 +157,7 @@ class Span:
 
     @property
     def duration_s(self) -> float:
-        return (self.t_end or time.perf_counter()) - self.t_start
+        return (self.t_end or clock()) - self.t_start
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -282,7 +300,7 @@ class SpanTracer:
     def _open(self, name: str, trace: Trace, parent_id: Optional[str],
               attrs: Dict[str, object]) -> Span:
         return Span(name=name, trace_id=trace.trace_id, span_id=_new_id(32),
-                    parent_id=parent_id, t_start=time.perf_counter(),
+                    parent_id=parent_id, t_start=clock(),
                     attrs=dict(attrs),
                     thread=threading.current_thread().name)
 
@@ -317,7 +335,7 @@ class SpanTracer:
         if span is None:
             return
         if span.t_end is None:
-            span.t_end = time.perf_counter()
+            span.t_end = clock()
         with self._lock:
             if span._ringed:
                 return
@@ -365,6 +383,83 @@ class SpanTracer:
                     "ring_capacity": self._ring.maxlen,
                     "traces_started": self.traces_started,
                     "traces_sampled": self.traces_sampled}
+
+
+class Phase:
+    """One host phase, scoped: ``with phases.phase("upload") as ph``.
+    ``t_start`` / ``t_end`` are the phase's two ``clock`` readings, there
+    for the caller to derive its own legs from (pickup -> ready) without
+    reading the clock again."""
+
+    __slots__ = ("name", "attrs", "traces", "t_start", "t_end", "_owner",
+                 "_annotation")
+
+    def __init__(self, owner: "Phases", name: str,
+                 traces: Sequence[Trace], attrs: Dict[str, object]):
+        self._owner = owner
+        self.name = name
+        self.traces = traces
+        self.attrs = attrs
+        self.t_start = self.t_end = 0.0
+
+    @property
+    def seconds(self) -> float:
+        return self.t_end - self.t_start
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the phase's work is done (bytes
+        moved, requests popped): onto the profiler's event and the span."""
+        self.attrs.update(attrs)
+        self._annotation.set_metadata(**attrs)
+
+    def __enter__(self) -> "Phase":
+        self._annotation = TraceAnnotation(self._owner.prefix + self.name,
+                                           **self.attrs)
+        self._annotation.__enter__()
+        self.t_start = clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t_end = clock()
+        self._annotation.__exit__(*exc)
+        self._owner.record(self.name, self.t_start, self.t_end,
+                           self.traces, **self.attrs)
+
+
+class Phases:
+    """The host phases of one component, ``names`` fixed at construction:
+    one ``<metric>{phase=<name>}`` histogram each (with no ``registry``,
+    none), spans named ``<prefix><name>``."""
+
+    def __init__(self, prefix: str, names: Sequence[str],
+                 registry=None, metric: str = "",
+                 tracer: Optional["SpanTracer"] = None):
+        self.prefix = prefix
+        self.tracer = tracer
+        self.histograms = {}
+        for name in names if registry is not None else ():
+            labels = {"phase": name}
+            # get-or-make: runners that share one registry share the family
+            self.histograms[name] = (
+                registry.get(metric, labels) or registry.histogram(
+                    metric, f"host time of one {prefix}<phase> span "
+                            f"(telemetry/spans.py Phases)", labels=labels))
+
+    def phase(self, name: str, traces: Sequence[Trace] = (),
+              **attrs) -> Phase:
+        return Phase(self, name, traces, attrs)
+
+    def record(self, name: str, t_start: float, t_end: float,
+               traces: Sequence[Trace] = (), **attrs) -> None:
+        """Where a phase hands its two readings; also the way in for a
+        phase that starts in one function and ends in another, which no
+        scope (and so no annotation) can cover."""
+        hist = self.histograms.get(name)
+        if hist is not None:
+            hist.observe(t_end - t_start)
+        for trace in traces:
+            self.tracer.add_span(self.prefix + name, trace, t_start, t_end,
+                                 **attrs)
 
 
 def to_chrome_trace(spans: Iterable[Span],

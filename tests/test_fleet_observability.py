@@ -351,8 +351,15 @@ def test_failover_retry_is_two_forward_children_one_trace(fleet3):
                                        b"px")
             assert status == 200
             tid = headers["X-Trace-Id"]
-            spans = [s.to_dict() for s in router.tracer.spans()
-                     if s.trace_id == tid]
+            # the handler closes the root AFTER the reply's last byte
+            deadline = time.monotonic() + 5.0
+            while True:
+                spans = [s.to_dict() for s in router.tracer.spans()
+                         if s.trace_id == tid]
+                if (any(s["name"] == "route.request" for s in spans)
+                        or time.monotonic() > deadline):
+                    break
+                time.sleep(0.01)
             fwd = [s for s in spans if s["name"] == "route.forward"]
             if len(fwd) >= 2:
                 tid_with_retry = tid
