@@ -9,6 +9,7 @@ compilation the same way (reference: evaluate_stereo.py:77-82).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
 import warnings
@@ -393,6 +394,31 @@ class StreamFrame:
         return -self.flow
 
 
+def _fill_edge_padded(buf: np.ndarray, images, pads) -> None:
+    """``buf[:] = np.pad(np.stack(images), .., mode="edge")``, bitwise, in
+    one pass over ``buf`` (n, Hp, Wp, C): each image into its window, then
+    the strips beside it and, corners included, above and below it."""
+    l, r, t, b = pads
+    hp, wp = buf.shape[1:3]
+    for out, image in zip(buf, images):
+        rows = out[t:hp - b]
+        rows[:, l:wp - r] = image
+        rows[:, :l] = rows[:, l:l + 1]
+        rows[:, wp - r:] = rows[:, wp - r - 1:wp - r]
+        out[:t] = out[t]
+        out[hp - b:] = out[hp - b - 1]
+
+
+@functools.partial(jax.jit, static_argnames="pads")
+def _crop_flat(flows, pads):
+    """``InputPadder.unpad`` on the device, flattened.  A 1-D result has one
+    layout, so the fetch arrives row-major; the TPU's compiler lays the
+    forward's own (n, Hp, Wp) result out with H minor and a 3-D crop of it
+    with n minor, and NumPy would be handed those strides."""
+    l, r, t, b = pads
+    return flows[:, t:flows.shape[1] - b, l:flows.shape[2] - r].reshape(-1)
+
+
 class InferenceRunner:
     """``runner(image1, image2)`` → full-resolution disparity-flow (H, W).
 
@@ -518,6 +544,8 @@ class InferenceRunner:
                              getattr(cost_registry, "metrics", None),
                              "infer_phase_seconds")
         self._compiled: Dict[Tuple[int, int], any] = {}
+        # (key, (left, right)): the host staging pair of the latest call
+        self._staging: Tuple = (None, None)
         # Streaming (warm-start) programs live in their own small cache:
         # they carry an extra state output (and, warm, an extra input),
         # so they are distinct executables from the ``_compiled`` ones —
@@ -547,9 +575,9 @@ class InferenceRunner:
         Keyed by the padded shape so distinct raw shapes that pad to the
         same grid share one executable (real KITTI-2015 mixes 375x1242 /
         370x1224 / 376x1241 — all 384x1248 padded; a raw-shape key would
-        compile each).  Padding/unpadding happen on the HOST in NumPy: the
-        device sees exactly one dispatch per image (the per-image product
-        path, bench_product.py)."""
+        compile each).  Padding happens on the HOST in NumPy and the crop
+        of the result is its own tiny program (``_crop_flat``): this one
+        never sees a raw shape."""
         key = (padded_hw, batch)
         if key not in self._compiled:
             while len(self._compiled) >= self.max_cached_shapes:
@@ -604,8 +632,10 @@ class InferenceRunner:
     def __call__(self, image1: np.ndarray, image2: np.ndarray,
                  ) -> Tuple[np.ndarray, float]:
         """Returns ``(flow, seconds)`` — flow is (H, W) x-flow (=-disparity),
-        seconds is the full per-image product path: host->device copy, pad,
-        forward, unpad, and the host fetch of the result.
+        seconds is the full per-image product path: host replicate pad,
+        host->device copy in the caller's dtype (KITTI/eval images arrive
+        uint8, a 4x smaller copy; the program casts on the device),
+        forward, crop, and the host fetch of the result.
 
         The stop clock is the ``np.asarray`` fetch: the product path ends
         with the result on the host.  A first call at a new padded shape
@@ -614,18 +644,7 @@ class InferenceRunner:
         way the reference's 50-image discard absorbs cuDNN autotune
         (reference: evaluate_stereo.py:77-82)."""
         assert image1.ndim == 3 and image1.shape == image2.shape
-
-        def padded(pads):
-            # Host-side replicate pad (NumPy — microseconds) and caller-dtype
-            # upload: KITTI/eval images arrive uint8, so the per-image copy
-            # is 4x smaller; the cast to float happens on device inside the
-            # compiled program.
-            l, r, t, b = pads
-            spec = ((t, b), (l, r), (0, 0))
-            return (np.pad(np.asarray(image1), spec, mode="edge")[None],
-                    np.pad(np.asarray(image2), spec, mode="edge")[None])
-
-        flows, elapsed = self._run_padded(image1.shape, 1, padded)
+        flows, elapsed = self._run_padded((image1,), (image2,))
         return flows[0], elapsed
 
     def run_batch(self, images1, images2) -> Tuple[np.ndarray, float]:
@@ -645,30 +664,43 @@ class InferenceRunner:
                    for im in (*images1, *images2)), \
             "run_batch requires same-shape pairs; pad upstream or use " \
             "per-image calls for mixed shapes"
+        return self._run_padded(images1, images2)
 
-        def padded(pads):
-            l, r, t, b = pads
-            spec = ((0, 0), (t, b), (l, r), (0, 0))
-            return (np.pad(np.stack(images1), spec, mode="edge"),
-                    np.pad(np.stack(images2), spec, mode="edge"))
-
-        return self._run_padded(shape, len(images1), padded)
-
-    def _run_padded(self, shape, n: int, padded
-                    ) -> Tuple[np.ndarray, float]:
+    def _run_padded(self, images1, images2) -> Tuple[np.ndarray, float]:
         """The product path of ``__call__`` and ``run_batch``, each step a
         host phase (``infer.stack_pad`` / ``upload`` / ``execute`` /
-        ``fetch`` / ``unpad``, telemetry/spans.py).  ``padded(pads)`` makes
-        the two (n, Hp, Wp, 3) host arrays.  The seconds run from the first
-        phase's start to the end of ``fetch``, as they always have: the
-        contiguous copy of the unpadded view is outside them."""
+        ``fetch`` / ``unpad``, telemetry/spans.py).  Each input byte is
+        written once, into a padded host pair that is kept for the next
+        call of the same shape, batch and dtype (only the latest pair, so
+        host memory does not grow with ``max_cached_shapes``); the result
+        is cropped on the device and fetched at its own size, row-major, so
+        ``unpad`` is left the reshape (the array is read-only: it is the
+        fetch's own memory).  The seconds run from the first phase's start
+        to the end of ``fetch``, as they always have.  A runner's calls do
+        not interleave (no caller in the repo drives one runner from two
+        threads): the next call refills the pair, after ``execute`` has
+        waited for the program and with it for the upload."""
+        n = len(images1)
+        images1 = [np.asarray(im) for im in images1]
+        images2 = [np.asarray(im) for im in images2]
 
         def phase(name: str, **attrs):
             return self.phases.phase(name, batch_size=n, **attrs)
 
         with phase("stack_pad") as first:
-            padder = InputPadder((1,) + tuple(shape), divis_by=self.divis_by)
-            p1, p2 = padded(padder.pads)
+            h, w, c = images1[0].shape
+            padder = InputPadder((1, h, w, c), divis_by=self.divis_by)
+            l, r, t, b = padder.pads
+            key = ((n, t + h + b, l + w + r, c),
+                   np.result_type(*{im.dtype for im in images1 + images2}))
+            reused = self._staging[0] == key
+            if not reused:
+                self._staging = (None, None)        # free the old pair first
+                self._staging = (key, (np.empty(*key), np.empty(*key)))
+            p1, p2 = self._staging[1]
+            _fill_edge_padded(p1, images1, padder.pads)
+            _fill_edge_padded(p2, images2, padder.pads)
+            first.set(bytes=p1.nbytes + p2.nbytes, reused=reused)
             fwd = self._forward_for(p1.shape[1:3], batch=n)
         with phase("upload", bytes=p1.nbytes + p2.nbytes):
             # returns at once; the launch waits for the copy, in ``execute``
@@ -679,13 +711,12 @@ class InferenceRunner:
             if self.early_exit:
                 out, iters_used = out
                 self._note_iters_used(iters_used)
-            flows_padded = np.asarray(out)
-            fetch.set(bytes=flows_padded.nbytes)
-            flows = padder.unpad(flows_padded)     # pure NumPy slicing
+            flows = np.asarray(_crop_flat(out, pads=padder.pads))
+            fetch.set(bytes=flows.nbytes)
             if flows.dtype != np.float32:          # half-precision fetch
                 flows = flows.astype(np.float32)
         with phase("unpad"):
-            flows = np.ascontiguousarray(flows)
+            flows = flows.reshape(n, h, w)
         return flows, fetch.t_end - first.t_start
 
     # ------------------------------------------------------------- streaming

@@ -255,7 +255,10 @@ def test_run_batch_emits_the_five_infer_events(tiny_model, tmp_path):
     assert [n for n, *_ in evs] == ["infer." + p for p in RUNNER_PHASES] * 2
     assert [st["batch_size"] for *_, st in evs] == [2] * 5 + [1] * 5
     assert evs[1][3]["bytes"] == 2 * 2 * 64 * 64 * 3        # upload
-    assert evs[3][3]["bytes"] == 2 * 64 * 64 * 4            # fetch
+    assert evs[3][3]["bytes"] == 2 * 48 * 64 * 4            # fetch, cropped
+    # the staging pair: the batch of 2 again, then a new one for the single
+    assert evs[0][3]["bytes"] == 2 * 2 * 64 * 64 * 3
+    assert [evs[i][3]["reused"] for i in (0, 5)] == [True, False]
     # the seconds end with the fetch, as they did before the phases
     assert 0 < (evs[3][2] - evs[0][1]) * 1e-9 - seconds < 2e-3
     for p in RUNNER_PHASES:

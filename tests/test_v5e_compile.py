@@ -233,6 +233,24 @@ def test_accuracy_forward_compiles(one_chip, kernels_on):
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float16])
+def test_bulk_crop_compiles_to_one_flat_result(one_chip, dtype):
+    """The bulk runner's crop (eval/runner._crop_flat) at the realtime
+    cell's call of 128 KITTI pairs: its 1-D result is what makes the fetch
+    arrive row-major, and it takes the forward's result in the layout the
+    compiler gives it (no 3-D result whose layout the compiler may pick)."""
+    from raft_stereo_tpu.eval.runner import _crop_flat
+
+    compiled = _crop_flat.lower(_sds((128, 384, 1248), dtype, one_chip),
+                                pads=(3, 3, 4, 5)).compile()
+    (result,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert result.shape == (128 * 375 * 1242,) and result.dtype == dtype
+    memory = compiled.memory_analysis()
+    assert (memory.output_size_in_bytes
+            < 1.01 * result.size * result.dtype.itemsize)
+    assert memory.temp_size_in_bytes < 0.3e9
+
+
 def test_data_parallel_train_step_compiles(data_mesh, kernels_on):
     """``make_train_step(train_cfg, mesh=<data=4>)`` with the default
     ``corr_backend="reg_fused"`` at the published SceneFlow crop (2 of the
