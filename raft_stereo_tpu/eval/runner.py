@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from raft_stereo_tpu.config import RaftStereoConfig
+from raft_stereo_tpu.kernels.corr_lookup import path_choices
 from raft_stereo_tpu.models.raft_stereo import RAFTStereo
 from raft_stereo_tpu.ops.padding import InputPadder
 from raft_stereo_tpu.telemetry.spans import Phases
@@ -675,11 +676,16 @@ class InferenceRunner:
         host memory does not grow with ``max_cached_shapes``); the result
         is cropped on the device and fetched at its own size, row-major, so
         ``unpad`` is left the reshape (the array is read-only: it is the
-        fetch's own memory).  The seconds run from the first phase's start
-        to the end of ``fetch``, as they always have.  A runner's calls do
-        not interleave (no caller in the repo drives one runner from two
-        threads): the next call refills the pair, after ``execute`` has
-        waited for the program and with it for the upload."""
+        fetch's own memory).  The first ``infer.execute`` of a (padded
+        shape, batch) also carries ``compiled=1`` and ``paths``, the
+        choices ``kernels.corr_lookup.log_path_once`` was told while the
+        program was traced (fnet sequential or batched, the lookup's
+        launches, fused gates or Flax a level).  The seconds run from the
+        first phase's start to the end of ``fetch``, as they always have.
+        A runner's calls do not interleave (no caller in the repo drives
+        one runner from two threads): the next call refills the pair,
+        after ``execute`` has waited for the program and with it for the
+        upload."""
         n = len(images1)
         images1 = [np.asarray(im) for im in images1]
         images2 = [np.asarray(im) for im in images2]
@@ -701,12 +707,20 @@ class InferenceRunner:
             _fill_edge_padded(p1, images1, padder.pads)
             _fill_edge_padded(p2, images2, padder.pads)
             first.set(bytes=p1.nbytes + p2.nbytes, reused=reused)
+            builds = (p1.shape[1:3], n) not in self._compiled
             fwd = self._forward_for(p1.shape[1:3], batch=n)
         with phase("upload", bytes=p1.nbytes + p2.nbytes):
             # returns at once; the launch waits for the copy, in ``execute``
             d1, d2 = jnp.asarray(p1), jnp.asarray(p2)
-        with phase("execute"):
+        with phase("execute") as execute:
+            said = path_choices() if builds else None
             out = jax.block_until_ready(fwd(self.variables, d1, d2))
+            if builds:
+                # this call traced and built the executable: the span says
+                # so, with the shape-driven choices the trace made
+                execute.set(compiled=1, paths="; ".join(
+                    m for m, times in path_choices().items()
+                    if times != said.get(m, 0)))
         with phase("fetch") as fetch:
             if self.early_exit:
                 out, iters_used = out

@@ -382,7 +382,13 @@ def alt_lookup_fused(fmap1: jnp.ndarray, fmap2_pyramid: List[jnp.ndarray],
 
     Uses the single-launch all-levels kernel when the whole program's
     Mosaic stack estimate fits the scoped-vmem limit; otherwise one launch
-    per level (which shrinks row blocks for full-res pyramids)."""
+    per level, each with its row block shrunk to the VMEM budget
+    (``row_blk_for``).  Read on the v5e (PERF.md section 5, PR 28): KITTI
+    realtime shapes (W2 156/78, bf16) take the single launch; at 1984x2880
+    (W2 720/360/180/90, D 256) the estimate is 3.1x the limit in
+    float32 and in bfloat16 alike, so a lookup is four launches with row
+    blocks 2/4/8/8, 6.5/3.7/2.8/2.1 ms each on one pair's 496 rows in
+    float32: 8.9 % of the lookup's memory roofline, a quarter of a call."""
     d = fmap1.shape[-1]
     w2s = [f2.shape[2] for f2 in fmap2_pyramid]
     single = (_multi_alt_scoped_bytes(w2s, d, fmap1.dtype.itemsize, radius)

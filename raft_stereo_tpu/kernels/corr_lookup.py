@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,13 +69,26 @@ def _lookup_row_bytes(w2: int, radius: int, itemsize: int) -> int:
 _interpret_override: Optional[bool] = None
 
 
-@functools.lru_cache(maxsize=None)
+# message -> times said, in the order first said.  Trace-time only: a
+# choice is made per traced shape, not per call, so this stays small.
+_path_choices: Dict[str, int] = {}
+
+
 def log_path_once(message: str) -> None:
     """Trace-time record of a shape-driven choice between a kernel and its
     fallback (or between two launch plans), shared by every kernel family
-    of the package.  The cache is the once-per-distinct-message rule: a
-    choice is made per traced shape, not per call."""
-    log.info("kernel path: %s", message)
+    of the package.  Logged once per distinct message; every time it is
+    said is counted, so a caller that traces a program can tell which
+    choices that trace made (``path_choices`` before and after)."""
+    if message not in _path_choices:
+        log.info("kernel path: %s", message)
+    _path_choices[message] = _path_choices.get(message, 0) + 1
+
+
+def path_choices() -> Dict[str, int]:
+    """What ``log_path_once`` has been told in this process: each distinct
+    message with the number of times it was said."""
+    return dict(_path_choices)
 
 
 def log_launch_choice(what: str, w2s, dtype, single: bool) -> None:

@@ -35,18 +35,19 @@ from raft_stereo_tpu.profiling import annotate
 # Extra peak-HBM bytes PER PIXEL the batch-2 fnet concat costs over the
 # sequential path when the stem runs at full resolution (n_downsample<=2):
 # XLA holds both images' full-resolution stem working sets live at once.
-# Measured on the TPU v5 lite chip via tools/fullres_gates.py
+# Measured in float32 on an earlier runtime via tools/fullres_gates.py
 # (FULLRES_GATES_r03.json): 1190 / 1179 / 1166 B/px at 544x960 / 1088x1984
-# / 1984x2880 — stable within ~2%.  The same run measured the sequential
-# path's FPS cost as ZERO or better (-2..-11% i.e. sequential was FASTER
-# at every shape), so the gate only protects the batched path's
-# (historically assumed) scheduling advantage at small shapes.
+# / 1984x2880.  Read again on the TPU v5e in bfloat16 (PERF.md section 6,
+# PR 28, one 1984x2880 pair a call, 32 iterations): the batched path
+# reserves 10.75e9 B at its peak against the sequential path's 5.98e9 B,
+# 836 B/px, and a call takes 2.005 s against 2.003 s.  So the sequential
+# path costs no time at that size and the gate is a memory decision alone.
 _STEM_EXTRA_BYTES_PER_PIXEL = 1180
 # Fraction of device HBM the batched path's EXTRA working set may occupy
-# before the sequential path is chosen.  With the measured bytes/pixel and
-# a 16 GiB chip this lands the threshold at ~1.5 MPix — KITTI/SceneFlow
-# shapes stay batched, Middlebury-F-class frames go sequential (the
-# gate that first made 16.5 MPix frames fit in round 2).
+# before the sequential path is chosen.  With the constant above and the
+# 16.9e9 B a v5e reports this lands the threshold at 1,432,994 pixels of ONE
+# image, whatever the batch: KITTI and SceneFlow shapes stay batched at every
+# batch size the serving ladder has, Middlebury-F-class frames go sequential.
 _SEQ_FNET_HBM_FRACTION = 0.10
 
 # Confidence-map scale (px at feature resolution): the per-pixel
@@ -64,19 +65,44 @@ CONFIDENCE_EWMA_DECAY = 0.8
 
 
 def sequential_fnet_threshold(cfg: RaftStereoConfig) -> int:
-    """Pixel count above which fnet runs the two images sequentially.
+    """Pixel count of one image (H x W; the batch is not counted) from
+    which fnet runs the two images sequentially.
 
     ``cfg.sequential_fnet_pixels`` overrides; otherwise derived from the
     device's HBM so bigger chips keep the batched path longer and smaller
-    chips fall back sooner: threshold = fraction * HBM / measured extra
-    bytes-per-pixel.  The sequential path's measured FPS cost is zero or
-    negative (FULLRES_GATES_r03.json), so the gate is purely a
-    memory-pressure decision."""
+    chips fall back sooner: threshold = fraction * HBM / extra
+    bytes-per-pixel (1,432,994 on a v5e).  On the v5e at 1984x2880 the
+    sequential path costs no time (2.003 s a call against 2.005 s batched)
+    and reserves 4.8e9 B less, so the gate is a memory decision alone;
+    whether that holds at the KITTI sizes below the threshold has not been
+    read on this chip."""
     if cfg.sequential_fnet_pixels is not None:
         return cfg.sequential_fnet_pixels
     from raft_stereo_tpu.profiling import device_hbm_bytes
     return int(_SEQ_FNET_HBM_FRACTION * device_hbm_bytes()
                / _STEM_EXTRA_BYTES_PER_PIXEL)
+
+
+def _fnet_sequential(cfg: RaftStereoConfig, shape, custom_trunk: bool,
+                     record: bool) -> bool:
+    """Whether fnet scans the two images one after the other (a custom
+    trunk always does; else ``sequential_fnet_threshold``, a test on one
+    image's H x W whatever the batch), with the choice recorded as the
+    kernels record theirs."""
+    from raft_stereo_tpu.kernels.corr_lookup import log_path_once
+
+    _, h, w, _ = shape
+    if custom_trunk:
+        sequential, why = True, "a custom trunk streams or shards it"
+    else:
+        threshold = sequential_fnet_threshold(cfg)
+        sequential = h * w >= threshold
+        why = (f"{h * w} px an image {'>=' if sequential else '<'} "
+               f"{threshold}")
+    if record:
+        log_path_once(f"fnet {h}x{w}: "
+                      f"{'sequential' if sequential else 'batched'} ({why})")
+    return sequential
 
 
 class RAFTStereo(nn.Module):
@@ -295,8 +321,8 @@ class RAFTStereo(nn.Module):
             with annotate("fnet"):
                 fmap = self.conv2_out(self.conv2_res(v))
                 fmap1, fmap2 = jnp.split(fmap, 2, axis=0)
-        elif (custom_trunk is not None or image1.shape[1] * image1.shape[2]
-                >= sequential_fnet_threshold(cfg)):
+        elif _fnet_sequential(cfg, image1.shape, custom_trunk is not None,
+                              record=not self.is_initializing()):
             # Full-resolution inputs: the stem runs at FULL image resolution
             # when n_downsample <= 2 (matching the reference's stride gate,
             # core/extractor.py:140), so its activations dominate peak HBM.

@@ -155,7 +155,7 @@ def test_backward_falls_to_per_level_launches_with_same_gradient(
 def test_path_choice_is_logged_once(rng, caplog):
     import logging
 
-    corr_lookup.log_path_once.cache_clear()
+    corr_lookup._path_choices.clear()
     pyr = _pyramid(rng, b=1, h=4, w=24, levels=2)
     coords = jnp.zeros(pyr[0].shape[:3], jnp.float32)
     with caplog.at_level(logging.INFO, logger=corr_lookup.__name__):
@@ -174,7 +174,7 @@ def test_gru_auto_fallback_says_why(caplog):
 
     from raft_stereo_tpu.kernels import gru_fused
 
-    corr_lookup.log_path_once.cache_clear()
+    corr_lookup._path_choices.clear()
     with caplog.at_level(logging.INFO, logger=corr_lookup.__name__):
         assert not gru_fused.gru_fused_should_use(
             "auto", kernel_size=3, w=312, cin=384, ch=128, itemsize=2)

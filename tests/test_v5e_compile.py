@@ -134,18 +134,24 @@ def test_quantized_lookup_compiles(one_chip, monkeypatch, shape, q_dtype):
     assert len(_kernel_calls(compiled)) == 1
 
 
-def test_alt_lookup_compiles_at_middlebury_f(one_chip):
+# bf16 is what a runner below 16 iterations leaves the features in; float32
+# is the published full-resolution command's (``corr_fp32``, the
+# ``fullres.bulk.middlebury-f`` cell).  At W2 = 720/360/180/90 the all-levels
+# launch's working set is over Mosaic's scoped VMEM in either, so the lookup
+# is one launch a level, each with its row block shrunk to fit.
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_alt_lookup_compiles_at_middlebury_f(one_chip, dtype):
     from raft_stereo_tpu.kernels.corr_alt import alt_lookup_fused
 
     h, w, d = MIDDLEBURY_F
-    f1 = _sds((1, h, w, d), jnp.bfloat16, one_chip)
-    f2s = [_sds((1, h, w // 2 ** i, d), jnp.bfloat16, one_chip)
-           for i in range(4)]
+    f1 = _sds((1, h, w, d), dtype, one_chip)
+    f2s = [_sds((1, h, w // 2 ** i, d), dtype, one_chip) for i in range(4)]
     coords = _sds((1, h, w), jnp.float32, one_chip)
     compiled = jax.jit(
         lambda a, bs, c: alt_lookup_fused(a, bs, c, RADIUS)
     ).lower(f1, f2s, coords).compile()
-    assert _kernel_calls(compiled)
+    assert len(_kernel_calls(compiled)) == 4
 
 
 @pytest.mark.parametrize("dtype,launches", [
