@@ -13,13 +13,23 @@ This kernel uses the algebraic identity
               = hat_k ⊛ (f1 · f2ᵀ)[w, :]
 
 i.e. a linear-interpolated feature dot product IS a hat-function reduction of
-one row-block of the correlation volume.  So each (row, W1-block) tile:
+one row-block of the correlation volume.  So each (row, W1-block) tile, a
+row at a time:
 
-  1. computes its volume tile  v = f1_tile @ f2_rowᵀ / √D  on the MXU,
-     entirely in VMEM (never written to HBM — the fusion of SURVEY.md §7's
-     kernels 9b and 9c), then
-  2. hat-samples v exactly like the reg_fused lookup kernel
-     (kernels/corr_lookup.py).
+  1. computes its volume tile TRANSPOSED,  vT = f2_row @ f1_tileᵀ / √D,
+     (W2, W1B) on the MXU: the right image's bins on the sublanes (padded
+     to whole registers of 8, not to 128 lanes), the tile's 128 pixels on
+     the lanes; entirely in VMEM (never written to HBM — the fusion of
+     SURVEY.md §7's kernels 9b and 9c), then
+  2. samples vT along the sublanes (``sublane_sample``): the same numbers
+     as the reg_fused lookup kernel's ``hat_sample`` (kernels/
+     corr_lookup.py; tests/test_corr_alt.py holds the two together), got
+     with whole-register compares, selects and adds and one eight-sublane
+     reduction per window bin — no reduction across lanes — and
+  3. stores the taps as dense rows of a (K, W1B) block, pixels on the
+     lanes; the result array is (rows, K, W1) and XLA takes the
+     ``swapaxes`` to (rows, W1, K) as a layout of the next convolution's
+     operand (a bitcast in the compiled realtime program, PR 29).
 
 Per iteration this recomputes the volume tile (alt's memory/compute trade);
 across ``corr_levels`` the right features come from the W-pooled pyramid the
@@ -48,10 +58,11 @@ from jax.experimental.pallas import tpu as pltpu
 from raft_stereo_tpu.kernels.corr_lookup import (ROW_BLK, VMEM_BUDGET,
                                                  W1_BLK, log_launch_choice,
                                                  fused_lookup_available,
-                                                 hat_sample, hat_scatter,
-                                                 row_blk_for,
+                                                 hat_scatter, row_blk_for,
                                                  interpret_enabled as
                                                  _interpret)
+
+SUBLANES = 8      # rows of one float32 vector register
 
 
 def alt_fused_available() -> bool:
@@ -72,19 +83,79 @@ def alt_fused_fits(w2: int, d: int, itemsize: int, radius: int) -> bool:
 
 
 # ------------------------------------------------------------------ kernels
-def _fwd_kernel(f1_ref, f2_ref, coords_ref, out_ref, *, radius: int,
-                scale: float, inv_sqrt_d: float, precision):
-    """(R, W1B, D) left tile + (R, W2, D) right rows + (R, W1B) centers
-    → (R, W1B, K) window correlations."""
-    f1 = f1_ref[:].astype(jnp.float32)
-    f2 = f2_ref[:].astype(jnp.float32)
-    # Volume tile on the MXU, VMEM-resident only: (R, W1B, W2).
-    v = jax.lax.dot_general(f1, f2, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32,
-                            precision=precision) * inv_sqrt_d
-    centers = coords_ref[:, :, 0].astype(jnp.float32) * scale
-    for k, sample in hat_sample(v, centers, radius):
-        out_ref[:, :, k] = sample.astype(out_ref.dtype)
+def sublane_sample(vt, centers, radius: int, w2: int):
+    """Σ_x vt[x, w] · hat_k(x - centers[w]) for each tap k, for a volume
+    tile laid out TRANSPOSED: (W2p, W1B) float32 with the right image's
+    bins on the sublanes (W2p whole vector registers of 8; bins at and
+    beyond ``w2`` count as zero whatever they hold) and the tile's pixels
+    on the lanes, plus its (1, W1B) centers → 2·radius+1 rows of (1, W1B).
+
+    Same numbers as ``corr_lookup.hat_sample`` on the untransposed tile
+    (tests/test_corr_alt.py holds the two together, borders included), by
+    another route: the taps of one pixel sit one bin apart, so all of them
+    read the same 2·radius+2 consecutive bins ``floor(c) - radius + m``
+    and tap k is ``(1-a)·bin[k] + a·bin[k+1]`` with ``a = c - floor(c)``,
+    which is what the hat weights come to.  Each bin is picked out of the
+    tile by comparing a sublane iota with its index: per volume register
+    and bin one compare, one select and one add into that bin's
+    accumulator, whole registers on the vector unit; the eight sublanes of
+    an accumulator are reduced once at the end.  Nothing crosses lanes."""
+    w2p, lanes = vt.shape
+    bins = 2 * radius + 2
+    # beyond these every tap reads bins outside the row: clamping keeps
+    # the integer conversion in range and changes no result
+    c = jnp.clip(centers, -(radius + 2.0), w2 + radius + 1.0)
+    first = jnp.floor(c)
+    a = c - first
+    sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, lanes), 0)
+    # (bins, 8, W1B): for window bin m, how far tile 0's sublanes sit from
+    # that bin of the pixel on their lane; 0 marks the one to pick.  The
+    # bins ride a leading axis, so one traced operation is ``bins`` whole
+    # registers and the trace stays short (it is paid at every start-up)
+    off = (sub - (first.astype(jnp.int32) - radius))[None] - \
+        jax.lax.broadcasted_iota(jnp.int32, (bins, SUBLANES, lanes), 0)
+    acc = None
+    for x0 in range(0, w2p, SUBLANES):
+        v = vt[x0:x0 + SUBLANES]
+        if x0 + SUBLANES > w2:
+            v = jnp.where(sub < w2 - x0, v, 0.0)
+        hit = jnp.where(off == -x0, v[None], 0.0)
+        acc = hit if acc is None else acc + hit
+    g = jnp.sum(acc, axis=1, keepdims=True)            # (bins, 1, W1B)
+    taps = (1.0 - a) * g[:-1] + a * g[1:]
+    return [taps[k] for k in range(bins - 1)]
+
+
+@functools.partial(jax.jit, static_argnames=("radius", "w2", "inv_sqrt_d",
+                                             "precision"))
+def _row_taps(f1, f2, centers, *, radius: int, w2: int, inv_sqrt_d: float,
+              precision):
+    """One row of one level: (W1B, D) left tile, (W2p, D) right row and
+    (1, W1B) centers → the taps as rows of (1, W1B).  The volume tile is
+    produced transposed, (W2p, W1B), on the MXU and lives in VMEM only.
+    Jitted so that a kernel's unrolled rows share ONE trace of it (the
+    Mosaic lowering inlines the calls)."""
+    vt = jax.lax.dot_general(f2, f1, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32,
+                             precision=precision) * inv_sqrt_d
+    return sublane_sample(vt, centers, radius, w2)
+
+
+def _fwd_kernel(f1_ref, *refs, radius: int, widths, scales,
+                inv_sqrt_d: float, precision):
+    """(R, W1B, D) left tile + per level (R, W2p, D) right rows +
+    (R, 1, W1B) centers → (R, levels·K, W1B) window correlations, taps on
+    the sublanes.  One level a launch, or every level of the pyramid."""
+    *f2_refs, coords_ref, out_ref = refs
+    for r in range(f1_ref.shape[0]):
+        f1 = f1_ref[r].astype(jnp.float32)
+        centers0 = coords_ref[r].astype(jnp.float32)
+        taps = []
+        for f2_ref, w2, scale in zip(f2_refs, widths, scales):
+            taps += _row_taps(f1, f2_ref[r].astype(jnp.float32),
+                              centers0 * scale, radius=radius, w2=w2,
+                              inv_sqrt_d=inv_sqrt_d, precision=precision)
+        out_ref[r] = jnp.concatenate(taps, axis=0).astype(out_ref.dtype)
 
 
 def _bwd_kernel(f1_ref, f2_ref, coords_ref, g_ref, df1_ref, df2_ref, *,
@@ -144,11 +215,24 @@ def _precision_for(dtype) -> jax.lax.Precision:
             else jax.lax.Precision.DEFAULT)
 
 
+def _w2_rows(w2: int, itemsize: int) -> int:
+    """Rows of a right-feature block: ``w2`` rounded up to whole sublane
+    tiles of the feature dtype (8 rows of float32, 16 of bfloat16, 32 of a
+    one-byte grid), so the transposed tile is whole registers."""
+    tile = SUBLANES * max(1, 4 // itemsize)
+    return -(-w2 // tile) * tile
+
+
 # Mosaic fails to compile (not fall back) when a program's live set exceeds
-# VMEM, and at Middlebury-F scale (w2=496, d=256) the default ROW_BLK=8
-# working set is ~12 MB before double buffering — so large shapes shrink the
+# VMEM, and at Middlebury-F scale (w2=720, d=256) the default ROW_BLK=8
+# working set is ~23 MB before double buffering — so large shapes shrink the
 # row block via the package-shared budget (corr_lookup.row_blk_for).
 def _fwd_row_bytes(w1_blk, w2, d, itemsize, radius):
+    """What sizes a row block, forward and (with its own tiles added)
+    backward.  The last two terms are the backward's; since PR 29 the
+    forward samples a row's tile out of the registers and holds neither,
+    and keeps the row blocks this sum gave it (2/4/8/8 at W2
+    720/360/180/90, D 256): the launch plan was not that PR's to move."""
     fp32 = 4
     return (w2 * d * (itemsize + fp32)          # f2 rows: input + upcast
             + w1_blk * d * (itemsize + fp32)    # f1 tile: input + upcast
@@ -157,37 +241,58 @@ def _fwd_row_bytes(w1_blk, w2, d, itemsize, radius):
             + w1_blk * w2 * fp32)               # product intermediate
 
 
-def _launch_fwd(f1, f2, coords, radius, scale, inv_sqrt_d,
+def _launch_fwd(f1, f2s, coords, radius: int, scales, rb: int,
                 out_dtype=None):
-    # ``out_dtype`` (default: f1's own dtype) exists for the int8
-    # feature path: int8 features correlate to fp values (the in-kernel
-    # fp32 upcast is the in-register dequant modulo the feature scales
-    # the caller applies), so the output must not round through int8.
+    """(rows, W1, D) + per level (rows, W2, D) + (rows, W1) →
+    (rows, W1, levels·K) in ONE launch with row blocks of ``rb``.
+
+    Each right-feature block reads past a width that is no whole number
+    of sublane tiles (the sampler masks those rows); the kernel's own
+    result has the taps on the sublanes and the pixels on the lanes,
+    (rows, levels·K, W1), and XLA takes the ``swapaxes``.
+
+    ``out_dtype`` (default: f1's own dtype) exists for the int8 feature
+    path: int8 features correlate to fp values (the in-kernel fp32 upcast
+    is the in-register dequant modulo the feature scales the caller
+    applies), so the output must not round through int8."""
     rows, w1, d = f1.shape
-    w2 = f2.shape[1]
-    k = 2 * radius + 1
-    rb = row_blk_for(_fwd_row_bytes(W1_BLK, w2, d, f1.dtype.itemsize,
-                                    radius))
-    grid = (pl.cdiv(rows, rb), pl.cdiv(w1, W1_BLK))
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, radius=radius, scale=scale,
-                          inv_sqrt_d=inv_sqrt_d,
+    widths = tuple(int(f2.shape[1]) for f2 in f2s)
+    k = (2 * radius + 1) * len(f2s)
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, radius=radius, widths=widths,
+                          scales=tuple(scales), inv_sqrt_d=1.0 / math.sqrt(d),
                           precision=_precision_for(f1.dtype)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((rb, W1_BLK, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rb, w2, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rb, W1_BLK, 1), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((rb, W1_BLK, k), lambda i, j: (i, j, 0),
+        grid=(pl.cdiv(rows, rb), pl.cdiv(w1, W1_BLK)),
+        in_specs=[pl.BlockSpec((rb, W1_BLK, d), lambda i, j: (i, j, 0),
+                               memory_space=pltpu.VMEM)]
+                 + [pl.BlockSpec((rb, _w2_rows(w2, f2.dtype.itemsize), d),
+                                 lambda i, j: (i, 0, 0),
+                                 memory_space=pltpu.VMEM)
+                    for f2, w2 in zip(f2s, widths)]
+                 + [pl.BlockSpec((rb, 1, W1_BLK), lambda i, j: (i, 0, j),
+                                 memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((rb, k, W1_BLK), lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, w1, k),
+        out_shape=jax.ShapeDtypeStruct((rows, k, w1),
                                        out_dtype or f1.dtype),
         interpret=_interpret(),
-    )(f1, f2, coords[..., None])
+    )(f1, *f2s, coords[:, None, :])
+    return jnp.swapaxes(out, 1, 2)
+
+
+def _launch_fwd_level(f1, f2, coords, radius: int, scale: float,
+                      out_dtype=None):
+    """One level a launch, its row block shrunk to the VMEM budget."""
+    rb = row_blk_for(_fwd_row_bytes(W1_BLK, f2.shape[1], f1.shape[2],
+                                    f1.dtype.itemsize, radius))
+    return _launch_fwd(f1, [f2], coords, radius, (scale,), rb, out_dtype)
+
+
+def _launch_fwd_multi(f1, f2s, coords, radius: int, out_dtype=None):
+    """Every level of the pyramid in one launch."""
+    return _launch_fwd(f1, f2s, coords, radius,
+                       [1.0 / 2 ** i for i in range(len(f2s))], ROW_BLK,
+                       out_dtype)
 
 
 def _launch_bwd(f1, f2, coords, g, radius, scale, inv_sqrt_d):
@@ -237,9 +342,9 @@ def _alt_level(f1, f2, coords, radius: int, scale: float):
     → (B,H,W1,2r+1) correlations at one pyramid level."""
     b, h, w1, d = f1.shape
     w2 = f2.shape[2]
-    inv_sqrt_d = 1.0 / math.sqrt(d)
-    out = _launch_fwd(f1.reshape(b * h, w1, d), f2.reshape(b * h, w2, d),
-                      coords.reshape(b * h, w1), radius, scale, inv_sqrt_d)
+    out = _launch_fwd_level(f1.reshape(b * h, w1, d),
+                            f2.reshape(b * h, w2, d),
+                            coords.reshape(b * h, w1), radius, scale)
     return out.reshape(b, h, w1, -1)
 
 
@@ -265,104 +370,73 @@ _alt_level.defvjp(_alt_level_fwd, _alt_level_bwd)
 
 
 # ---------------------------------------------------- multi-level forward
-# All pyramid levels in ONE kernel launch: the right-feature pyramid is
-# concatenated along W (static level offsets) and each tile computes every
-# level's volume slice + hat-samples it in the same pass.  Bit-identical to
-# the per-level launches and ~1.5x faster at realtime shapes (410us ->
-# 274us measured on a v5e chip) — launch overhead dominates at small W2.
-def _fwd_multi_kernel(f1_ref, f2cat_ref, coords_ref, out_ref, *, radius: int,
-                      offsets, widths, inv_sqrt_d: float, precision):
-    f1 = f1_ref[:].astype(jnp.float32)
-    centers0 = coords_ref[:].astype(jnp.float32)
-    k = 2 * radius + 1
-    for lvl, (off, w2) in enumerate(zip(offsets, widths)):
-        f2 = f2cat_ref[:, off:off + w2, :].astype(jnp.float32)
-        v = jax.lax.dot_general(f1, f2, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32,
-                                precision=precision) * inv_sqrt_d
-        for kk, sample in hat_sample(v, centers0 / (2 ** lvl), radius):
-            out_ref[:, :, lvl * k + kk] = sample.astype(out_ref.dtype)
-
-
-
-
+# All pyramid levels in ONE kernel launch (``_launch_fwd_multi``): the levels
+# stay separate operands — no concatenated copy of the right features — and
+# each row of a tile computes every level's transposed volume slice +
+# samples it in the same pass.  Bit-identical to the per-level launches;
+# launch overhead dominates at small W2.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _alt_multi(f1, f2cat, coords, static):
-    """Single-launch all-levels lookup.  ``static`` = (radius, offsets,
-    widths) as hashable tuples."""
-    radius, offsets, widths = static
+def _alt_multi(f1, f2s, coords, radius: int):
+    """Single-launch all-levels lookup over the tuple ``f2s`` of
+    (B,H,W2_i,D) right features."""
     b, h, w1, d = f1.shape
-    wcat = f2cat.shape[2]
     rows = b * h
-    k = (2 * radius + 1) * len(offsets)
-    grid = (pl.cdiv(rows, ROW_BLK), pl.cdiv(w1, W1_BLK))
-    out = pl.pallas_call(
-        functools.partial(_fwd_multi_kernel, radius=radius, offsets=offsets,
-                          widths=widths, inv_sqrt_d=1.0 / math.sqrt(d),
-                          precision=_precision_for(f1.dtype)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROW_BLK, W1_BLK, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((ROW_BLK, wcat, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((ROW_BLK, W1_BLK), lambda i, j: (i, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((ROW_BLK, W1_BLK, k), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, w1, k), f1.dtype),
-        interpret=_interpret(),
-    )(f1.reshape(rows, w1, d), f2cat.reshape(rows, wcat, d),
-      coords.reshape(rows, w1))
-    return out.reshape(b, h, w1, k)
+    out = _launch_fwd_multi(f1.reshape(rows, w1, d),
+                            [f2.reshape(rows, f2.shape[2], d) for f2 in f2s],
+                            coords.reshape(rows, w1), radius)
+    return out.reshape(b, h, w1, -1)
 
 
-def _alt_multi_fwd(f1, f2cat, coords, static):
-    return _alt_multi(f1, f2cat, coords, static), (f1, f2cat, coords)
+def _alt_multi_fwd(f1, f2s, coords, radius):
+    return _alt_multi(f1, f2s, coords, radius), (f1, f2s, coords)
 
 
-def _alt_multi_bwd(static, residuals, g):
+def _alt_multi_bwd(radius, residuals, g):
     # Backward calls the per-level backward launch directly (training cost
     # is conv-dominated; the forward launch count is what matters for
     # inference latency).
-    radius, offsets, widths = static
-    f1, f2cat, coords = residuals
+    f1, f2s, coords = residuals
     k = 2 * radius + 1
     df1 = jnp.zeros_like(f1)
-    df2_parts = []
-    for lvl, (off, w2) in enumerate(zip(offsets, widths)):
-        f2 = f2cat[:, :, off:off + w2, :]
+    df2s = []
+    for lvl, f2 in enumerate(f2s):
         d1, d2, _ = _alt_level_bwd(radius, 1.0 / (2 ** lvl),
                                    (f1, f2, coords),
                                    g[..., lvl * k:(lvl + 1) * k])
         df1 = df1 + d1
-        df2_parts.append(d2)
-    return df1, jnp.concatenate(df2_parts, axis=2), jnp.zeros_like(coords)
+        df2s.append(d2)
+    return df1, tuple(df2s), jnp.zeros_like(coords)
 
 
 _alt_multi.defvjp(_alt_multi_fwd, _alt_multi_bwd)
 
 
-# Mosaic's scoped-vmem (kernel stack) limit is 16 MiB on this generation,
-# and its stack allocator does NOT reuse buffers across the unrolled level
-# loop of `_fwd_multi_kernel` — the live set is the per-level SUM.  One
-# hard calibration point: 544x960 fp32 (wcat=450, d=256) FAILS with a
-# measured 18.11 MiB scoped allocation where `_multi_alt_scoped_bytes`
-# estimates 14.71 MiB — the estimator runs ~1.23x low (compiler
-# temporaries it can't see).  The gate threshold therefore sits at
-# 16 MiB / 1.28 = 12.5 MiB of ESTIMATED bytes, so the worst gate-passing
-# program lands at ~12.5 * 1.23 = 15.4 MiB of real allocation, inside the
-# limit.  The realtime shape (wcat=292, bf16) estimates 10.39 MiB and is
-# proven to compile and run (bench.py r02/r03).
+# The gate of the launch PLAN.  Mosaic's scoped-vmem (kernel stack) limit is
+# 16 MiB on this generation.  The estimate below and its threshold were
+# calibrated on the body this module had before PR 29, which held every
+# level's (R, W1B, W2) tile, hat field and product at once: 544x960 fp32
+# (wcat=450, d=256) FAILED with a measured 18.11 MiB where the estimate
+# says 14.71 MiB (1.23x low), so the threshold sits at 16 MiB / 1.28.
+# Today's body works a row at a time and holds little beside its
+# double-buffered blocks: by bisection of ``vmem_limit_bytes`` in compiles
+# for a described v5e (PR 29) the all-levels program needs 3.7-4.0 MiB at
+# the realtime shape (W2 156/78/39/19, bf16), 6.7-7.0 MiB at 544x960 fp32
+# (it compiles as one launch now), 11.2-11.5 MiB at W2 720/360/180/90 in
+# bf16 (it compiles under the default limit too) and 21.9-22.2 MiB there
+# in fp32 (it does not).  PR 29 kept the plan as it was, so that it changed
+# one thing (one launch at KITTI realtime widths, four at 544x960 fp32 and
+# at 1984x2880); moving the gate to what the body needs now is PERF.md
+# section 7's next step.
 _MOSAIC_SCOPED_VMEM = int(12.5 * 2 ** 20)
 
 
 def _multi_alt_scoped_bytes(w2s, d: int, itemsize: int, radius: int) -> int:
-    """Estimated Mosaic stack bytes of one `_fwd_multi_kernel` program:
-    double-buffered input blocks, fp32 upcast copies (free when the input
-    is already fp32), per-level volume + hat-field + product (all live —
-    no cross-level reuse), and the double-buffered output block."""
+    """The launch plan's estimate of one all-levels program (see above:
+    what the pre-PR-29 body held, NOT today's live set, which is the
+    double-buffered blocks plus one row's upcast features, transposed
+    tiles and taps): double-buffered input blocks, fp32 upcast copies
+    (none when the input is already fp32), three (R, W1B, W2) fp32 arrays
+    a level, and the double-buffered output block."""
     fp32 = 4
     k = 2 * radius + 1
     wcat = sum(w2s)
@@ -380,26 +454,25 @@ def alt_lookup_fused(fmap1: jnp.ndarray, fmap2_pyramid: List[jnp.ndarray],
     """Fused no-volume window correlation at every level, concat level-major —
     drop-in for the XLA alt lookup in models/corr.py make_corr_fn_alt.
 
-    Uses the single-launch all-levels kernel when the whole program's
-    Mosaic stack estimate fits the scoped-vmem limit; otherwise one launch
-    per level, each with its row block shrunk to the VMEM budget
-    (``row_blk_for``).  Read on the v5e (PERF.md section 5, PR 28): KITTI
-    realtime shapes (W2 156/78, bf16) take the single launch; at 1984x2880
-    (W2 720/360/180/90, D 256) the estimate is 3.1x the limit in
-    float32 and in bfloat16 alike, so a lookup is four launches with row
-    blocks 2/4/8/8, 6.5/3.7/2.8/2.1 ms each on one pair's 496 rows in
-    float32: 8.9 % of the lookup's memory roofline, a quarter of a call."""
+    Uses the single-launch all-levels kernel when the launch plan's
+    estimate fits its gate (``_multi_alt_scoped_bytes``); otherwise one
+    launch per level, each with its row block shrunk to the VMEM budget
+    (``row_blk_for``).  Read on the v5e (PERF.md sections 5 and 6, PR 29,
+    the builder's traced runs of the two bulk cells): KITTI realtime
+    shapes (W2 156/78/39/19, bf16, 6144 rows) take the single launch,
+    4.29 ms (31.8 ms with the tile sampled along the lanes: 42.3 % of the
+    lookup's memory roofline against 5.69 %); at 1984x2880 (W2
+    720/360/180/90, D 256) a lookup is four launches with row blocks
+    2/4/8/8, 4.86/2.50/1.36/0.77 ms each on one pair's 496 rows in float32
+    (6.49/3.73/2.79/2.09 before): 14.2 % of the roofline, level 0 held by
+    the MXU's six ``HIGHEST`` passes (~4 ms)."""
     d = fmap1.shape[-1]
     w2s = [f2.shape[2] for f2 in fmap2_pyramid]
     single = (_multi_alt_scoped_bytes(w2s, d, fmap1.dtype.itemsize, radius)
               <= _MOSAIC_SCOPED_VMEM)
     log_launch_choice(f"alt lookup D={d}", w2s, fmap1.dtype, single)
     if single:
-        static = (radius,
-                  tuple(int(sum(w2s[:i])) for i in range(len(w2s))),
-                  tuple(int(w) for w in w2s))
-        f2cat = jnp.concatenate(fmap2_pyramid, axis=2)
-        return _alt_multi(fmap1, f2cat, coords, static)
+        return _alt_multi(fmap1, tuple(fmap2_pyramid), coords, radius)
 
     outs = [_alt_level(fmap1, f2, coords, radius, 1.0 / (2 ** i))
             for i, f2 in enumerate(fmap2_pyramid)]
@@ -407,36 +480,6 @@ def alt_lookup_fused(fmap1: jnp.ndarray, fmap2_pyramid: List[jnp.ndarray],
 
 
 # ----------------------------------------------------- int8 feature entry
-def _launch_fwd_multi_q(f1, f2cat, coords, radius: int, offsets, widths,
-                        inv_sqrt_d: float, out_dtype):
-    """Forward-only single-launch all-levels lookup over int8 features:
-    the ``_fwd_multi_kernel`` body unchanged (its fp32 upcast is the
-    in-register dequant), only the output dtype overridden."""
-    rows, w1, d = f1.shape
-    wcat = f2cat.shape[1]
-    k = (2 * radius + 1) * len(offsets)
-    grid = (pl.cdiv(rows, ROW_BLK), pl.cdiv(w1, W1_BLK))
-    return pl.pallas_call(
-        functools.partial(_fwd_multi_kernel, radius=radius,
-                          offsets=offsets, widths=widths,
-                          inv_sqrt_d=inv_sqrt_d,
-                          precision=_precision_for(f1.dtype)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROW_BLK, W1_BLK, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((ROW_BLK, wcat, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((ROW_BLK, W1_BLK), lambda i, j: (i, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((ROW_BLK, W1_BLK, k), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, w1, k), out_dtype),
-        interpret=_interpret(),
-    )(f1, f2cat, coords)
-
-
 def alt_lookup_fused_q(fmap1_q: jnp.ndarray,
                        fmap2_pyramid_q: List[jnp.ndarray],
                        coords: jnp.ndarray, radius: int,
@@ -455,9 +498,11 @@ def alt_lookup_fused_q(fmap1_q: jnp.ndarray,
     same ``check_q_dtype`` contract as ``lookup_pyramid_fused_q``; the
     kernel body is dtype-generic.
 
-    Forward-only (inference tier, under ``stop_gradient``); same
-    launch selection and scoped-VMEM gating as ``alt_lookup_fused``
-    with the 1-byte itemsize shrinking the estimate."""
+    Forward-only (inference tier, under ``stop_gradient``); the kernel
+    bodies, launch selection and scoped-VMEM gating of
+    ``alt_lookup_fused`` (their fp32 upcast is the in-register dequant)
+    with the 1-byte itemsize shrinking the estimate and only the output
+    dtype overridden."""
     from raft_stereo_tpu.kernels.corr_lookup import check_q_dtype
 
     check_q_dtype([fmap1_q] + list(fmap2_pyramid_q), q_dtype)
@@ -465,26 +510,17 @@ def alt_lookup_fused_q(fmap1_q: jnp.ndarray,
     b, h, w1, _ = fmap1_q.shape
     w2s = [f2.shape[2] for f2 in fmap2_pyramid_q]
     rows = b * h
-    inv_sqrt_d = 1.0 / math.sqrt(d)
+    f1 = fmap1_q.reshape(rows, w1, d)
+    f2s = [f2.reshape(rows, f2.shape[2], d) for f2 in fmap2_pyramid_q]
+    coords = coords.reshape(rows, w1)
     single = (_multi_alt_scoped_bytes(w2s, d, fmap1_q.dtype.itemsize,
                                       radius) <= _MOSAIC_SCOPED_VMEM)
     log_launch_choice(f"quantized alt lookup D={d}", w2s, fmap1_q.dtype, single)
     if single:
-        offsets = tuple(int(sum(w2s[:i])) for i in range(len(w2s)))
-        widths = tuple(int(w) for w in w2s)
-        f2cat = jnp.concatenate(fmap2_pyramid_q, axis=2)
-        out = _launch_fwd_multi_q(
-            fmap1_q.reshape(rows, w1, d),
-            f2cat.reshape(rows, sum(w2s), d),
-            coords.reshape(rows, w1), radius, offsets, widths,
-            inv_sqrt_d, out_dtype)
-        return out.reshape(b, h, w1, -1)
-    outs = []
-    for i, f2 in enumerate(fmap2_pyramid_q):
-        out = _launch_fwd(fmap1_q.reshape(rows, w1, d),
-                          f2.reshape(rows, f2.shape[2], d),
-                          coords.reshape(rows, w1), radius,
-                          1.0 / (2 ** i), inv_sqrt_d,
-                          out_dtype=out_dtype)
-        outs.append(out.reshape(b, h, w1, -1))
-    return jnp.concatenate(outs, axis=-1)
+        out = _launch_fwd_multi(f1, f2s, coords, radius, out_dtype)
+    else:
+        out = jnp.concatenate(
+            [_launch_fwd_level(f1, f2, coords, radius, 1.0 / 2 ** i,
+                               out_dtype) for i, f2 in enumerate(f2s)],
+            axis=-1)
+    return out.reshape(b, h, w1, -1)

@@ -33,6 +33,8 @@ ACCURACY_KITTI = (96, 312, (312, 156, 78, 39))     # 384x1248, 4 levels
 REALTIME_KITTI = (48, 156, (156, 78))              # 1/8-res, 2 levels
 SCENEFLOW_TRAIN = (8 * 80, 180, (180, 90, 45, 22))  # batch 8, 320x720
 MIDDLEBURY_F = (496, 720, 256)                     # 1/4-res H, W, fnet D
+# the realtime bulk cell: 1/8-res rows of 384x1248 pairs, all four levels
+REALTIME_BULK = (48, 156, (156, 78, 39, 19), 256)
 
 
 @pytest.fixture(scope="module")
@@ -152,6 +154,36 @@ def test_alt_lookup_compiles_at_middlebury_f(one_chip, dtype):
         lambda a, bs, c: alt_lookup_fused(a, bs, c, RADIUS)
     ).lower(f1, f2s, coords).compile()
     assert len(_kernel_calls(compiled)) == 4
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_alt_lookup_compiles_at_realtime_bulk(one_chip, dtype):
+    """The no-volume lookup as ``realtime.bulk.kitti`` runs it (two pairs'
+    rows here, 128 pairs' there), and the quantized entry that shares its
+    forward body: ONE kernel call for all four levels, whose printed result
+    holds rows x W1 x levels*K elements.  The cell's ``trace.kernels``
+    finds the call by ``tpu_custom_call`` in its name and
+    ``trace_reduce.result_elements`` counts its lookups from that shape, so
+    a change to either fails here, without a chip."""
+    from benchmark.trace_reduce import result_elements
+    from raft_stereo_tpu.kernels import corr_alt
+
+    h, w, w2s, d = REALTIME_BULK
+    rows = 2 * h
+    f1 = _sds((1, rows, w, d), dtype, one_chip)
+    f2s = [_sds((1, rows, w2, d), dtype, one_chip) for w2 in w2s]
+    coords = _sds((1, rows, w), jnp.float32, one_chip)
+    if dtype == jnp.int8:
+        def lookup(a, bs, c):
+            return corr_alt.alt_lookup_fused_q(a, bs, c, RADIUS,
+                                               out_dtype=jnp.float32)
+    else:
+        def lookup(a, bs, c):
+            return corr_alt.alt_lookup_fused(a, bs, c, RADIUS)
+    compiled = jax.jit(lookup).lower(f1, f2s, coords).compile()
+    (call,) = _kernel_calls(compiled)
+    assert result_elements(call) == rows * w * len(w2s) * K, call
 
 
 @pytest.mark.parametrize("dtype,launches", [
