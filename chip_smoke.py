@@ -505,7 +505,8 @@ def phase_setup(p: dict) -> dict:
             left, right = _make_pair(rng, SERVE_HW)
             np.savez(os.path.join(work, f"pair{k}.npz"), left=left,
                      right=right)
-        from bench_loader import build_tree
+        sys.path.insert(0, os.path.join(HERE, "tests"))
+        from golden_data import build_tree
         build_tree(os.path.join(work, "datasets"), n_pairs=8, seed=seed)
     return {"device": device, "native": bool(native.available())}
 

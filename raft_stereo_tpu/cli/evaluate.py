@@ -39,8 +39,8 @@ def run_eval(args) -> dict:
         # frames run in order twice — cold per-frame vs warm-start
         # chained — and the row reports the EPE drift + iters/FPS split
         # (eval/validate.sequence_drift).  --stream_out records the row
-        # as a versioned bench JSON (bench_stream.py drives this over
-        # the synthetic validators -> STREAM_r14.json).
+        # as a versioned bench JSON (telemetry/events.bench_record), a
+        # product of the run.
         from raft_stereo_tpu.data import datasets as ds
 
         if args.dataset == "eth3d":
@@ -134,7 +134,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "warm-start EPE drift plus per-pass iters/FPS")
     p.add_argument("--stream_out", default=None,
                    help="with --sequence: write the drift row as a "
-                        "versioned bench JSON (e.g. STREAM_r14.json)")
+                        "versioned bench JSON")
     p.add_argument("--json", action="store_true",
                    help="print results as one JSON line")
     common.add_arch_overrides(p)

@@ -347,8 +347,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "one trigger trace id")
     p.add_argument("--http_workers", type=int, default=128,
                    help="router HTTP thread-pool size (bounded pool "
-                        "replaces thread-per-connection; sized for the "
-                        "10k-session load profile in bench_fleet.py)")
+                        "replaces thread-per-connection; sized for "
+                        "10k concurrent sessions)")
     return p
 
 

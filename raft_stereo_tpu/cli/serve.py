@@ -519,8 +519,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "/v1/stream/<id> frames warm-start the GRU from "
                         "the session's previous disparity (with an "
                         "early-exit tier the convergence gate then stalls "
-                        "in a fraction of the cold iterations — the "
-                        "video FPS win bench_stream.py measures); "
+                        "in a fraction of the cold "
+                        "iterations); "
                         "DELETE /v1/stream/<id> closes a session")
     p.add_argument("--session_ttl_s", type=float, default=30.0,
                    help="idle seconds before a session expires (its next "
@@ -557,8 +557,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "frames resume the GRU's own trajectory — the "
                         "warm-h executable families; lets the "
                         "convergence gate chain stably at tighter "
-                        "thresholds than the flow-only warm start "
-                        "(STREAM_r19.json)")
+                        "thresholds than the flow-only warm "
+                        "start")
     p.add_argument("--edf_scheduler", action="store_true",
                    help="deadline-aware EDF pop policy: frames carrying "
                         "a per-frame deadline (X-Deadline-Ms) are "

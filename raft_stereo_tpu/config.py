@@ -70,9 +70,9 @@ class RaftStereoConfig:
     # Fused ConvGRU gate kernel (kernels/gru_fused.py): compute both gate
     # convolutions (convzr, convq) and the r-gate coupling of every GRU
     # level in one Pallas launch per level, keeping the gate intermediates
-    # in VMEM — the scan body is ~89% of realtime inference at 7 iterations
-    # (INFERENCE_PROFILE_r03.json), and this collapses its ~10 XLA
-    # dispatches per level to 1 kernel + 1 fused pointwise tail.
+    # in VMEM — the refinement loop is most of the device's busy time in
+    # every cell of the benchmark (PERF.md section 5), and this collapses
+    # its ~10 XLA dispatches per level to 1 kernel + 1 fused pointwise tail.
     #   "auto" (default): use the kernel when the backend supports it and
     #     the level's working set fits VMEM; silently fall back to the Flax
     #     conv path otherwise (CPU/GPU, very wide levels).
@@ -98,8 +98,8 @@ class RaftStereoConfig:
     # "gru_gates" (pre-activation convzr/convq outputs of every ConvGRU
     # level, ~110 MB/iter at the SceneFlow config), "motion_features"
     # (BasicMotionEncoder output, ~30 MB/iter).  Each trades HBM for
-    # skipped recompute; see docs/TRAIN_PROFILE.md round 4 for chip
-    # measurements of the combinations.
+    # skipped recompute; the combinations are not re-measured on the v5e
+    # (no training cell yet: PERF.md section 7, 0a).
     remat_save: Tuple[str, ...] = ("corr_lookup",)
     # Stream the encoders' FULL-RESOLUTION stages in horizontal bands
     # (models/banded.py): only band-sized activations exist, cutting peak
@@ -149,16 +149,16 @@ class RaftStereoConfig:
     # instead of as one batch-2 concat (halves the full-resolution stem's
     # peak HBM).  None = derive from the local device's HBM at trace time
     # (models/raft_stereo.sequential_fnet_threshold — measured stem
-    # bytes/pixel, tools/fullres_gates.py); 0 forces always-sequential, a
-    # huge value forces always-batched.
+    # bytes/pixel); 0 forces always-sequential, a huge value forces
+    # always-batched.
     sequential_fnet_pixels: Optional[int] = None
     # Row height of the banded encoder's streaming bands (banded_encoder
     # only).  None = derive from device HBM and image width at trace time
     # (models/banded.default_band_rows); must be even (stride-2 alignment).
     band_rows: Optional[int] = None
     # --- Adaptive GRU early exit (test-mode inference only) -------------
-    # The GRU refinement loop is ~89% of realtime inference
-    # (INFERENCE_PROFILE_r03.json) and the paper's iterative-refinement
+    # The GRU refinement loop is most of the device's busy time
+    # (PERF.md section 5) and the paper's iterative-refinement
     # framing makes every intermediate disparity a valid output, so the
     # test-mode loop can stop once the update stalls.  When
     # ``exit_threshold_px > 0`` the fixed-depth ``lax.scan`` becomes a
@@ -184,7 +184,7 @@ class RaftStereoConfig:
     # quantize at load), and the correlation pyramid stores int8 with
     # per-level scales read by the extended Pallas lookup kernels
     # (models/corr.py).  The memory-bound halves of the per-frame cost
-    # (COST_REPORT_r10.json roofline) move 1/4 (vs fp32) or 1/2 (vs
+    # (the lookups: PERF.md section 3) move 1/4 (vs fp32) or 1/2 (vs
     # bf16) of the bytes.  "int8_mxu": the compute-path extension
     # (quant/matmul.py) — encoder convs MULTIPLY int8×int8 and
     # accumulate int32 on the MXU (activations quantized in-graph with
@@ -193,7 +193,7 @@ class RaftStereoConfig:
     # flops win.  "off" (default) compiles the EXACT pre-quant
     # program — bitwise-identical, pinned by tests/test_quant.py.
     # Accuracy is gated by the measured in-distribution drift
-    # (tools/quant_drift.py -> QUANT_DRIFT_r22.json), the BF16_DRIFT
+    # (tools/quant_drift.py), the tools/bf16_drift.py
     # methodology extended down.  Inference-only: the training CLIs
     # never set it, and the quantized corr path runs under
     # stop_gradient.
@@ -357,10 +357,10 @@ class RaftStereoConfig:
         """The realtime config (reference: README.md:84 uses reg_cuda there).
 
         On TPU the fused no-volume 'alt' kernel is the chosen backend:
-        sustained throughput ties reg_fused (106-142 vs 110-141 FPS at
-        KITTI resolution on one chip), bursts run ~1.5x faster (193-218
-        FPS), and the correlation volume never exists in HBM (tiles are
-        computed in VMEM), freeing memory for larger batches/resolutions."""
+        the correlation volume never exists in HBM (tiles are computed in
+        VMEM), freeing memory for larger batches/resolutions, and it is
+        the path the benchmark's realtime cell times (PERF.md section 4);
+        reg_fused has not been timed against it on the v5e (ROADMAP D3)."""
         return cls(shared_backbone=True, n_downsample=3, n_gru_layers=2,
                    slow_fast_gru=True, corr_backend="alt",
                    mixed_precision=True)
@@ -401,14 +401,14 @@ class RequestTier:
 # Threshold units are px of mean |Δdisparity| per iteration at feature
 # resolution.  Defaults sit on the measured convergence curve
 # (train_gru_delta_px telemetry; swept on the four validators by
-# tools/early_exit_report.py -> EARLY_EXIT_r12.json): "interactive" trades
+# tools/early_exit_report.py, not timed on the v5e): "interactive" trades
 # ~hundredths of a px of EPE for the biggest latency cut, "balanced"
 # stops once updates are metric-noise, "quality" is the reference
 # fixed-depth program.  "turbo" is the quantized tier (v2 since r22):
 # interactive's exit knobs on the int8 COMPUTE path ("int8_mxu" —
 # int8×int8→int32 encoder convs + int8 correlation pyramid,
 # quant/matmul.py) — the bottom rung of the brownout cost ladder, gated
-# by the measured drift (tools/quant_drift.py -> QUANT_DRIFT_r22.json).
+# by the measured drift (tools/quant_drift.py).
 # The r15 weights-only path stays addressable as an inline
 # "name:threshold:min:int8" spec.
 REQUEST_TIERS: Dict[str, RequestTier] = {
@@ -426,7 +426,7 @@ def parse_tier(spec: Union[str, RequestTier]) -> RequestTier:
     """A tier from a preset name or an inline
     ``name:threshold[:min[:quant]]`` spec — ``"interactive"`` uses the
     preset, ``"fast:0.1:2"`` defines an ad-hoc tier, and
-    ``"fast8:0.1:2:int8"`` puts it on the int8 path (bench/smoke
+    ``"fast8:0.1:2:int8"`` puts it on the int8 path (smoke
     harnesses pin exact knobs this way)."""
     if isinstance(spec, RequestTier):
         return spec

@@ -30,7 +30,7 @@ except ImportError:  # pragma: no cover
 def _blend(a: np.ndarray, b, factor: float) -> np.ndarray:
     """``factor*a + (1-factor)*b`` clipped to uint8 — in-place fp32 ops (one
     temporary instead of four; the loader's per-sample cost is dominated by
-    these full-frame blends, bench_loader.py)."""
+    these full-frame blends)."""
     out = a.astype(np.float32)
     out *= np.float32(factor)
     bb = (1.0 - factor) * b

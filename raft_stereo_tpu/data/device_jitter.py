@@ -2,7 +2,7 @@
 
 The host ``ColorJitter`` (data/augment.py) costs ~63 ms/sample at SceneFlow
 frame sizes — 78% of the whole per-sample host budget on a one-core host
-(measured, docs/TRAIN_PROFILE.md round 4) — while the chip absorbs the same
+(measured on an earlier host, round 4) — while the chip absorbs the same
 elementwise work in single-digit milliseconds inside the already
 memory-bound train step.  This module replicates torchvision ColorJitter
 semantics (reference: core/utils/augmentor.py:73-93 — brightness/contrast/

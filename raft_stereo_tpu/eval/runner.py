@@ -39,7 +39,7 @@ warnings.filterwarnings(
 
 # GRU-iteration depth at which bf16 correlation measurably drifts on TRAINED
 # weights: at iters=32 the per-pixel p99 reaches ~6.5-7 px with ΔEPE +0.04 px
-# (BF16_DRIFT_r03.json), while at the realtime depth (7) drift is ≤0.03 px
+# (tools/bf16_drift.py), while at the realtime depth (7) drift is ≤0.03 px
 # EPE.  Eval/demo runs at or past this depth flip the correlation features to
 # fp32 (everything else stays bf16) unless the caller opts out.
 DEEP_ITERS_FP32_CORR = 16
@@ -54,7 +54,7 @@ def effective_inference_config(config: RaftStereoConfig, iters: int,
                                ) -> RaftStereoConfig:
     """The config an inference path should actually run: deep-iteration
     bf16 correlation gets ``corr_fp32`` flipped on (the measured 32-iter
-    drift on trained weights, BF16_DRIFT_r03.json).  Shared by the solo
+    drift on trained weights, tools/bf16_drift.py).  Shared by the solo
     ``InferenceRunner`` and the serving engine so both compile the same
     program for the same request class — the engine's batch-1 bucket is
     bitwise-equal to solo inference by construction."""
@@ -63,7 +63,7 @@ def effective_inference_config(config: RaftStereoConfig, iters: int,
         log.warning(
             "iters=%d >= %d with bf16 correlation: enabling corr_fp32 "
             "for this runner (measured 32-iter drift on trained "
-            "weights, BF16_DRIFT_r03.json; pass corr_fp32_auto=False "
+            "weights, tools/bf16_drift.py; pass corr_fp32_auto=False "
             "to keep bf16 corr)", iters, DEEP_ITERS_FP32_CORR)
         return dataclasses.replace(config, corr_fp32=True)
     return config
@@ -315,8 +315,8 @@ def make_forward_mesh(model: RAFTStereo, iters: int, mesh,
     replicated in and the full-resolution disparity GATHERED out — the
     program an "xl" serving bucket dispatches when one full-resolution
     pair cannot fit (or meet latency) on one device
-    (ROWSGRU_MEMORY_r05.json: 141 GiB at rows=1 vs 13.8 GiB/device on a
-    16-way rows mesh).
+    (a compiler's memory analysis on an earlier runtime: 141 GiB at rows=1
+    vs 13.8 GiB/device on a 16-way rows mesh; not re-measured on the v5e).
 
     Same calling convention and numerics contract as the base program:
     ``fn(variables, images1, images2) -> (N, Hp, Wp) flow`` with the
@@ -450,7 +450,7 @@ class InferenceRunner:
         ``iters >= DEEP_ITERS_FP32_CORR`` a mixed-precision config without
         ``corr_fp32`` gets it enabled here (with a one-line warning) —
         the measured 32-iter drift on trained weights is the reason
-        (BF16_DRIFT_r03.json).  Pass False to measure raw bf16 numerics
+        (tools/bf16_drift.py).  Pass False to measure raw bf16 numerics
         (tools/bf16_drift.py does).
         ``cost_registry`` (telemetry/costs.CompileRegistry | None): when
         set, every per-shape compile routes through the AOT path
@@ -768,8 +768,8 @@ class InferenceRunner:
         sessionless path (pinned bitwise by tests/test_sessions.py).
 
         With early exit configured (``exit_threshold_px``) a warm frame
-        typically stalls after far fewer iterations than a cold one —
-        the FPS win bench_stream.py measures.  A ``prev_flow_low`` whose
+        typically stalls after far fewer iterations than a cold one
+        (no cell times it yet: ROADMAP R5).  A ``prev_flow_low`` whose
         shape does not match this frame's padded low-res grid raises:
         resolution changes are a caller-visible stream break, not
         something to resample over silently.

@@ -173,9 +173,9 @@ def _hat_field(centers, w2: int, radius: int):
     ``max(0, 1-|x - centers - (k-radius)|)`` = F[x + 2·radius - k] where
     F[j] = max(0, 1-|j - radius - centers|) over j ∈ [0, w2+2·radius).
     Computing F ONCE and slicing per tap replaces ~6 vector passes per tap
-    (iota, sub, abs, sub, max, mul) with 2 (mul, add) — the training-trace
-    finding that the VPU weight construction, not DMA or launch overhead,
-    dominates the lookup (docs/TRAIN_PROFILE.md)."""
+    (iota, sub, abs, sub, max, mul) with 2 (mul, add) — a training trace
+    on an earlier runtime found the VPU weight construction, not DMA or
+    launch overhead, dominating the lookup (not re-measured on the v5e)."""
     ext = w2 + 2 * radius
     xs = jax.lax.broadcasted_iota(jnp.int32, (1, 1, ext), 2).astype(jnp.float32)
     return jnp.maximum(0.0, 1.0 - jnp.abs(xs - radius - centers[..., None]))
@@ -305,7 +305,7 @@ _sample_level.defvjp(_sample_level_fwd, _sample_level_bwd)
 
 
 # ----------------------------------------- single-launch all-levels lookup
-# Training-trace finding (docs/TRAIN_PROFILE.md): each custom call inside the
+# Training-trace finding (an earlier runtime): each custom call inside the
 # 22-iteration scan carries ~1 ms of in-graph overhead/stall far above its
 # isolated runtime (26 us), so 12 per-iteration launches (4 fwd + 4 remat
 # recompute + 4 bwd) dominate the step.  Sampling EVERY level in one launch
@@ -490,7 +490,7 @@ def lookup_pyramid_fused_q(pyramid: List[jnp.ndarray],
     """Fused window lookup over a QUANTIZED pyramid (round-15 turbo
     tier; fp8-capable since r22): the kernels read the 1-byte volume
     tiles from HBM — 1/4 (vs fp32) or 1/2 (vs bf16) of the bytes the
-    memory-bound lookup moves (COST_REPORT_r10.json roofline) — and the
+    memory-bound lookup moves (PERF.md section 3: its roofline) — and the
     in-kernel fp32 upcast of each tile is the in-register dequant.  The
     caller applies the per-level scales to the RAW sampled output
     (models/corr.py): hat sampling is linear, so ``scale * sample(q)``

@@ -1,8 +1,8 @@
 """Pallas TPU kernel: fused ConvGRU gate pipeline.
 
-The GRU refinement loop is RAFT-Stereo's runtime: at the realtime
-configuration the scan body is 89% of inference at 7 iterations
-(INFERENCE_PROFILE_r03.json), and its hot block is the ConvGRU gate math in
+The GRU refinement loop is RAFT-Stereo's runtime: it is most of the
+device's busy time in every cell of the benchmark (PERF.md section 5:
+52-72 %), and its hot block is the ConvGRU gate math in
 models/update.py — per level per iteration, XLA dispatches the ``convzr``
 conv, the ``convq`` conv, and a trail of pointwise ops (~10 ops/level), each
 round-tripping activations through HBM.  This kernel computes BOTH gate

@@ -3,7 +3,7 @@
 With ``n_downsample=2`` the encoder stem runs at FULL image resolution
 (matching the reference's stride gate, core/extractor.py:140), and its
 activations — not the correlation volume — set peak HBM at high resolution
-(docs/TRAIN_PROFILE.md round 2: 8.5 GiB for a 1984×2880 frame AFTER the
+(an earlier runtime read 8.5 GiB for a 1984×2880 frame AFTER the
 sequential-fnet fix).  This module executes the full-resolution segment of
 ``_Trunk`` (stem + layer1 + layer2_0's stride-2 entry convs) in horizontal
 BANDS with halo rows, so only band-sized tensors ever exist:
@@ -197,15 +197,15 @@ def _residual_block(tp, batch_stats, x, name, stride, norm_fn, dtype):
 
 
 # Peak-HBM bytes one band of the streaming segment adds per
-# (row x width-pixel x batch-sample).  Measured on the TPU v5 lite chip via
-# tools/fullres_gates.py (FULLRES_GATES_r03.json): peak-HBM slope in band
-# height at 1984x2880 = 231.7 B/(row*width-pixel); the overall peak is
+# (row x width-pixel x batch-sample).  Measured on an earlier runtime and
+# not re-measured on the v5e (no cell turns banded_encoder on): peak-HBM slope
+# in band height at 1984x2880 = 231.7 B/(row*width-pixel); the overall peak is
 # nearly FLAT in the band (3.93-4.20 GiB for bands 128-512) because the
 # off-band stages dominate, so the choice is low-stakes within the clamp.
 _BAND_BYTES_PER_ROW_PIXEL = 232
 # Fraction of device HBM the resident band working set may occupy — ~1%,
 # which reproduces the band=256 that carried the round-2 full-resolution
-# measurements (FULLRES_r02.json) at the 2880-wide calibration shape on a
+# measurements at the 2880-wide calibration shape on a
 # 16 GiB chip; the rest stays for the off-band stages (1/2-res tail,
 # correlation, GRU state) that coexist with the streamed stem.
 _BAND_HBM_FRACTION = 1 / 96
@@ -217,9 +217,9 @@ def default_band_rows(n: int, w: int) -> int:
     working set (``n * w * band * _BAND_BYTES_PER_ROW_PIXEL``) stays under
     ``_BAND_HBM_FRACTION`` of HBM, clamped to [64, 1024].  At W=2880 on a
     16 GiB chip this lands at 266 rows — within 5% of the band=256 that
-    carried the round-2 full-resolution measurements (FULLRES_r02.json),
+    carried the round-2 full-resolution measurements,
     whose peak HBM the calibration run measured as nearly flat in the
-    band height anyway (FULLRES_GATES_r03.json)."""
+    band height anyway (not re-measured on the v5e)."""
     from raft_stereo_tpu.profiling import device_hbm_bytes
     budget = _BAND_HBM_FRACTION * device_hbm_bytes()
     band = int(budget // (max(n, 1) * w * _BAND_BYTES_PER_ROW_PIXEL))

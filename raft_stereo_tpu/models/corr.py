@@ -54,7 +54,7 @@ def over_data_axis(fn, batched):
 def corr_quant_enabled(cfg: RaftStereoConfig) -> bool:
     """Whether this config stores the correlation pyramid int8
     (round-15 turbo tier): the lookup is memory-bound
-    (COST_REPORT_r10.json roofline), so the int8 volume moves 1/4 (vs
+    (PERF.md section 3), so the int8 volume moves 1/4 (vs
     fp32) or 1/2 (vs bf16) of the bytes per iteration.  The int8_mxu
     compute mode (r22) shares the identical pyramid path — the modes
     differ in the ENCODER convs, not here."""

@@ -35,8 +35,8 @@ from raft_stereo_tpu.profiling import annotate
 # Extra peak-HBM bytes PER PIXEL the batch-2 fnet concat costs over the
 # sequential path when the stem runs at full resolution (n_downsample<=2):
 # XLA holds both images' full-resolution stem working sets live at once.
-# Measured in float32 on an earlier runtime via tools/fullres_gates.py
-# (FULLRES_GATES_r03.json): 1190 / 1179 / 1166 B/px at 544x960 / 1088x1984
+# Measured in float32 on an earlier runtime, from the compiled programs'
+# memory analysis: 1190 / 1179 / 1166 B/px at 544x960 / 1088x1984
 # / 1984x2880.  Read again on the TPU v5e in bfloat16 (PERF.md section 6,
 # PR 28, one 1984x2880 pair a call, 32 iterations): the batched path
 # reserves 10.75e9 B at its peak against the sequential path's 5.98e9 B,
@@ -55,7 +55,7 @@ _SEQ_FNET_HBM_FRACTION = 0.10
 # to confidence as exp(-score/scale), so a pixel whose update magnitude
 # settled at the scale reads ~0.37 and a fully-settled pixel reads ~1.0.
 # Sized to the early-exit band the repo already operates in
-# (EARLY_EXIT_r12: tier thresholds 0.01..0.05 px MEAN |Δ| — individual
+# (config.REQUEST_TIERS: thresholds 0.01..0.05 px MEAN |Δ| — individual
 # unconverged pixels sit orders of magnitude above that).
 CONFIDENCE_SCALE_PX = 0.25
 # Trajectory-decay EWMA weight: how much of the per-pixel update history
@@ -164,8 +164,8 @@ class RAFTStereo(nn.Module):
             fixed-depth scan program bitwise-unchanged.
           unroll_gru: test-mode only — run the refinement loop as an
             unrolled Python loop instead of ``lax.scan``.  Same math, same
-            weights; the compiled program inlines every iteration, which is
-            what ``tools/cost_report.py`` compiles because XLA's
+            weights; the compiled program inlines every iteration, which
+            matters to a reader of the executable because XLA's
             ``cost_analysis`` counts a while-loop body ONCE regardless of
             trip count, so only an unrolled executable carries honest
             per-iteration flops.  Not for deployment: compile time grows
@@ -179,7 +179,7 @@ class RAFTStereo(nn.Module):
             per-session ctx cache behind streaming serving: for a static
             camera the context of the scene does not change frame to
             frame, and cnet is the dominant per-frame encoder cost at
-            streaming shapes (COST_REPORT_r10.json).  Unsupported with
+            streaming shapes (not re-measured on the v5e).  Unsupported with
             ``shared_backbone`` (fnet is computed FROM the cnet trunk
             there, so nothing is saved) and with ``rows_gru``.
           return_ctx: test-mode only — also return that context bundle
@@ -307,8 +307,8 @@ class RAFTStereo(nn.Module):
                     x, norm_fn, dtype, mesh=rows_mesh, axis=rows_axis)
 
         # Phase annotations (profiling.annotate = TraceAnnotation +
-        # jax.named_scope): device traces break out the same phases the
-        # bench's realtime_phase_split line reports.
+        # jax.named_scope): device traces break out these phases (only
+        # Mosaic calls keep the scope's name on the TPU: PERF.md section 3).
         if cfg.shared_backbone:
             both = jnp.concatenate([image1, image2], axis=0)
             with annotate("cnet"):
@@ -329,7 +329,7 @@ class RAFTStereo(nn.Module):
             # Scanning fnet over the two images SEQUENTIALLY (weights shared,
             # lax.scan => strictly ordered) halves that peak vs the batch-2
             # concat — the difference between fitting Middlebury-F-class
-            # frames on a 16 GB chip or not (docs/TRAIN_PROFILE.md round 2).
+            # frames on a 16 GB chip or not (_STEM_EXTRA_BYTES_PER_PIXEL).
             # With banded_encoder, each trunk additionally streams its
             # full-resolution stages band by band (models/banded.py).
             if not reuse_ctx:
@@ -426,7 +426,7 @@ class RAFTStereo(nn.Module):
             disp = jax.lax.stop_gradient(disp)
             # Named so the remat policy below can SAVE this lookup's output:
             # the backward then reuses it instead of re-running the Pallas
-            # kernel (a measured ~10% of step time; docs/TRAIN_PROFILE.md).
+            # kernel (~10% of step time on an earlier runtime; config.py).
             corr = checkpoint_name(
                 corr_fn(grid_x + disp).astype(dtype), "corr_lookup")
             flow2 = jnp.stack([disp, jnp.zeros_like(disp)],

@@ -170,7 +170,7 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
             # power of two is fp-exact), so the whole pyramid samples in the
             # SINGLE multi-level launch (VMEM-gated) — not one launch per
             # level, which would reintroduce the per-custom-call overhead
-            # docs/TRAIN_PROFILE.md measured.
+            # a training trace showed (kernels/corr_lookup.py).
             shard = lax.axis_index(CORR_AXIS)
             offset = (shard * pyr[0].shape[-1]).astype(coords.dtype)
             out = _kernels.lookup_pyramid_fused(list(pyr), coords - offset,

@@ -366,9 +366,9 @@ def rows_sharded_gru_loop(cfg: RaftStereoConfig, dtype, update_params,
     # -training regime): keep H SHARDED over the rows axis so the encoders'
     # ≤1/2-res tail stays row-sharded end to end — measured on the 8-dev
     # virtual mesh at 2048x2880, an UNSHARDED pin left ~49 GiB/device of
-    # replicated tail backward stores (ROWSGRU_MEMORY_r05.json iters-6
-    # probe), dwarfing the sharded loop.  With a data axis > 1 the pin
-    # flips to H-UNSHARDED: tail convs sharded over (batch x rows)
+    # replicated tail backward stores (an iters-6 compile probe, not
+    # re-measured on the v5e), dwarfing the sharded loop.  With a data
+    # axis > 1 the pin flips to H-UNSHARDED: tail convs over (batch x rows)
     # simultaneously hit XLA's SPMD conv-KERNEL-gradient double-count
     # (reproduced and documented for the trunk executor,
     # parallel/rows_sharded.py); there the reshard happens at the

@@ -2,7 +2,7 @@
 
 The long-context analog of sequence parallelism for stereo: at
 full-resolution inputs the ENCODER STEM's activations — not the
-correlation volume — set peak HBM (docs/TRAIN_PROFILE.md, FULLRES_r02), and
+correlation volume — set peak HBM (PERF.md section 4, full resolution), and
 stereo correlation itself is per-image-row, so the image-row (H) axis is
 the natural context axis.  This module runs the trunk's full-resolution
 segment with H sharded across a mesh axis:

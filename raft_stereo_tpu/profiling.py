@@ -10,23 +10,19 @@ evaluate_stereo.py:77-82,105-107).  This module makes both first-class:
 * ``annotate(name)`` — named host spans that show up inside traces; wrap
   pipeline stages (decode, augment, device step) to see overlap.
 * ``FpsProtocol`` — the reference's FPS measurement protocol (warmup
-  discard, per-image wall time) plus a *chained* variant that takes the
-  host's dispatch out of a per-call time: K forwards are chained on
-  device inside ``lax.fori_loop`` and two chain lengths are differenced
-  (the method bench.py uses).
+  discard, per-image wall time), which the evaluation runner reports with.
+  What decides a PR's speed is ``benchmark/`` (``PERF.md``).
 """
 
 from __future__ import annotations
 
 import contextlib
-import functools
 import os
 import time
 from dataclasses import dataclass
 from typing import Callable, Iterable, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -42,7 +38,7 @@ def setup_compilation_cache() -> str:
     code names another directory.  Otherwise the cache goes to
     ``<checkout>/.jax_cache`` — a FIXED path: a directory named after a
     temp file, a pid or the time is never found again by the next process.
-    Every CLI, bench, tool and smoke calls this before its first compile
+    Every CLI, tool and smoke calls this before its first compile
     (a whole test-mode forward at published widths costs the v5e compiler
     30-40 s, the training step 100 s and more)."""
     from_env = os.environ.get(COMPILE_CACHE_ENV)
@@ -75,10 +71,9 @@ def annotate(name: str):
     code that RUNS inside the block, and — because model code is traced,
     not run — an XLA op-name scope (``jax.named_scope``) so every op staged
     out inside the block carries ``name/`` in its metadata.  Device traces
-    then break out the same phases the bench reports: the model wraps
+    then break out the model's phases: it wraps
     ``fnet``/``cnet``/``corr_pyramid``/``gru_iter``/``upsample``
-    (models/raft_stereo.py) and bench.py's ``realtime_phase_split`` line
-    reports encoder-vs-GRU wall time."""
+    (models/raft_stereo.py)."""
     with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
         yield
 
@@ -145,59 +140,3 @@ class FpsProtocol:
         mean = float(np.mean(times))
         return FpsResult(fps=1.0 / mean, mean_s=mean, per_image_s=times,
                          n_timed=len(times))
-
-
-def make_forward_chain(apply_fn: Callable, variables, img1, img2):
-    """The standard on-device forward chain for ``chained_seconds_per_call``:
-    K calls of ``apply_fn(variables, image1, image2)`` inside a jitted
-    ``fori_loop`` (inputs perturbed per iteration so XLA can't fold the
-    loop), synced by a scalar ``float()`` fetch.  The one canonical copy of
-    this scaffolding, used by bench.py / bench_product.py /
-    tools/inference_profile.py (bench_fullres.py and tools/fullres_gates.py
-    keep inline chains because the same compiled program doubles as their
-    ``memory_analysis`` subject) — see ``chained_seconds_per_call`` for the
-    timing pitfalls it guards against."""
-
-    @functools.partial(jax.jit, static_argnums=(3,))
-    def chain(variables, a, b, k):
-        def body(i, acc):
-            out = apply_fn(variables, a + i * 1e-6, b)
-            return acc + jnp.mean(out)
-        return jax.lax.fori_loop(0, k, body, jnp.float32(0))
-
-    return lambda k: (lambda: float(chain(variables, img1, img2, k)))
-
-
-def chained_seconds_per_call(make_chain: Callable[[int], Callable[[], object]],
-                             k_lo: int = 3, k_hi: int = 23,
-                             repeats: int = 3,
-                             reduce: Callable = np.median) -> float:
-    """Dispatch-robust per-call device time.
-
-    ``make_chain(k)`` must return a zero-arg callable that runs ``k``
-    device-chained iterations and blocks until a scalar is ready.  The
-    difference ``(t(k_hi) - t(k_lo)) / (k_hi - k_lo)`` cancels constant
-    dispatch overhead (see bench.py).
-    ``reduce`` combines the per-repeat estimates; the default ``median``
-    tolerates an outlier repeat.  Note ``min`` is the WRONG choice for this
-    difference estimator: a spike during a k_lo run biases that repeat's
-    difference low (possibly negative), and min would select exactly the
-    corrupted repeat.
-    """
-    chains = {k: make_chain(k) for k in (k_lo, k_hi)}
-    for k in (k_lo, k_hi):  # compile both
-        chains[k]()
-    estimates = []
-    for _ in range(repeats):
-        ts = {}
-        for k in (k_lo, k_hi):
-            t0 = time.perf_counter()
-            chains[k]()
-            ts[k] = time.perf_counter() - t0
-        estimates.append((ts[k_hi] - ts[k_lo]) / (k_hi - k_lo))
-    per_call = float(reduce(estimates))
-    if per_call <= 0:
-        raise RuntimeError(
-            f"non-positive per-call estimate {per_call!r}: timing noise "
-            f"exceeded the chained workload; raise k_hi or repeats")
-    return per_call

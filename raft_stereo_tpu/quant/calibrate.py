@@ -1,7 +1,7 @@
 """Post-training calibration: per-layer activation ranges for the int8
 inference tier, collected by running the model on in-distribution pairs.
 
-The BF16_DRIFT_r03-r05 series established this repo's rule for precision
+tools/bf16_drift.py established this repo's rule for precision
 claims: measure the drift in-distribution on the trained checkpoint, not
 on paper.  Calibration is the collection half of that rule for int8 —
 run the REAL forward (same padding semantics as ``eval/runner``, same

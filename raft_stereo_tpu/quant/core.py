@@ -1,10 +1,10 @@
 """Post-training int8 weight quantization for the inference tier.
 
-The cost report's roofline split classifies the encoders as the dominant
-per-frame cost at streaming shapes and the correlation lookup as
-memory-bound (COST_REPORT_r10.json), so the bytes a program MOVES — not
-the flops it runs — bound the turbo tier's throughput.  This module
-implements the weight half of the int8 story:
+The correlation lookup is memory-bound (PERF.md section 3) and the
+encoders were the dominant per-frame cost at streaming shapes on an
+earlier runtime (not re-measured on the v5e: ROADMAP R7), so the bytes a
+program MOVES — not the flops it runs — bound the turbo tier's
+throughput.  This module implements the weight half of the int8 story:
 
 * **Per-channel symmetric quantization** (Wu et al. 2020, "Integer
   Quantization for Deep Learning Inference" §4: per-output-channel scales
@@ -182,8 +182,8 @@ def tree_is_quantized(variables: Dict) -> bool:
 
 def quantized_param_bytes(variables: Dict) -> Dict[str, int]:
     """Byte accounting of one quantized tree: ``{"int8": n, "fp32": n,
-    "scales": n}`` — what the drift/bench tools report as the moved-bytes
-    win next to the measured FPS."""
+    "scales": n}`` — what the drift tools report as the moved-bytes
+    win."""
     acc = {"int8": 0, "fp32": 0, "scales": 0}
 
     def walk(tree):

@@ -2,8 +2,8 @@
 
 The r15 int8 tier moved BYTES — weights ship int8 but
 ``dequantize_variables`` upcasts at trace time, so every matmul still
-runs fp32 and CPU measured parity-within-noise (BENCH_SERVE_r15.json:
-turbo 0.95x balanced).  This module converts the bytes win into a flops
+runs fp32 (never timed on the chip: ROADMAP R7).
+This module converts the bytes win into a flops
 win (AQT-style, ROADMAP open item 1): the conv itself multiplies
 int8×int8 and accumulates int32 (``preferred_element_type=jnp.int32``
 — the MXU's native low-precision mode on TPU; XLA:CPU lowers the same

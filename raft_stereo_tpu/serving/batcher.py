@@ -20,8 +20,8 @@ pressure, not by a timer: below capacity every request dispatches the
 moment a worker is free (batch 1, minimum latency); once workers are busy
 the queue deepens and the next pop grabs a 4 or an 8.  This replaced the
 round-6 MicroBatcher, whose timed flush left the device idle while
-requests aged toward ``max_wait_ms`` (BENCH_SERVE_r06.json: queue-wait p95
-~4 s at offered 1.91 Hz with the device under-occupied).
+requests aged toward ``max_wait_ms`` with the device
+under-occupied.
 
 Model-agnostic on purpose: the queue never touches JAX, so every
 scheduling policy in this file is testable in milliseconds.

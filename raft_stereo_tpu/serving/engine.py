@@ -2,8 +2,8 @@
 telemetry in one place.
 
 This replaced the round-6 split of ``StereoService`` + ``MicroBatcher`` +
-per-worker ``InferenceRunner``.  That stack's best throughput was 1.015x
-solo inference (BENCH_SERVE_r06.json): its default "chain" mode dispatched
+per-worker ``InferenceRunner``.  That stack could not beat solo
+inference by design: its default "chain" mode dispatched
 the batch-1 program serially per request, its "stack" mode re-padded the
 batch axis to the next power of two and lost more than it gained, and its
 timed flush left the device idle while requests aged toward
@@ -329,7 +329,7 @@ class ServeConfig:
     # Keyframe guard: a WARM frame on an early-exit tier that runs to the
     # iteration cap never satisfied the convergence gate — its output may
     # be drifting (warm-start chains accumulate error when the GRU is
-    # not contracting; measured in STREAM_r14.json), so its state is not
+    # not contracting; seen on synthetic sequences), so its state is not
     # trusted and the NEXT frame cold-starts, re-seeding the chain from
     # a clean zero-init (the video-codec I-frame move).  Cold frames at
     # the cap stay trusted: that is the stateless baseline by
@@ -340,9 +340,9 @@ class ServeConfig:
     # hidden-state tree frame to frame alongside the disparity, so a
     # warm frame resumes the GRU's own trajectory instead of re-deriving
     # it from the context encoder (the half of RAFT's temporal state the
-    # r14 flow-only warm start left cold — STREAM_r14 measured tight
-    # convergence gates DIVERGING from cold-h warm starts).  Swaps the
-    # state/warm executable families for their ``_h`` variants (distinct
+    # r14 flow-only warm start left cold — tight convergence gates
+    # DIVERGED from cold-h warm starts on the synthetic sequences).  Swaps
+    # the state/warm executable families for their ``_h`` variants (distinct
     # compile-cost + persist keys); the scene-cut fallback, keyframe
     # guard, and crash demotion invalidate the h-tree in lockstep with
     # the flow state.  False (default): the r14 flow-only families,
@@ -392,8 +392,9 @@ class ServeConfig:
     # owns rows*corr devices (parallel.distributed.device_groups,
     # allocated AFTER the data_parallel solo workers) and answers one
     # request with all of them — per-device HBM drops ~1/N
-    # (ROWSGRU_MEMORY_r05.json: 141 GiB at rows=1 -> 13.8 GiB/device at
-    # 16 ways).  None (default): no xl tier; a replica whose device
+    # (a compiler's memory analysis on an earlier runtime: 141 GiB at
+    # rows=1 -> 13.8 GiB/device at 16 ways; not re-measured on the v5e).
+    # None (default): no xl tier; a replica whose device
     # count cannot supply the mesh SKIPS the tier with a typed log line
     # instead of failing at boot (compile-farm/fleet contract).  XL
     # programs are fixed-depth, full-precision, and stateless (no
@@ -406,7 +407,7 @@ class ServeConfig:
     # xl family automatically (clients can force any compatible request
     # with ?tier=xl).  Default ~2 MP: about where a 32-iteration
     # full-resolution pair stops being a sensible single-device dispatch
-    # (FULLRES_EVAL_r05.json: 16.5 s/image at 5.7 MP on one device).
+    # (PERF.md section 5: 1.76 s a 5.7 MP pair on one v5e; ROADMAP R3).
     xl_threshold_pixels: int = 2_000_000
     # The mesh's own ceiling: buckets past this many pixels exceed what
     # the declared device group can hold (size it from the mesh's
@@ -3729,11 +3730,10 @@ class ServingEngine:
             self.policy.note(bucket, real_px, dispatched_px)
             # MFU numerator: the batch-n executable's model flops, once per
             # dispatch.  NOTE XLA's cost_analysis counts a loop body ONCE
-            # regardless of trip count (scan and while alike —
-            # tools/cost_report.py records both undercounts), so this
-            # numerator never overstates under early exit; scale phase
-            # flops by the observed iters_used for honest per-phase MFU
-            # (cost_report --observed_iters).
+            # regardless of trip count (scan and while alike), so this
+            # numerator never overstates under early exit and under-reads
+            # a looped program; benchmark/flops.py counts from shapes
+            # instead, and its step_mfu_pct is what a PR is judged on.
             if self._mfu is not None:
                 rec = self.compiled_cost(bucket, batch=n, tier=tier,
                                          family=family, model=model)

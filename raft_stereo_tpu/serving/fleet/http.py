@@ -346,8 +346,8 @@ class _PooledHTTPServer(ThreadingHTTPServer):
     accepts queue in the kernel backlog (``request_queue_size``), at
     most ``max_workers`` requests execute concurrently, and an idle
     keep-alive is reaped by the handler timeout so a parked client
-    releases its worker.  bench_fleet.py is the receipt: the 5k/10k
-    session legs run against exactly this server."""
+    releases its worker.  No cell of the benchmark drives it yet
+    (ROADMAP R5)."""
 
     request_queue_size = 1024
     daemon_threads = True

@@ -3,7 +3,7 @@ registry (telemetry/registry.py).
 
 The Counter/Gauge/Histogram/MetricsRegistry implementations started life in
 this module; PR 3 promoted them to ``raft_stereo_tpu.telemetry.registry`` as
-the single implementation the training runtime and bench tooling share, and
+the single implementation the training runtime and the smokes share, and
 this module re-exports them so every existing ``serving.metrics`` import
 keeps working unchanged.  ``ServingMetrics`` — the serving subsystem's
 standard instrument set — still lives here.
@@ -51,8 +51,8 @@ class ServingMetrics:
     """The serving subsystem's standard instrument set, in one place so the
     batcher / workers / HTTP layer all record into the same names.
 
-    Latency is split into the three legs of the product path
-    (bench_product.py): queue wait (admission -> device worker pickup), device
+    Latency is split into the three legs of the product path:
+    queue wait (admission -> device worker pickup), device
     time (dispatch -> outputs ready), and fetch (device->host transfer of
     the results).
     """
@@ -489,8 +489,8 @@ class ServingMetrics:
 
     def bucket_pixels(self) -> Dict[str, Dict[str, int]]:
         """Per-bucket pixel accounting snapshot: ``{"HxW": {"real_px": n,
-        "pad_px": n}}`` — what bench_serve.py publishes next to the MFU
-        numbers and what the waste feedback loop acts on."""
+        "pad_px": n}}`` — what the waste feedback loop
+        (``--adaptive_buckets``) acts on."""
         with self._bucket_lock:
             return {label: {"real_px": pair[0].value,
                             "pad_px": pair[1].value}

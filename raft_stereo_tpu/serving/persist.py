@@ -1,8 +1,8 @@
 """Shared content-addressed executable artifact store: restart-to-ready
 (and fleet scale-out) in seconds, not compile-minutes.
 
-COST_REPORT_r10.json measured 23.6 s of XLA compile for the 7-iter
-realtime model *per shape bucket* — and rounds 11/12/14/15 multiplied
+A forward at published widths costs the v5e compiler 30-40 s *per shape
+bucket* (PERF.md section 5, set-up) — and rounds 11/12/14/15 multiplied
 the executable surface to (bucket x batch x tier x family).  A crashed,
 rescheduled, or newly scaled-out serving replica repays that entire
 product on boot, which at production scale means tens of seconds of dead

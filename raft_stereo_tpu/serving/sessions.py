@@ -434,7 +434,7 @@ class StereoSession:
 
     def iters_used_mean(self) -> Optional[float]:
         """Per-session mean GRU trip count — the number the close stats
-        and the streaming bench report per stream."""
+        report per stream."""
         if not self.iters_used_frames:
             return None
         return self.iters_used_sum / self.iters_used_frames

@@ -17,8 +17,8 @@ becomes a number:
   divided by the device's peak (``DEVICE_PEAK_TFLOPS`` auto table, or a
   ``--device_peak_tflops`` override).
 * **Arithmetic intensity / roofline**: flops / bytes-accessed against the
-  device ridge point classifies an executable (or a phase —
-  tools/cost_report.py) compute- vs memory-bound.
+  device ridge point classifies an executable compute- vs
+  memory-bound.
 * **`GET /debug/compiles`**: the executable inventory as JSON on both HTTP
   endpoints (telemetry/http.py ``handle_debug_get``).
 
@@ -232,10 +232,10 @@ def executable_cost(compiled) -> Dict[str, Any]:
 
 
 def aot_cost_summary(jitted, *args, **kwargs) -> Dict[str, Any]:
-    """One-shot helper for the bench scripts: AOT-compile ``jitted`` for
+    """One-shot helper: AOT-compile ``jitted`` for
     ``args`` and return ``{flops, bytes_accessed, arithmetic_intensity,
-    compile_s, memory, degraded}`` — the cost denominator a ``BENCH_*``
-    record carries next to its measured time (telemetry/events.py
+    compile_s, memory, degraded}`` — the cost denominator a record
+    carries next to its measured time (telemetry/events.py
     ``bench_record(rec, cost=...)``).  ``{"degraded": True}`` alone when
     even lowering fails."""
     try:

@@ -13,7 +13,7 @@ log lines.  This module is the one schema they consolidate onto:
   events (telemetry/train_metrics.py); ``replay()`` reads the file back
   into the run timeline (tests/test_telemetry.py replays one end to end).
 * ``bench_record()`` — wraps a bench result dict with the same
-  ``schema_version`` + run-metadata header, so every ``bench*.py`` JSON
+  ``schema_version`` + run-metadata header, so every smoke or tool JSON
   line/file is attributable to a device topology and a timestamp without
   each bench re-inventing the header.
 

@@ -24,9 +24,9 @@ Four acceptance properties, asserted end to end on CPU at tiny shapes
    split checked (ready only after the ladder is warm).
 
 Writes ``bench_record`` JSONs: chaos results to CHAOS_SMOKE_OUT
-(default CHAOS_r13.json) and the restart benchmark to RECOVERY_OUT
-(default RECOVERY_r13.json) — CI pins both to *_ci.json and uploads
-them.  Exit 0 on success, non-zero with a diagnostic on any failure.
+(default CHAOS_ci.json) and the restart benchmark to RECOVERY_OUT
+(default RECOVERY_ci.json) — CI uploads both.  Exit 0 on success,
+non-zero with a diagnostic on any failure.
 
 Run from the repo root:  JAX_PLATFORMS=cpu python scripts/chaos_smoke.py
 """
@@ -45,9 +45,9 @@ sys.path.insert(0, _REPO)
 sys.path.insert(0, os.path.join(_REPO, "tests"))
 
 OUT = os.environ.get("CHAOS_SMOKE_OUT",
-                     os.path.join(_REPO, "CHAOS_r13.json"))
+                     os.path.join(_REPO, "CHAOS_ci.json"))
 RECOVERY_OUT = os.environ.get("RECOVERY_OUT",
-                              os.path.join(_REPO, "RECOVERY_r13.json"))
+                              os.path.join(_REPO, "RECOVERY_ci.json"))
 
 
 class _RecordingSink:

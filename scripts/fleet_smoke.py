@@ -59,15 +59,10 @@ Round-23 observability legs (on the live 3-replica fleet):
     into exactly ONE coordinated flight-recorder dump: router bundle +
     all three replicas' bundles, one manifest under the trigger trace
     id.
-11. **Small-N load record** — ``bench_fleet.py --quick`` against stub
-    replicas -> FLEET_BENCH_OUT (default BENCH_FLEET_ci.json; the full
-    10k-session sweep is the repo-root ``bench_fleet.py`` ->
-    BENCH_FLEET_r23.json).
 
-Writes ``bench_record`` JSON to FLEET_OUT (default FLEET_r16.json) and
-the HA legs to FLEET_HA_OUT (default FLEET_HA_r18.json; CI pins
-FLEET_ci.json / FLEET_HA_ci.json and uploads both).  Exit 0 on success,
-non-zero with a diagnostic on any violation.
+Writes ``bench_record`` JSON to FLEET_OUT (default FLEET_ci.json) and
+the HA legs to FLEET_HA_OUT (default FLEET_HA_ci.json; CI uploads
+both).  Exit 0 on success, non-zero with a diagnostic on any violation.
 
 Run from the repo root:  JAX_PLATFORMS=cpu python scripts/fleet_smoke.py
 """
@@ -94,11 +89,9 @@ sys.path.insert(0, _REPO)
 sys.path.insert(0, os.path.join(_REPO, "tests"))
 sys.path.insert(0, os.path.join(_REPO, "tools"))
 
-OUT = os.environ.get("FLEET_OUT", os.path.join(_REPO, "FLEET_r16.json"))
+OUT = os.environ.get("FLEET_OUT", os.path.join(_REPO, "FLEET_ci.json"))
 HA_OUT = os.environ.get("FLEET_HA_OUT",
-                        os.path.join(_REPO, "FLEET_HA_r18.json"))
-BENCH_OUT = os.environ.get("FLEET_BENCH_OUT",
-                           os.path.join(_REPO, "BENCH_FLEET_ci.json"))
+                        os.path.join(_REPO, "FLEET_HA_ci.json"))
 
 HW = (48, 64)
 ITERS = 2
@@ -1013,14 +1006,6 @@ def main() -> int:
         print(json.dumps(rec))
         write_record(OUT, rec, indent=1)
         print(f"fleet smoke OK -> {OUT}")
-
-        # ---- 11. small-N router load record (bench_fleet --quick) ----
-        import bench_fleet
-
-        rc = bench_fleet.main(["--quick", "--skip_real",
-                               "--out", BENCH_OUT])
-        assert rc == 0, "quick bench_fleet leg failed"
-        print(f"fleet load record -> {BENCH_OUT}", flush=True)
         return 0
     except BaseException:
         for r in replicas:

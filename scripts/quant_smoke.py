@@ -56,7 +56,7 @@ OUT = os.environ.get("QUANT_CI_OUT", os.path.join(_REPO, "QUANT_ci.json"))
 STEPS = int(os.environ.get("QUANT_SMOKE_STEPS", "120"))
 ITERS_CAP = 6
 # CI drift budget: a 120-step 32x48 network is NOT the trained
-# checkpoint the 0.05 px product gate (QUANT_DRIFT_r22.json) applies
+# checkpoint the 0.05 px product gate (tools/quant_drift.py) applies
 # to; the smoke asserts the tier is sane, not product-accurate.
 CI_GATE_PX = 0.5
 
@@ -217,7 +217,7 @@ def main() -> int:
         "value": round(depe_mxu, 4),
         "unit": f"int8_mxu dEPE px vs fp32 (cap {ITERS_CAP}, "
                 f"{hw[0]}x{hw[1]}, {STEPS} steps, CPU; product gate in "
-                f"QUANT_DRIFT_r22.json)",
+                f"tools/quant_drift.py)",
         "train_steps": STEPS,
         "epe_fp32": round(epe_fp, 4),
         "epe_int8": round(epe_q, 4),

@@ -10,9 +10,10 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(__file__))
-# repo root too: tests import the root-level bench modules (e.g.
-# bench_loader's tree builder), which are tracked sources, so the suite
-# must resolve them when pytest is invoked from any directory.
+# repo root too: tests import the package, chip_smoke.py and benchmark/
+# from the checkout, so the suite must resolve them when pytest is invoked
+# from any directory.  (The SceneFlow fixture tree, ``build_tree``, lives
+# in tests/golden_data.py.)
 sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
 from _hermetic import force_cpu  # noqa: E402
 

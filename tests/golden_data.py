@@ -320,6 +320,37 @@ def make_things(root: str, rng, n: int = 2, hw=(60, 90),
         frame_utils.write_pfm(os.path.join(dseq, "0006.pfm"), disp)
 
 
+def build_tree(root: str, n_pairs: int, seed: int = 0,
+               hw=(540, 960)) -> None:
+    """FlyingThings3D/frames_cleanpass/TRAIN layout (the training recipe's
+    input, reference: core/stereo_datasets.py:123-184) at SceneFlow's
+    native 540x960 by default, with realistic content: smooth low-frequency
+    images (PNG deflate cost sits between noise and natural images) and a
+    smooth positive disparity field.  The loader tests and chip_smoke.py's
+    training phase read it."""
+    h, w = hw
+    rng = np.random.default_rng(seed)
+    base = np.kron(rng.uniform(0, 255, (-(-h // 20), -(-w // 20), 3)),
+                   np.ones((20, 20, 1)))[:h, :w]
+
+    for i in range(n_pairs):
+        seq = os.path.join(root, "FlyingThings3D", "frames_cleanpass",
+                           "TRAIN", "A", f"{i:04d}")
+        dseq = os.path.join(root, "FlyingThings3D", "disparity", "TRAIN",
+                            "A", f"{i:04d}", "left")
+        os.makedirs(os.path.join(seq, "left"), exist_ok=True)
+        os.makedirs(os.path.join(seq, "right"), exist_ok=True)
+        os.makedirs(dseq, exist_ok=True)
+        noise = rng.integers(0, 30, (h, w, 3))
+        left = np.clip(base + noise, 0, 255).astype(np.uint8)
+        right = np.clip(np.roll(base, -12, axis=1) + noise, 0,
+                        255).astype(np.uint8)
+        disp = (8.0 + 40.0 * rng.random((h, w))).astype(np.float32)
+        Image.fromarray(left).save(os.path.join(seq, "left", "0006.png"))
+        Image.fromarray(right).save(os.path.join(seq, "right", "0006.png"))
+        frame_utils.write_pfm(os.path.join(dseq, "0006.pfm"), disp)
+
+
 def make_middlebury(root: str, rng, n: int = 2, hw=(60, 90),
                     split: str = "H", hard: bool = False) -> None:
     """MiddEval3/training<split>/<scene>/{im0,im1,disp0GT.pfm,mask0nocc.png}

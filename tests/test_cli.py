@@ -236,7 +236,7 @@ def test_runner_batched_matches_per_image(tiny_checkpoint):
 @pytest.mark.quick  # overrides the module slow mark: runner-construction only
 def test_runner_deep_iters_bf16_corr_guard():
     """iters >= DEEP_ITERS_FP32_CORR with bf16 corr flips corr_fp32 in the
-    runner's effective config (measured 32-iter drift, BF16_DRIFT_r03.json);
+    runner's effective config (measured 32-iter drift, tools/bf16_drift.py);
     the as-given config is preserved for identity comparisons, and
     corr_fp32_auto=False opts out (tools/bf16_drift.py measures raw bf16)."""
     import dataclasses

@@ -146,7 +146,7 @@ def test_aot_compile_falls_back_when_lowering_fails():
 
 
 def test_aot_cost_summary_bench_denominator():
-    """bench.py attaches this summary to its JSON record."""
+    """The summary a tool attaches to its JSON record."""
     s = aot_cost_summary(jax.jit(lambda x: (x @ x).sum()), jnp.ones((8, 8)))
     assert s["flops"] > 0 and s["bytes_accessed"] > 0
     assert s["compile_s"] > 0 and not s["degraded"]
@@ -399,42 +399,10 @@ def test_train_step_cost_instrumented(tmp_path):
     assert stats[-1]["mfu"] > 0
 
 
-# ----------------------------------------------------- cost report tool
-def test_cost_report_tool_phases_sum_and_classify(tmp_path):
-    """Acceptance: per-phase flop totals sum to the whole-model
-    executable's flops within tolerance, and every phase gets a roofline
-    classification."""
-    import tools.cost_report as cost_report
-
-    out = str(tmp_path / "COST_REPORT_test.json")
-    # The CPU has no published peak, so the roofline the phases are
-    # classified against is named: the v5e's (without the two flags every
-    # bound is "unknown" here — never a borrowed TPU default).
-    assert cost_report.main(["--config", "tiny", "--height", "64",
-                             "--width", "96", "--iters", "2",
-                             "--device_peak_tflops", "197",
-                             "--device_peak_gbps", "819",
-                             "--out", out]) == 0
-    with open(out) as f:
-        rep = json.load(f)
-    assert rep["schema_version"] >= 1 and rep["metric"] == "cost_report"
-    phases = rep["phases"]
-    assert set(phases) == {"fnet", "cnet", "corr_pyramid", "gru_iter",
-                           "upsample", "other"}
-    for name, p in phases.items():
-        assert p["bound"] in ("compute", "memory"), name
-        assert p["flops"] is not None, name
-    assert phases["gru_iter"]["flops"] > 0
-    assert phases["gru_iter"]["per_iteration"]["flops"] > 0
-    assert rep["sum_check"]["rel_err"] < 1e-6
-    assert rep["whole_model"]["memory"]["argument_size_in_bytes"] > 0
-    # the deployed scan executable is recorded with its caveat
-    assert "deployed_scan_executable" in rep
-
-
 def test_unrolled_gru_matches_scan(tiny_model):
-    """unroll_gru (the cost tool's compile subject) runs the same math as
-    the deployed scan."""
+    """unroll_gru (every iteration as its own set of operations, which
+    benchmark/tests/test_reference.py applies) runs the same math as the
+    deployed scan."""
     from raft_stereo_tpu.models.raft_stereo import RAFTStereo
 
     cfg, variables = tiny_model

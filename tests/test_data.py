@@ -205,13 +205,13 @@ def test_loader_epoch_reshuffles(tmp_path):
 def test_sceneflow_loader_decode_throughput(tmp_path):
     """Guards the PFM+PNG decode -> DenseAugmentor -> batch path on the
     SceneFlow disk layout (the training recipe's input, reference:
-    core/stereo_datasets.py:123-184).  Uses bench_loader's tree builder so
-    the benchmark and this guard can never drift apart; asserts correctness
-    and a very conservative throughput floor (the real demand check is
-    bench_loader.py on the bench host)."""
+    core/stereo_datasets.py:123-184).  Uses the tree builder of
+    tests/golden_data.py, the one chip_smoke.py's training phase reads;
+    asserts correctness and, on request, a very conservative throughput
+    floor."""
     import time
 
-    from bench_loader import build_tree
+    from golden_data import build_tree
     from raft_stereo_tpu.data.datasets import SceneFlow
 
     root = str(tmp_path / "sf")
@@ -233,9 +233,10 @@ def test_sceneflow_loader_decode_throughput(tmp_path):
     assert set(np.unique(b["valid"])) <= {0.0, 1.0}
     # 16 images decoded+augmented; wall-clock floors flake on oversubscribed
     # CI runners no matter the headroom, so the timing assert is opt-in
-    # (RAFT_TPU_TIMING_ASSERTS=1 on a quiet host).  Real throughput-vs-demand
-    # evidence is bench_loader.py's job on the bench host; the shape/dtype
-    # contract asserts above stay unconditional.
+    # (RAFT_TPU_TIMING_ASSERTS=1 on a quiet host).  Whether the loader keeps
+    # up with the chip's step is not measured yet (PERF.md §7 0a: no
+    # training cell); the shape/dtype contract asserts above stay
+    # unconditional.
     if os.environ.get("RAFT_TPU_TIMING_ASSERTS", "").lower() in (
             "1", "true", "yes"):
         assert 16 / dt > 2.0, f"decode path too slow: {16 / dt:.1f} images/s"

@@ -179,8 +179,7 @@ def test_train_step_with_device_photometric(rng):
 def test_process_worker_loader_matches_sync(tmp_path):
     """worker_type='process' yields byte-identical batches in the same
     order as the synchronous path (determinism is scheduling-free)."""
-    from bench_loader import build_tree
-
+    from golden_data import build_tree
     from raft_stereo_tpu.data.datasets import SceneFlow
     from raft_stereo_tpu.data.loader import StereoLoader
 

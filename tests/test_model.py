@@ -163,5 +163,5 @@ def test_fullres_gates_are_memory_derived(monkeypatch):
     assert b_narrow % 2 == 0 and b_wide % 2 == 0
     assert banded._BAND_MIN <= b_wide <= b_narrow <= banded._BAND_MAX
     # At the round-2 measurement shape the derivation reproduces the band
-    # that carried FULLRES_r02.json within a factor of ~2.
+    # that carried the round-2 measurements within a factor of ~2.
     assert 128 <= banded.default_band_rows(1, 2880) <= 512

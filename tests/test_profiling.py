@@ -1,7 +1,6 @@
 """Profiling subsystem (raft_stereo_tpu/profiling.py) on the CPU backend."""
 
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -30,20 +29,6 @@ def test_fps_protocol_needs_more_than_warmup():
     proto = profiling.FpsProtocol(warmup=50)
     with pytest.raises(ValueError, match="warmup"):
         proto.measure(lambda x: x, [(0,), (1,)])
-
-
-def test_chained_seconds_per_call_cancels_overhead():
-    per_call = 2e-3
-    overhead = 20e-3
-
-    def make_chain(k):
-        def run():
-            time.sleep(overhead + k * per_call)
-        return run
-
-    est = profiling.chained_seconds_per_call(make_chain, k_lo=2, k_hi=10,
-                                             repeats=2)
-    assert est == pytest.approx(per_call, rel=0.5)
 
 
 def test_trace_writes_profile(tmp_path):
@@ -128,38 +113,6 @@ def test_annotate_nesting_composes_scopes():
     with profiling.annotate("outer"):
         with profiling.annotate("inner"):
             pass
-
-
-def test_bench_phase_split_math():
-    """bench.py's realtime_phase_split line: differencing the 7-iter and
-    1-iter forwards attributes per-GRU-iter vs fixed (encoder+) time."""
-    import bench
-
-    # synthetic: 0.9 ms fixed + 1.1 ms/iter
-    split = bench.phase_split(t_iters_s=0.9e-3 + 7 * 1.1e-3,
-                              t_one_iter_s=0.9e-3 + 1.1e-3, iters=7)
-    assert split["metric"] == "realtime_phase_split"
-    assert split["per_gru_iter_ms"] == pytest.approx(1.1, abs=1e-3)
-    assert split["encoder_and_fixed_ms"] == pytest.approx(0.9, abs=1e-3)
-    assert split["gru_share_at_7_iters"] == pytest.approx(
-        7 * 1.1 / (0.9 + 7 * 1.1), abs=1e-3)
-
-
-def test_bench_regression_warnings():
-    """The warn-on-regression comparison against BASELINE.json's published
-    phase split: quiet within the noise band, loud past it."""
-    import bench
-
-    good = bench.phase_split(t_iters_s=0.9e-3 + 7 * 0.5e-3,
-                             t_one_iter_s=0.9e-3 + 0.5e-3, iters=7)
-    assert bench.check_regression(good, fps=150.0) == []
-
-    bad = bench.phase_split(t_iters_s=0.9e-3 + 7 * 5.0e-3,
-                            t_one_iter_s=0.9e-3 + 5.0e-3, iters=7)
-    warns = bench.check_regression(bad, fps=20.0)
-    kinds = " ".join(w["warning"] for w in warns)
-    assert "per_gru_iter_ms" in kinds
-    assert "north-star" in kinds
 
 
 # ------------------------------------------------- the compile-cache rule

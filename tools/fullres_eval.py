@@ -2,7 +2,7 @@
 trainingF scale (VERDICT round 3, item 5).
 
 Round 3 benched the full-res machinery (banded encoder, sequential fnet,
-no-volume alt kernel) as bare forwards (bench_fullres.py); this runs the
+no-volume alt kernel) as bare forwards; this runs the
 actual product surface — ``eval.validate.validate_middlebury`` (per-image
 valid-mask/threshold semantics proven equal to the reference's validator,
 tests/test_eval_parity.py) — over a synthetic MiddEval3 trainingF tree at
@@ -111,7 +111,7 @@ def main():
                         "TRAINED_EVAL_r05.json)")
 
     # Compiled peak HBM of the forward at the exact eval shape (the runtime
-    # exposes no live memory stats — bench_fullres.py) .
+    # exposes no live memory stats).
     imgf = jnp.zeros((1,) + HW + (3,), jnp.float32)
     lowered = jax.jit(lambda v, a, b: model.apply(v, a, b, iters=ITERS,
                                                   test_mode=True)[1]

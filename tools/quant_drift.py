@@ -58,7 +58,7 @@ sys.path.insert(0, _REPO)
 OUT = os.environ.get("QUANT_DRIFT_OUT",
                      os.path.join(_REPO, "QUANT_DRIFT_r22.json"))
 SCALES_OUT = os.environ.get("QUANT_SCALES_OUT",
-                            os.path.join(_REPO, "QUANT_SCALES_r22.json"))
+                            os.path.join(_REPO, "quant_scales.json"))
 
 
 def build_parser():
