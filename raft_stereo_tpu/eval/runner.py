@@ -420,6 +420,25 @@ def _crop_flat(flows, pads):
     return flows[:, t:flows.shape[1] - b, l:flows.shape[2] - r].reshape(-1)
 
 
+# The least a sub-batch of a pipelined call stages (both images of its
+# pairs, as uploaded): 16 of the realtime cell's 128 KITTI uint8 pairs are
+# 46.0e6 B.  Read on the v5e (PERF.md section 6, PR 31): a program of 16
+# pairs costs 5.67 ms a pair against 5.03 at 128, which is what a finer
+# split would pay more of, and a coarser one leaves more of the first fill
+# and upload and of the last fetch exposed.
+_SUB_BATCH_MIN_BYTES = 40 << 20
+
+
+def _sub_batches(n: int, staged_bytes: int) -> int:
+    """How many equal sub-batches ``_run_padded`` runs a fixed-depth call
+    of ``n`` pairs and ``staged_bytes`` as: the most that divide ``n`` and
+    leave each ``_SUB_BATCH_MIN_BYTES`` to stage; 1 is the call as one
+    program (a call of one, a call under twice that, an ``n`` that no such
+    number divides)."""
+    most = min(n, staged_bytes // _SUB_BATCH_MIN_BYTES)
+    return max((k for k in range(2, most + 1) if n % k == 0), default=1)
+
+
 class InferenceRunner:
     """``runner(image1, image2)`` → full-resolution disparity-flow (H, W).
 
@@ -649,15 +668,20 @@ class InferenceRunner:
         return flows[0], elapsed
 
     def run_batch(self, images1, images2) -> Tuple[np.ndarray, float]:
-        """Batched product mode: ONE host->device upload, ONE compiled
-        forward, ONE fetch for N same-shape pairs — amortizes the per-image
-        dispatch and transfer setup.  The per-image ``__call__`` remains
-        the reference protocol (evaluate_stereo.py:60-109 is per-image by
-        definition); this is the throughput surface.
+        """Batched product mode: N same-shape pairs through ONE compiled
+        program — amortizes the per-image dispatch and transfer setup.  A
+        small call is one upload, one launch and one fetch; a large
+        fixed-depth call is launched as equal sub-batches of that one
+        program, each one's fill, upload and fetch under another's execute
+        (``_run_padded``, ``_sub_batches``), and every pair's answer is
+        what its sub-batch alone would give.  The per-image ``__call__``
+        remains the reference protocol (evaluate_stereo.py:60-109 is
+        per-image by definition); this is the throughput surface.
 
         Args: ``images1``/``images2`` — sequences of (H, W, 3) images, all
-        the same shape.  Returns ``(flows (N, H, W), seconds)``; the stop
-        clock is the result fetch, as in ``__call__``.
+        the same shape.  Returns ``(flows (N, H, W), seconds)``, the array
+        the caller's own; the stop clock is the (last) result fetch, as in
+        ``__call__``.
         """
         assert len(images1) == len(images2) and len(images1) > 0
         shape = np.asarray(images1[0]).shape
@@ -675,63 +699,114 @@ class InferenceRunner:
         call of the same shape, batch and dtype (only the latest pair, so
         host memory does not grow with ``max_cached_shapes``); the result
         is cropped on the device and fetched at its own size, row-major, so
-        ``unpad`` is left the reshape (the array is read-only: it is the
-        fetch's own memory).  The first ``infer.execute`` of a (padded
-        shape, batch) also carries ``compiled=1`` and ``paths``, the
-        choices ``kernels.corr_lookup.log_path_once`` was told while the
-        program was traced (fnet sequential or batched, the lookup's
-        launches, fused gates or Flax a level).  The seconds run from the
-        first phase's start to the end of ``fetch``, as they always have.
-        A runner's calls do not interleave (no caller in the repo drives
-        one runner from two threads): the next call refills the pair,
-        after ``execute`` has waited for the program and with it for the
+        ``unpad`` is left the reshape.
+
+        A large fixed-depth call (``_sub_batches``) runs as ``chunks``
+        equal sub-batches, contiguous row ranges of the one staging pair,
+        through ONE compiled program of batch ``n / chunks``, pipelined on
+        this thread by jax's asynchronous dispatch: sub-batch k is filled,
+        uploaded and launched, its crop enqueued right behind it, and only
+        then is sub-batch k-1 fetched, which waits for program k-1 alone
+        and copies its piece into the call's new ``(n, H, W)`` result while
+        program k runs.  The device is left to wait for the first
+        sub-batch's fill and upload and for the last one's fetch.  In such
+        a call ``execute`` is the launch and the wait for the program is in
+        ``fetch``.  Every other call (``chunks`` 1) is one program, which
+        ``execute`` waits for, and its result is the fetch's own memory,
+        read-only.  Every span carries ``chunk`` and ``chunks``.
+
+        The first ``infer.execute`` of a (padded shape, batch) also carries
+        ``compiled=1`` and ``paths``, the choices
+        ``kernels.corr_lookup.log_path_once`` was told while the program
+        was traced (fnet sequential or batched, the lookup's launches,
+        fused gates or Flax a level).  The seconds run from the first
+        phase's start to the end of the last ``fetch``, as they always
+        have.  A runner's calls do not interleave (no caller in the repo
+        drives one runner from two threads): within a call no row of the
+        pair is written twice, and the next call refills the pair after
+        this one has waited for its last program (in the last ``fetch``,
+        or in ``execute`` where there is one) and with it for every
         upload."""
         n = len(images1)
         images1 = [np.asarray(im) for im in images1]
         images2 = [np.asarray(im) for im in images2]
+        h, w, c = images1[0].shape
+        padder = InputPadder((1, h, w, c), divis_by=self.divis_by)
+        l, r, t, b = padder.pads
+        key = ((n, t + h + b, l + w + r, c),
+               np.result_type(*{im.dtype for im in images1 + images2}))
+        staged = 2 * int(np.prod(key[0])) * key[1].itemsize
+        chunks = 1 if self.early_exit else _sub_batches(n, staged)
+        m = n // chunks                                # pairs a sub-batch
+        flows = None
 
-        def phase(name: str, **attrs):
-            return self.phases.phase(name, batch_size=n, **attrs)
+        def phase(name: str, k: int, **attrs):
+            return self.phases.phase(name, batch_size=n, chunk=k,
+                                     chunks=chunks, **attrs)
 
-        with phase("stack_pad") as first:
-            h, w, c = images1[0].shape
-            padder = InputPadder((1, h, w, c), divis_by=self.divis_by)
-            l, r, t, b = padder.pads
-            key = ((n, t + h + b, l + w + r, c),
-                   np.result_type(*{im.dtype for im in images1 + images2}))
-            reused = self._staging[0] == key
-            if not reused:
-                self._staging = (None, None)        # free the old pair first
-                self._staging = (key, (np.empty(*key), np.empty(*key)))
-            p1, p2 = self._staging[1]
-            _fill_edge_padded(p1, images1, padder.pads)
-            _fill_edge_padded(p2, images2, padder.pads)
-            first.set(bytes=p1.nbytes + p2.nbytes, reused=reused)
-            builds = (p1.shape[1:3], n) not in self._compiled
-            fwd = self._forward_for(p1.shape[1:3], batch=n)
-        with phase("upload", bytes=p1.nbytes + p2.nbytes):
-            # returns at once; the launch waits for the copy, in ``execute``
-            d1, d2 = jnp.asarray(p1), jnp.asarray(p2)
-        with phase("execute") as execute:
-            said = path_choices() if builds else None
-            out = jax.block_until_ready(fwd(self.variables, d1, d2))
-            if builds:
-                # this call traced and built the executable: the span says
-                # so, with the shape-driven choices the trace made
-                execute.set(compiled=1, paths="; ".join(
-                    m for m, times in path_choices().items()
-                    if times != said.get(m, 0)))
-        with phase("fetch") as fetch:
-            if self.early_exit:
-                out, iters_used = out
-                self._note_iters_used(iters_used)
-            flows = np.asarray(_crop_flat(out, pads=padder.pads))
-            fetch.set(bytes=flows.nbytes)
-            if flows.dtype != np.float32:          # half-precision fetch
-                flows = flows.astype(np.float32)
-        with phase("unpad"):
+        def fetch(k: int, crop, iters_used):
+            nonlocal flows
+            with phase("fetch", k) as fetched:
+                if iters_used is not None:
+                    self._note_iters_used(iters_used)
+                if chunks == 1:
+                    flows = piece = np.asarray(crop)
+                    if flows.dtype != np.float32:  # half-precision fetch
+                        flows = flows.astype(np.float32)
+                else:
+                    if flows is None:
+                        flows = np.empty(n * h * w, np.float32)
+                    rows = flows[k * m * h * w:(k + 1) * m * h * w]
+                    # New memory costs a page fault a page on its first
+                    # write: take them now, under the program this fetch
+                    # is about to wait for, not in the copy behind it.
+                    rows.fill(0)
+                    piece = np.asarray(crop)
+                    rows[:] = piece
+                fetched.set(bytes=piece.nbytes)
+            return fetched
+
+        queued = None        # the sub-batch launched and not yet fetched
+        for k in range(chunks):
+            rows = slice(k * m, (k + 1) * m)
+            with phase("stack_pad", k) as fill:
+                reused = self._staging[0] == key
+                if not reused:
+                    self._staging = (None, None)    # free the old pair first
+                    self._staging = (key, (np.empty(*key), np.empty(*key)))
+                p1, p2 = (p[rows] for p in self._staging[1])
+                _fill_edge_padded(p1, images1[rows], padder.pads)
+                _fill_edge_padded(p2, images2[rows], padder.pads)
+                fill.set(bytes=p1.nbytes + p2.nbytes, reused=reused)
+                builds = (p1.shape[1:3], m) not in self._compiled
+                fwd = self._forward_for(p1.shape[1:3], batch=m)
+            if k == 0:
+                first = fill
+            with phase("upload", k, bytes=p1.nbytes + p2.nbytes):
+                # returns at once; the launch waits for the copy
+                d1, d2 = jnp.asarray(p1), jnp.asarray(p2)
+            with phase("execute", k) as execute:
+                said = path_choices() if builds else None
+                out = fwd(self.variables, d1, d2)
+                if chunks == 1:
+                    out = jax.block_until_ready(out)
+                if builds:
+                    # this call traced and built the executable: the span
+                    # says so, with the shape-driven choices the trace made
+                    execute.set(compiled=1, paths="; ".join(
+                        msg for msg, times in path_choices().items()
+                        if times != said.get(msg, 0)))
+                out, iters_used = out if self.early_exit else (out, None)
+                # right behind its program and before the next one, or the
+                # fetch of this sub-batch would wait for the next program
+                crop = _crop_flat(out, pads=padder.pads)
+            if queued is not None:
+                fetch(k - 1, *queued)
+            queued = (crop, iters_used)
+        last = fetch(chunks - 1, *queued)
+        with phase("unpad", chunks - 1):
             flows = flows.reshape(n, h, w)
-        return flows, fetch.t_end - first.t_start
+        return flows, last.t_end - first.t_start
 
     # ------------------------------------------------------------- streaming
     def _stream_forward_for(self, padded_hw: Tuple[int, int], warm: bool,

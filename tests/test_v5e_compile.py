@@ -35,6 +35,10 @@ SCENEFLOW_TRAIN = (8 * 80, 180, (180, 90, 45, 22))  # batch 8, 320x720
 MIDDLEBURY_F = (496, 720, 256)                     # 1/4-res H, W, fnet D
 # the realtime bulk cell: 1/8-res rows of 384x1248 pairs, all four levels
 REALTIME_BULK = (48, 156, (156, 78, 39, 19), 256)
+# its call: 128 padded 384x1248 uint8 pairs, which the runner launches as
+# this many pipelined sub-batches (PERF.md section 4)
+BULK_CALL = (128, (384, 1248))
+BULK_SUB_BATCHES = 8
 
 
 @pytest.fixture(scope="module")
@@ -271,22 +275,42 @@ def test_accuracy_forward_compiles(one_chip, kernels_on):
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
+def _bulk_call_sub_batches():
+    """The rule of eval/runner._run_padded on the realtime cell's call."""
+    from raft_stereo_tpu.eval.runner import _sub_batches
+
+    pairs, (hp, wp) = BULK_CALL
+    return _sub_batches(pairs, 2 * pairs * hp * wp * 3)     # uint8 pairs
+
+
+def test_bulk_rule_splits_the_cells_call():
+    """No compile: given the cell's call the rule picks what ``PERF.md`` §4
+    says it picks, so a CPU run sees the shape the cell launches."""
+    assert _bulk_call_sub_batches() == BULK_SUB_BATCHES
+    assert BULK_CALL[0] % BULK_SUB_BATCHES == 0
+
+
+@pytest.mark.parametrize("pairs", ["call", "sub_batch"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float16])
-def test_bulk_crop_compiles_to_one_flat_result(one_chip, dtype):
+def test_bulk_crop_compiles_to_one_flat_result(one_chip, dtype, pairs):
     """The bulk runner's crop (eval/runner._crop_flat) at the realtime
-    cell's call of 128 KITTI pairs: its 1-D result is what makes the fetch
-    arrive row-major, and it takes the forward's result in the layout the
+    cell's call of 128 KITTI pairs and at the sub-batch the runner's rule
+    launches it as: its 1-D result is what makes the fetch arrive
+    row-major, and it takes the forward's result in the layout the
     compiler gives it (no 3-D result whose layout the compiler may pick)."""
     from raft_stereo_tpu.eval.runner import _crop_flat
 
-    compiled = _crop_flat.lower(_sds((128, 384, 1248), dtype, one_chip),
+    n, (hp, wp) = BULK_CALL
+    if pairs == "sub_batch":
+        n //= _bulk_call_sub_batches()
+    compiled = _crop_flat.lower(_sds((n, hp, wp), dtype, one_chip),
                                 pads=(3, 3, 4, 5)).compile()
     (result,) = jax.tree_util.tree_leaves(compiled.out_info)
-    assert result.shape == (128 * 375 * 1242,) and result.dtype == dtype
+    assert result.shape == (n * 375 * 1242,) and result.dtype == dtype
     memory = compiled.memory_analysis()
     assert (memory.output_size_in_bytes
             < 1.01 * result.size * result.dtype.itemsize)
-    assert memory.temp_size_in_bytes < 0.3e9
+    assert memory.temp_size_in_bytes < 0.3e9 * n / BULK_CALL[0]
 
 
 def test_data_parallel_train_step_compiles(data_mesh, kernels_on):
