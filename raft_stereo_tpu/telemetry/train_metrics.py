@@ -42,7 +42,7 @@ from typing import Dict, Iterable, Optional
 from raft_stereo_tpu.telemetry.events import EventLog
 from raft_stereo_tpu.telemetry.registry import (DEFAULT_LATENCY_BUCKETS,
                                                 MetricsRegistry)
-from raft_stereo_tpu.telemetry.spans import SpanTracer
+from raft_stereo_tpu.telemetry.spans import Phases, SpanTracer
 from raft_stereo_tpu.telemetry.watchdog import AnomalySink, NonFiniteSentinel
 
 log = logging.getLogger(__name__)
@@ -50,6 +50,11 @@ log = logging.getLogger(__name__)
 # The cost-registry key the train loop instruments its jitted step under
 # (training/train_loop.py) and the drain's MFU computation looks up.
 TRAIN_STEP_COST_KEY = "train.step"
+
+# Host phases of the train loop (spans ``train.<name>``,
+# ``train_phase_seconds{phase=}``): the first four on the loop's thread, in
+# the order a step meets them, ``upload`` on the device prefetcher's thread.
+TRAIN_PHASES = ("data_wait", "dispatch", "drain", "checkpoint", "upload")
 
 # Pixel-scale buckets for GRU disparity-delta magnitudes: sub-milli-px
 # (converged) up to tens of px (early iterations at SceneFlow disparities).
@@ -151,6 +156,12 @@ class TrainTelemetry:
         self.anomaly_sink = AnomalySink(events=events, recorder=recorder)
         self.nonfinite = NonFiniteSentinel(self.anomaly_sink)
         self._trace = None  # the most recent sampled step's Trace
+        # Each phase scoped once by the loop (telemetry/spans.py): an event
+        # of an open profiler capture, on the device events' clock, and
+        # ``train_phase_seconds{phase=}``.  The histograms below that
+        # predate it stay, fed by the same readings.
+        self.phases = Phases("train.", TRAIN_PHASES, r,
+                             "train_phase_seconds")
         self.steps = r.counter(
             "train_steps_total", "optimization steps completed this run")
         self.anomalies = r.counter(

@@ -15,12 +15,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import logging
 import os
 import signal
 import threading
 import time
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,12 @@ def merge_warm_start_config(caller_cfg: RaftStereoConfig,
 # cost: depth x batch bytes): the upload of batch N+1 overlaps the device
 # compute of step N instead of sitting between two dispatches.
 _DEVICE_PREFETCH_DEPTH = 2
+
+
+def _no_phase(name: str, **attrs):
+    """``telemetry.phases.phase`` with no telemetry: a scope that reads no
+    clock and makes no event."""
+    return contextlib.nullcontext()
 
 
 class _DevicePrefetcher:
@@ -182,6 +189,21 @@ class _DevicePrefetcher:
             atexit.register(self._thread.join, timeout)
 
 
+def build_loader(train_cfg: TrainConfig, data_root: str,
+                 checkpoint_dir: str, name: str) -> StereoLoader:
+    """The loader ``train()`` reads when it is handed none: the recipe's
+    mixture under ``data_root`` through ``StereoLoader``'s own defaults
+    (workers, prefetch), this process's shard of it, the quarantine list
+    beside the run's checkpoints.  A caller that wants to see the batches a
+    run trains on wraps THIS and hands it in as ``loader=``."""
+    mixture = build_training_mixture(train_cfg, data_root)
+    return StereoLoader(mixture, batch_size=train_cfg.batch_size,
+                        seed=train_cfg.seed,
+                        quarantine_path=os.path.join(
+                            checkpoint_dir, f"{name}.quarantine.json"),
+                        **distributed.loader_shard_kwargs())
+
+
 def train(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
           name: str = "raft-stereo",
           data_root: str = "datasets",
@@ -192,7 +214,9 @@ def train(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
           loader: Optional[StereoLoader] = None,
           use_mesh: bool = True,
           warm_start: bool = False,
-          telemetry=None) -> TrainState:
+          telemetry=None,
+          should_stop: Optional[Callable[[int, TrainState], bool]] = None
+          ) -> TrainState:
     """Run the training loop; returns the final state.
 
     ``restore`` accepts a previous run's checkpoint directory (exact resume,
@@ -205,17 +229,28 @@ def train(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
     ``train_cfg.validation_frequency`` steps; ``model_cfg`` is the
     AUTHORITATIVE architecture (a checkpoint restore re-derives it, so a
     config captured at CLI time could be stale).
-    ``loader`` overrides dataset construction (used by tests).
+    ``loader`` stands in for ``build_loader``'s (tests; a caller that
+    records the batches a run trains on wraps that one).
     ``telemetry`` is an optional ``telemetry.TrainTelemetry``: step-time
     split, memory gauges, recompile detection, structured run events, and
     — layer 2 — per-step span traces (reconstructed from the timings this
-    loop already clocks; TrainConfig.trace_sample_rate), a non-finite
+    loop already clocks; TrainConfig.trace_sample_rate), the host phases
+    ``train.data_wait`` / ``dispatch`` / ``drain`` / ``checkpoint`` /
+    ``upload`` scoped once each (``telemetry.phases``: an event of an open
+    profiler capture and ``train_phase_seconds{phase=}``), a non-finite
     loss/grad sentinel riding the buffered metric drain, a step-stall
     watchdog, and a flight recorder that bundles the evidence on anomaly
     (cli/train.py wires all of it for --metrics_port).  When None — the
     default — the loop takes the exact pre-telemetry path: no extra
     timing calls, no extra device fetches (tests/test_telemetry.py and
     tests/test_observability.py pin this).
+    ``should_stop(step, state)`` is asked once a loop iteration, at the one
+    place where a SIGTERM's request is looked at, with the steps dispatched
+    so far and the state they leave; true stops the run as the signal does
+    (final checkpoint with its exact-resume sidecar, clean return).  The
+    loop runs ahead of the device: ``state``'s arrays are ready only once
+    the device has run those steps, so a caller that wants to know that
+    step ``step`` is DONE waits on them (``jax.block_until_ready``).
     """
     # Defensive: form the process group (no-op single-host / already done)
     # BEFORE the jax.devices() call below latches the backend.
@@ -261,14 +296,16 @@ def train(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
             ctx.enter_context(rows_sharding(mesh, axis=ROWS_AXIS))
         return _train_impl(model_cfg, train_cfg, name, data_root,
                            checkpoint_dir, restore, log_dir, validate_fn,
-                           loader, mesh, warm_start, telemetry)
+                           loader, mesh, warm_start, telemetry, should_stop)
 
 
 def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
                 name: str, data_root: str, checkpoint_dir: str,
                 restore: Optional[str], log_dir: str, validate_fn,
                 loader: Optional[StereoLoader], mesh,
-                warm_start: bool = False, telemetry=None) -> TrainState:
+                warm_start: bool = False, telemetry=None,
+                should_stop=None) -> TrainState:
+    phase = telemetry.phases.phase if telemetry is not None else _no_phase
     h, w = train_cfg.image_size
     init_shape = (1, h, w, 3)
     rng = jax.random.PRNGKey(train_cfg.seed)
@@ -353,13 +390,7 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
         state = replicate(state, mesh)
 
     if loader is None:
-        mixture = build_training_mixture(train_cfg, data_root)
-        loader = StereoLoader(mixture, batch_size=train_cfg.batch_size,
-                              seed=train_cfg.seed,
-                              quarantine_path=os.path.join(
-                                  checkpoint_dir,
-                                  f"{name}.quarantine.json"),
-                              **distributed.loader_shard_kwargs())
+        loader = build_loader(train_cfg, data_root, checkpoint_dir, name)
     # Fast-forward the loader to the checkpointed position (a no-op
     # without a runtime sidecar: legacy checkpoints keep the old
     # restart-at-epoch-0 behavior).  set_state is duck-typed so test
@@ -422,6 +453,7 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
     # step boundary, then a clean exit.  Preempted TPU VMs deliver SIGTERM;
     # with exact-resume checkpoints the run continues where it stopped.
     stop_requested = False
+    stop_asked = False          # by ``should_stop``
     prev_handlers = {}
 
     def _restore_handlers():
@@ -461,34 +493,37 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
         def drain_metrics():
             if not pending_metrics:
                 return
-            t_drain = time.perf_counter() if telemetry is not None else 0.0
-            fetched = jax.device_get(pending_metrics)
-            pending_metrics.clear()
-            first = step - len(fetched) + 1
-            # One vectorized schedule eval for the whole span (the per-step
-            # float(schedule(step)) alternative is itself a device sync).
-            lrs = np.asarray(schedule(np.arange(first, step + 1)))
-            # The gru_delta_px entry is a VECTOR (per-iteration convergence
-            # curve, TrainConfig.gru_telemetry) — split it off before the
-            # scalar-only logger sees the dicts.
-            gru_deltas = [m.pop("gru_delta_px") for m in fetched
-                          if "gru_delta_px" in m]
-            for m, lr in zip(fetched, lrs):
-                logger.push(m, lr=float(lr))
-            if tracker is not None:
-                # The anomaly tracker consumes the drained per-step skip
-                # flags (already host floats — zero extra fetches, the
-                # NonFiniteSentinel contract) and arms the rewind check
-                # the loop runs right after each drain.
-                for offset, m in enumerate(fetched):
-                    kind = tracker.observe(first + offset, m)
-                    if kind is not None and telemetry is not None:
-                        telemetry.observe_anomaly_skip(first + offset, kind)
+            with phase("drain", step=step,
+                       window=len(pending_metrics)) as drained:
+                fetched = jax.device_get(pending_metrics)
+                pending_metrics.clear()
+                first = step - len(fetched) + 1
+                # One vectorized schedule eval for the whole span (the
+                # per-step float(schedule(step)) alternative is itself a
+                # device sync).
+                lrs = np.asarray(schedule(np.arange(first, step + 1)))
+                # The gru_delta_px entry is a VECTOR (per-iteration
+                # convergence curve, TrainConfig.gru_telemetry) — split it
+                # off before the scalar-only logger sees the dicts.
+                gru_deltas = [m.pop("gru_delta_px") for m in fetched
+                              if "gru_delta_px" in m]
+                for m, lr in zip(fetched, lrs):
+                    logger.push(m, lr=float(lr))
+                if tracker is not None:
+                    # The anomaly tracker consumes the drained per-step
+                    # skip flags (already host floats — zero extra fetches,
+                    # the NonFiniteSentinel contract) and arms the rewind
+                    # check the loop runs right after each drain.
+                    for offset, m in enumerate(fetched):
+                        kind = tracker.observe(first + offset, m)
+                        if kind is not None and telemetry is not None:
+                            telemetry.observe_anomaly_skip(first + offset,
+                                                           kind)
             if telemetry is not None:
                 means = ({k: float(np.mean([m[k] for m in fetched]))
                           for k in fetched[0]} if fetched else {})
-                telemetry.observe_drain(time.perf_counter() - t_drain,
-                                        means, step, window=len(fetched))
+                telemetry.observe_drain(drained.seconds, means, step,
+                                        window=len(fetched))
                 for d in gru_deltas:
                     telemetry.observe_gru_deltas(np.asarray(d).ravel())
                 if hasattr(loader, "stats"):
@@ -499,19 +534,33 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
         # upload is otherwise serial with compute (see _DevicePrefetcher).
         upload = ((lambda b: shard_batch(b, mesh)) if mesh is not None
                   else jax.device_put)
-        if train_cfg.compact_upload:
+
+        def compact(b):
+            # halve the GT bytes on the wire (config.compact_upload):
+            # fp16 flow + uint8 valid, cast back to f32 in train_step
+            c = dict(b)
+            if c["flow"].dtype == np.float32:
+                c["flow"] = c["flow"].astype(np.float16)
+            if c["valid"].dtype == np.float32:
+                c["valid"] = (c["valid"] > 0.5).astype(np.uint8)
+            return c
+
+        def put_from(first_step: int):
+            """The prefetch thread's upload of the batches of steps
+            ``first_step``, ``first_step + 1``, ..."""
+            steps = itertools.count(first_step)
+
             def put(b):
-                # halve the GT bytes on the wire (config.compact_upload):
-                # fp16 flow + uint8 valid, cast back to f32 in train_step
-                c = dict(b)
-                if c["flow"].dtype == np.float32:
-                    c["flow"] = c["flow"].astype(np.float16)
-                if c["valid"].dtype == np.float32:
-                    c["valid"] = (c["valid"] > 0.5).astype(np.uint8)
-                return upload(c)
-        else:
-            put = upload
-        batches = _DevicePrefetcher(iter(loader), put)
+                with phase("upload", step=next(steps)) as up:
+                    if train_cfg.compact_upload:
+                        b = compact(b)
+                    if telemetry is not None:
+                        up.set(batch_size=len(b["flow"]), bytes=sum(
+                            int(v.nbytes) for v in b.values()))
+                    return upload(b)
+            return put
+
+        batches = _DevicePrefetcher(iter(loader), put_from(start_step + 1))
         # Loader-position bookkeeping for the exact-resume sidecar: the
         # current iterator started at the loader's own start_offset when
         # the loop step counter read anchor_step, so the position after
@@ -589,7 +638,8 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
                         e, b = divmod(loader.start_offset, len(loader))
                         loader.add_salt(e, b, tracker.rewinds)
                 batches.close()
-                batches = _DevicePrefetcher(iter(loader), put)
+                batches = _DevicePrefetcher(iter(loader),
+                                            put_from(to_step + 1))
                 pending_metrics.clear()
                 state = new_state
                 step = to_step
@@ -609,11 +659,10 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
 
         try:
             while True:
-                # Telemetry timing is gated on ``telemetry is not None`` at
-                # every site: the disabled path is the exact pre-telemetry
-                # loop — no clock reads, no extra device fetches.
-                if telemetry is not None:
-                    t_loop = time.perf_counter()
+                # Without telemetry every ``phase`` scope is ``_no_phase``
+                # and every other site is gated on ``telemetry is not
+                # None``: the disabled path is the pre-telemetry loop — no
+                # clock reads, no extra device fetches.
                 # Fetch BEFORE the stop collective so loader exhaustion is
                 # part of the global stop decision: any_process's call-count
                 # invariant (once per loop iteration on EVERY process) would
@@ -621,32 +670,36 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
                 # left this loop early — the others would hang in the next
                 # allgather.  With exhaustion folded into the collective,
                 # all processes break together at the earliest exhaustion.
-                batch = next(batches, None)
-                if telemetry is not None:
-                    t_batch = time.perf_counter()
+                with phase("data_wait", step=step + 1) as waited:
+                    batch = next(batches, None)
                 # The stop decision must be GLOBAL: a signal lands on one
                 # host only, and every process has to break at the same step
                 # boundary before the collective checkpoint save
                 # (any_process is itself a collective — called once per loop
                 # iteration; `step` is identical on all processes so the
-                # short-circuit is consistent).
+                # short-circuit is consistent).  A caller's ``should_stop``
+                # is a stop request like the signal's.
+                stop_asked = (should_stop is not None
+                              and should_stop(step, state))
                 if step >= total or distributed.any_process(
-                        stop_requested or batch is None):
+                        stop_requested or stop_asked or batch is None):
                     break
                 if telemetry is not None:
                     telemetry.note_batch(batch)
-                if policy is not None:
-                    state, metrics, ewma_dev = step_fn(state, batch,
-                                                       ewma_dev)
-                else:
-                    state, metrics = step_fn(state, batch)
+                # dispatch leg only (async dispatch returns at submit); the
+                # device-bound tail shows up in the drain
+                with phase("dispatch", step=step + 1,
+                           batch_size=train_cfg.batch_size) as dispatched:
+                    if policy is not None:
+                        state, metrics, ewma_dev = step_fn(state, batch,
+                                                           ewma_dev)
+                    else:
+                        state, metrics = step_fn(state, batch)
                 step += 1
                 if telemetry is not None:
-                    # dispatch leg only (async dispatch returns at submit);
-                    # the device-bound tail shows up in the drain histogram
                     telemetry.observe_step(
-                        step, data_wait_s=t_batch - t_loop,
-                        dispatch_s=time.perf_counter() - t_batch)
+                        step, data_wait_s=waited.seconds,
+                        dispatch_s=dispatched.seconds)
                 pending_metrics.append(metrics)
                 if len(pending_metrics) >= SUM_FREQ:
                     drain_metrics()
@@ -685,7 +738,8 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
             # here cannot kill a half-written save.
             _save(os.path.join(checkpoint_dir, name), model_cfg, state,
                   step, telemetry, runtime_state=_runtime_blob())
-            run_status = "stopped" if stop_requested else "complete"
+            run_status = ("stopped" if stop_requested or stop_asked
+                          else "complete")
         finally:
             # Also on the exception path: a crash at step N must not discard
             # the buffered metrics of steps N-1..N-SUM_FREQ+1 — that window
@@ -700,9 +754,10 @@ def _train_impl(model_cfg: RaftStereoConfig, train_cfg: TrainConfig,
             if telemetry is not None:
                 telemetry.run_end(run_status, step)
 
-    if stop_requested:
-        log.warning("stopped by signal at step %d; resume with "
-                    "--restore_ckpt %s", step,
+    if stop_requested or stop_asked:
+        log.warning("stopped by %s at step %d; resume with "
+                    "--restore_ckpt %s",
+                    "signal" if stop_requested else "the caller", step,
                     os.path.join(checkpoint_dir, name))
     log.info("training done: %d steps in %.1fs", step - start_step,
              time.time() - t0)
@@ -759,9 +814,10 @@ def _set_host_rng(blob) -> None:
 
 def _save(path: str, model_cfg: RaftStereoConfig, state: TrainState,
           step: int, telemetry=None, runtime_state=None) -> None:
-    t0 = time.perf_counter() if telemetry is not None else 0.0
-    ckpt.save_checkpoint(path, model_cfg, _arrays_of(state),
-                         runtime_state=runtime_state)
+    phase = telemetry.phases.phase if telemetry is not None else _no_phase
+    with phase("checkpoint", step=step) as saved:
+        ckpt.save_checkpoint(path, model_cfg, _arrays_of(state),
+                             runtime_state=runtime_state)
     log.info("saved checkpoint %s", path)
     if telemetry is not None:
-        telemetry.observe_checkpoint(time.perf_counter() - t0, path, step)
+        telemetry.observe_checkpoint(saved.seconds, path, step)
