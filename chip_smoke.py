@@ -554,11 +554,14 @@ def _lookup_vs_float64(seed: int) -> None:
         want.append(taps)
     want = np.concatenate(want, axis=-1)
     scale = float(np.abs(want).max())
-    err = {name: float(np.abs(np.asarray(fn(pyramid, jnp.asarray(coords),
+    # the kernel reads its levels transposed, (B, H, W2_i, W1)
+    err = {name: float(np.abs(np.asarray(fn(pyr, jnp.asarray(coords),
                                            radius), np.float64)
                               - want).max())
-           for name, fn in (("kernel", lookup_pyramid_fused),
-                            ("XLA sampler", lookup_pyramid_xla))}
+           for name, fn, pyr in (
+               ("kernel", lookup_pyramid_fused,
+                [jnp.swapaxes(v, -1, -2) for v in pyramid]),
+               ("XLA sampler", lookup_pyramid_xla, pyramid))}
     say(f"compare: lookup alone at {h}x{w}, {levels} levels, fp32, vs "
         f"float64 NumPy (largest sample {scale:.2f}): "
         + ", ".join(f"{k} max |err| {v:.3g}" for k, v in err.items())

@@ -21,11 +21,11 @@ row at a time:
      to whole registers of 8, not to 128 lanes), the tile's 128 pixels on
      the lanes; entirely in VMEM (never written to HBM — the fusion of
      SURVEY.md §7's kernels 9b and 9c), then
-  2. samples vT along the sublanes (``sublane_sample``): the same numbers
-     as the reg_fused lookup kernel's ``hat_sample`` (kernels/
-     corr_lookup.py; tests/test_corr_alt.py holds the two together), got
-     with whole-register compares, selects and adds and one eight-sublane
-     reduction per window bin — no reduction across lanes — and
+  2. samples vT along the sublanes with the stored-volume kernel's own
+     ``sublane_sample`` (kernels/corr_lookup.py; tests/test_corr_alt.py
+     holds it to the plain ``hat_sample``): whole-register compares,
+     selects and adds and one eight-sublane reduction per window bin — no
+     reduction across lanes — and
   3. stores the taps as dense rows of a (K, W1B) block, pixels on the
      lanes; the result array is (rows, K, W1) and XLA takes the
      ``swapaxes`` to (rows, W1, K) as a layout of the next convolution's
@@ -36,7 +36,7 @@ across ``corr_levels`` the right features come from the W-pooled pyramid the
 XLA side builds once.
 
 Backward (custom VJP, mirroring the identity):
-    dv[w, x] = Σ_k g[w, k] · hat_k(x)        (the reg_fused backward kernel)
+    dv[w, x] = Σ_k g[w, k] · hat_k(x)        (``corr_lookup.hat_scatter``)
     df1      = dv @ f2
     df2      = dvᵀ @ f1
 both matmuls fused into the same tile pass, so the backward never
@@ -55,14 +55,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from raft_stereo_tpu.kernels.corr_lookup import (ROW_BLK, VMEM_BUDGET,
-                                                 W1_BLK, log_launch_choice,
+from raft_stereo_tpu.kernels.corr_lookup import (ROW_BLK, SUBLANES,
+                                                 VMEM_BUDGET, W1_BLK,
+                                                 log_launch_choice,
                                                  fused_lookup_available,
                                                  hat_scatter, row_blk_for,
+                                                 sublane_sample, w2_rows,
                                                  interpret_enabled as
                                                  _interpret)
-
-SUBLANES = 8      # rows of one float32 vector register
 
 
 def alt_fused_available() -> bool:
@@ -83,49 +83,6 @@ def alt_fused_fits(w2: int, d: int, itemsize: int, radius: int) -> bool:
 
 
 # ------------------------------------------------------------------ kernels
-def sublane_sample(vt, centers, radius: int, w2: int):
-    """Σ_x vt[x, w] · hat_k(x - centers[w]) for each tap k, for a volume
-    tile laid out TRANSPOSED: (W2p, W1B) float32 with the right image's
-    bins on the sublanes (W2p whole vector registers of 8; bins at and
-    beyond ``w2`` count as zero whatever they hold) and the tile's pixels
-    on the lanes, plus its (1, W1B) centers → 2·radius+1 rows of (1, W1B).
-
-    Same numbers as ``corr_lookup.hat_sample`` on the untransposed tile
-    (tests/test_corr_alt.py holds the two together, borders included), by
-    another route: the taps of one pixel sit one bin apart, so all of them
-    read the same 2·radius+2 consecutive bins ``floor(c) - radius + m``
-    and tap k is ``(1-a)·bin[k] + a·bin[k+1]`` with ``a = c - floor(c)``,
-    which is what the hat weights come to.  Each bin is picked out of the
-    tile by comparing a sublane iota with its index: per volume register
-    and bin one compare, one select and one add into that bin's
-    accumulator, whole registers on the vector unit; the eight sublanes of
-    an accumulator are reduced once at the end.  Nothing crosses lanes."""
-    w2p, lanes = vt.shape
-    bins = 2 * radius + 2
-    # beyond these every tap reads bins outside the row: clamping keeps
-    # the integer conversion in range and changes no result
-    c = jnp.clip(centers, -(radius + 2.0), w2 + radius + 1.0)
-    first = jnp.floor(c)
-    a = c - first
-    sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, lanes), 0)
-    # (bins, 8, W1B): for window bin m, how far tile 0's sublanes sit from
-    # that bin of the pixel on their lane; 0 marks the one to pick.  The
-    # bins ride a leading axis, so one traced operation is ``bins`` whole
-    # registers and the trace stays short (it is paid at every start-up)
-    off = (sub - (first.astype(jnp.int32) - radius))[None] - \
-        jax.lax.broadcasted_iota(jnp.int32, (bins, SUBLANES, lanes), 0)
-    acc = None
-    for x0 in range(0, w2p, SUBLANES):
-        v = vt[x0:x0 + SUBLANES]
-        if x0 + SUBLANES > w2:
-            v = jnp.where(sub < w2 - x0, v, 0.0)
-        hit = jnp.where(off == -x0, v[None], 0.0)
-        acc = hit if acc is None else acc + hit
-    g = jnp.sum(acc, axis=1, keepdims=True)            # (bins, 1, W1B)
-    taps = (1.0 - a) * g[:-1] + a * g[1:]
-    return [taps[k] for k in range(bins - 1)]
-
-
 @functools.partial(jax.jit, static_argnames=("radius", "w2", "inv_sqrt_d",
                                              "precision"))
 def _row_taps(f1, f2, centers, *, radius: int, w2: int, inv_sqrt_d: float,
@@ -215,14 +172,6 @@ def _precision_for(dtype) -> jax.lax.Precision:
             else jax.lax.Precision.DEFAULT)
 
 
-def _w2_rows(w2: int, itemsize: int) -> int:
-    """Rows of a right-feature block: ``w2`` rounded up to whole sublane
-    tiles of the feature dtype (8 rows of float32, 16 of bfloat16, 32 of a
-    one-byte grid), so the transposed tile is whole registers."""
-    tile = SUBLANES * max(1, 4 // itemsize)
-    return -(-w2 // tile) * tile
-
-
 # Mosaic fails to compile (not fall back) when a program's live set exceeds
 # VMEM, and at Middlebury-F scale (w2=720, d=256) the default ROW_BLK=8
 # working set is ~23 MB before double buffering — so large shapes shrink the
@@ -265,7 +214,7 @@ def _launch_fwd(f1, f2s, coords, radius: int, scales, rb: int,
         grid=(pl.cdiv(rows, rb), pl.cdiv(w1, W1_BLK)),
         in_specs=[pl.BlockSpec((rb, W1_BLK, d), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM)]
-                 + [pl.BlockSpec((rb, _w2_rows(w2, f2.dtype.itemsize), d),
+                 + [pl.BlockSpec((rb, w2_rows(w2, f2.dtype.itemsize), d),
                                  lambda i, j: (i, 0, 0),
                                  memory_space=pltpu.VMEM)
                     for f2, w2 in zip(f2s, widths)]

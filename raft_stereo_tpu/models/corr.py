@@ -157,13 +157,15 @@ def pool_axis(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
 pool_last_axis = pool_axis
 
 
-def build_corr_pyramid(corr: jnp.ndarray, num_levels: int) -> List[jnp.ndarray]:
-    """Level i has W2 // 2^i disparity bins.  The reference stores
+def build_corr_pyramid(corr: jnp.ndarray, num_levels: int,
+                       axis: int = -1) -> List[jnp.ndarray]:
+    """Level i has W2 // 2^i disparity bins along ``axis`` (-2 for the
+    transposed volume the lookup kernel reads).  The reference stores
     ``num_levels+1`` entries but only ever reads ``num_levels``
     (core/corr.py:122-125 vs :133) — we build exactly ``num_levels``."""
     pyramid = [corr]
     for _ in range(num_levels - 1):
-        pyramid.append(pool_last_axis(pyramid[-1]))
+        pyramid.append(pool_axis(pyramid[-1], axis))
     return pyramid
 
 
@@ -304,7 +306,13 @@ def make_corr_fn_alt(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
 def make_corr_fn_reg_fused(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
     """Pallas-fused pyramid lookup (≙ reference sampler/ CUDA extension).
 
-    Falls back to the XLA lookup when Pallas is unavailable (e.g. CPU tests).
+    Where the kernel runs, the pyramid is built TRANSPOSED, level i
+    (B,H,W2_i,W1): the kernel samples along the sublanes with the pixels
+    on the lanes (kernels/corr_lookup.py), so the volume is the product
+    ``fmap2 · fmap1ᵀ`` — the same products at the same precision, the
+    result's layout free on the MXU — and the levels pool along axis -2.
+    The XLA fallback (Pallas unavailable, e.g. CPU tests) keeps
+    (B,H,W1,W2_i) and ``lookup_pyramid_xla``, as ``reg`` does.
     Keeps the compute dtype of the inputs (bf16-safe).  With
     ``cfg.quant == "int8"`` the pyramid is stored int8 with per-level
     scales and the kernels dequantize in-register
@@ -316,14 +324,19 @@ def make_corr_fn_reg_fused(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
         lookup_pyramid_fused_q)
 
     compute_dtype = fmap1.dtype
+    fused = fused_lookup_available()
+    fmap1 = fmap1.astype(jnp.float32)
+    fmap2 = fmap2.astype(jnp.float32)
+    # the volume of (fmap2, fmap1) IS the transposed volume
+    volume = (build_corr_volume(fmap2, fmap1) if fused
+              else build_corr_volume(fmap1, fmap2))
     if corr_quant_enabled(cfg):
         # int8 from the fp32 volume (not the bf16 round-trip): one
         # rounding step instead of two.
-        pyramid_f32 = build_corr_pyramid(
-            build_corr_volume(fmap1.astype(jnp.float32),
-                              fmap2.astype(jnp.float32)), cfg.corr_levels)
-        pyramid_q, scales = quantize_pyramid(pyramid_f32, cfg)
-        if fused_lookup_available():
+        pyramid_q, scales = quantize_pyramid(
+            build_corr_pyramid(volume, cfg.corr_levels,
+                               axis=-2 if fused else -1), cfg)
+        if fused:
             scale_vec = _tap_scale_vector(scales, cfg.corr_radius)
 
             def corr_fn(coords):
@@ -340,11 +353,9 @@ def make_corr_fn_reg_fused(cfg: RaftStereoConfig, fmap1, fmap2) -> CorrFn:
                 return lookup_pyramid_xla(pyramid, coords, cfg.corr_radius)
         return corr_fn
 
-    pyramid = build_corr_pyramid(
-        build_corr_volume(fmap1.astype(jnp.float32),
-                          fmap2.astype(jnp.float32)).astype(compute_dtype),
-        cfg.corr_levels)
-    if fused_lookup_available():
+    pyramid = build_corr_pyramid(volume.astype(compute_dtype),
+                                 cfg.corr_levels, axis=-2 if fused else -1)
+    if fused:
         def corr_fn(coords):
             return over_data_axis(
                 lambda pyr, c: lookup_pyramid_fused(pyr, c,

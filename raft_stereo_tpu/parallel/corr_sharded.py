@@ -43,7 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from raft_stereo_tpu.config import RaftStereoConfig
 from raft_stereo_tpu.models.corr import (_window_coords, build_corr_volume,
-                                         pool_last_axis)
+                                         pool_axis)
 from raft_stereo_tpu.ops.sampler import linear_sampler_1d
 from raft_stereo_tpu.parallel.mesh import CORR_AXIS
 
@@ -110,21 +110,35 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
     if w2p != w2:
         fmap2 = jnp.pad(fmap2, ((0, 0), (0, 0), (0, w2p - w2), (0, 0)))
 
+    # Per-shard lookup.  Two implementations of the same contract (below);
+    # the kernel reads its shard volumes TRANSPOSED, (B, H, W2/n, W1), bins
+    # on the sublanes (kernels/corr_lookup.py), so where it runs the local
+    # volume is built so and pools and shards along axis -2.
+    from raft_stereo_tpu.kernels import corr_lookup as _kernels
+
+    use_kernel = (cfg.corr_backend == "reg_fused"
+                  and _kernels.fused_lookup_available())
+    w2_axis = -2 if use_kernel else -1
+    vol_spec = (P(None, None, CORR_AXIS, None) if use_kernel
+                else P(None, None, None, CORR_AXIS))
+
     def build_local(f1: jnp.ndarray, f2_local: jnp.ndarray
                     ) -> Tuple[jnp.ndarray, ...]:
-        vol = build_corr_volume(f1, f2_local)
+        vol = (build_corr_volume(f2_local, f1) if use_kernel
+               else build_corr_volume(f1, f2_local))
         shard = lax.axis_index(CORR_AXIS)
         pyramid = []
         for level in range(num_levels):
             if level:
                 # Shard widths stay even at every level (padding quantum), so
                 # local pooling equals the reference's global floor pooling.
-                vol = pool_last_axis(vol)
-            lw = vol.shape[-1]
+                vol = pool_axis(vol, w2_axis)
+            lw = vol.shape[w2_axis]
             # Zero bins at/after the reference's floor-semantics level width
             # so boundary taps read zero exactly like out-of-range sampling.
             global_bin = shard * lw + jnp.arange(lw)
-            vol = jnp.where(global_bin < widths[level], vol, 0.0)
+            keep = global_bin < widths[level]
+            vol = jnp.where(keep[:, None] if use_kernel else keep, vol, 0.0)
             pyramid.append(vol.astype(store_dtype))
         return tuple(pyramid)
 
@@ -133,12 +147,9 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
     pyramid = jax.shard_map(
         build_local, mesh=mesh, axis_names={CORR_AXIS},
         in_specs=(P(), P(None, None, CORR_AXIS, None)),
-        out_specs=tuple(P(None, None, None, CORR_AXIS)
-                        for _ in range(num_levels)),
+        out_specs=tuple(vol_spec for _ in range(num_levels)),
     )(fmap1, fmap2)
 
-    # Per-shard lookup.  Two implementations of the same contract:
-    #
     # * reg_fused → the Pallas kernel with shard-shifted centers, inside a
     #   FULL-manual shard_map (every mesh axis manual, check_vma=False —
     #   partial-manual cannot vma-check the Pallas primitive, and full-manual
@@ -147,11 +158,6 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
     # * reg → the XLA sampler in a partial-manual shard_map (batch axis
     #   automatic) — the pure-XLA correctness reference, exactly like the
     #   unsharded backend split.
-    from raft_stereo_tpu.kernels import corr_lookup as _kernels
-
-    use_kernel = (cfg.corr_backend == "reg_fused"
-                  and _kernels.fused_lookup_available())
-
     if use_kernel:
         # Full-manual requires explicit batch placement: split over the data
         # axis when the static batch divides it (the training/eval case),
@@ -172,14 +178,14 @@ def make_corr_fn_w2_sharded(cfg: RaftStereoConfig, fmap1: jnp.ndarray,
             # level, which would reintroduce the per-custom-call overhead
             # a training trace showed (kernels/corr_lookup.py).
             shard = lax.axis_index(CORR_AXIS)
-            offset = (shard * pyr[0].shape[-1]).astype(coords.dtype)
+            offset = (shard * pyr[0].shape[-2]).astype(coords.dtype)
             out = _kernels.lookup_pyramid_fused(list(pyr), coords - offset,
                                                 radius)
             return lax.psum(out.astype(jnp.float32), CORR_AXIS)
 
         lookup = jax.shard_map(
             lookup_local, mesh=mesh, axis_names=set(mesh.axis_names),
-            in_specs=(tuple(P(bspec, None, None, CORR_AXIS)
+            in_specs=(tuple(P(bspec, None, CORR_AXIS, None)
                             for _ in range(num_levels)), P(bspec)),
             out_specs=P(bspec),
             check_vma=False,

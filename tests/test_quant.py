@@ -602,8 +602,9 @@ def test_fp8_pyramid_lookup_parity_interpret():
         pytest.skip("this jax build has no float8_e4m3fn dtype")
     rng = np.random.default_rng(2)
     b, h, w1, radius = 1, 4, 32, 3
+    # the kernel's layout: level i is (B, H, W2_i, W1)
     pyramid_f32 = [
-        jnp.asarray(rng.normal(size=(b, h, w1, w2)).astype(np.float32))
+        jnp.asarray(rng.normal(size=(b, h, w2, w1)).astype(np.float32))
         for w2 in (32, 16, 8)]
     coords = jnp.asarray(
         rng.uniform(0, w1, size=(b, h, w1)).astype(np.float32))

@@ -101,26 +101,83 @@ def _kernel_calls(compiled):
 
 def _pyramid(shape, dtype, sharding):
     rows, w1, w2s = shape
-    # (B=1, H=rows, W1, W2): the entries flatten (B, H) to rows themselves
-    return ([_sds((1, rows, w1, w2), dtype, sharding) for w2 in w2s],
+    # (B=1, H=rows, W2, W1), the TRANSPOSED levels the kernel reads: the
+    # entries flatten (B, H) to rows themselves
+    return ([_sds((1, rows, w2, w1), dtype, sharding) for w2 in w2s],
             _sds((1, rows, w1), jnp.float32, sharding))
 
 
 # ------------------------------------------------------------- the lookups
-@pytest.mark.parametrize("shape,dtype,launches", [
-    # fp32 at KITTI width: the four levels' tiles do not fit one program's
-    # VMEM budget together, so the lookup runs one launch per level
-    (ACCURACY_KITTI, jnp.float32, 4),
-    (REALTIME_KITTI, jnp.bfloat16, 1),
-], ids=["accuracy-kitti-fp32", "realtime-kitti-bf16"])
-def test_lookup_compiles(one_chip, shape, dtype, launches):
+@pytest.mark.parametrize("shape,dtype", [
+    # fp32 at KITTI width: ONE launch an iteration in the served program
+    # since PR 33 (four before: the old body's hat field and product did
+    # not fit one program's VMEM budget together with the four tiles)
+    (ACCURACY_KITTI, jnp.float32),
+    (REALTIME_KITTI, jnp.bfloat16),
+    (SCENEFLOW_TRAIN, jnp.bfloat16),
+], ids=["accuracy-kitti-fp32", "realtime-kitti-bf16", "sceneflow-train-bf16"])
+def test_lookup_compiles(one_chip, shape, dtype):
+    from benchmark.trace_reduce import result_elements
     from raft_stereo_tpu.kernels.corr_lookup import lookup_pyramid_fused
 
     pyramid, coords = _pyramid(shape, dtype, one_chip)
     compiled = jax.jit(
         lambda pyr, c: lookup_pyramid_fused(pyr, c, RADIUS)
     ).lower(pyramid, coords).compile()
-    assert len(_kernel_calls(compiled)) == launches
+    (call,) = _kernel_calls(compiled)
+    # the cells' ``corr_lookup_roofline`` counts lookups from this shape
+    rows, w1, w2s = shape
+    assert result_elements(call) == rows * w1 * len(w2s) * K, call
+
+
+@pytest.mark.parametrize("shape,dtype,backward", [
+    (ACCURACY_KITTI, jnp.float32, False),
+    (SCENEFLOW_TRAIN, jnp.bfloat16, False),
+    (SCENEFLOW_TRAIN, jnp.bfloat16, True),
+], ids=["accuracy-kitti-fp32", "sceneflow-train-bf16",
+        "sceneflow-train-bf16-backward"])
+def test_lookup_plan_tracks_mosaic_scoped_vmem(one_chip, monkeypatch, shape,
+                                               dtype, backward):
+    """The launch plan's estimate (``corr_lookup._program_bytes``, half of
+    the scoped VMEM a program of ``ROW_BLK`` rows needs) against the
+    compiler itself: with ``vmem_limit_bytes`` at twice the estimate the
+    all-levels program compiles, at three quarters of that it is refused.
+    So the gate neither admits what Mosaic would refuse nor splits what
+    would fit with a quarter to spare."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from raft_stereo_tpu.kernels import corr_lookup
+
+    rows, w1, w2s = shape
+    itemsize = jnp.dtype(dtype).itemsize
+    per_row, fixed = corr_lookup._program_bytes(w2s, RADIUS, itemsize,
+                                                itemsize, backward)
+    scoped = 2 * (corr_lookup.ROW_BLK * per_row + fixed)
+    pyramid, coords = _pyramid(shape, dtype, one_chip)
+    g = _sds((1, rows, w1, len(w2s) * K), dtype, one_chip)
+
+    def run(pyr, c, g):
+        out, vjp = jax.vjp(
+            lambda p: corr_lookup.lookup_pyramid_fused(p, c, RADIUS), pyr)
+        return vjp(g) if backward else out
+
+    real = pl.pallas_call
+
+    def compiles(limit):
+        monkeypatch.setattr(pl, "pallas_call", lambda *a, **k: real(
+            *a, compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=int(limit)), **k))
+        jax.clear_caches()
+        try:
+            compiled = jax.jit(run).lower(pyramid, coords, g).compile()
+        except Exception as e:  # the compiler's refusal names the limit
+            assert "vmem" in str(e).lower(), e
+            return False
+        return len(_kernel_calls(compiled)) == 1
+
+    assert compiles(scoped)
+    assert not compiles(0.75 * scoped)
 
 
 @pytest.mark.parametrize("shape,q_dtype", [
@@ -190,14 +247,14 @@ def test_alt_lookup_compiles_at_realtime_bulk(one_chip, dtype):
     assert result_elements(call) == rows * w * len(w2s) * K, call
 
 
-@pytest.mark.parametrize("dtype,launches", [
-    (jnp.bfloat16, 1),
-    # fp32: the all-levels backward asks Mosaic for 16.32 MiB of scoped
-    # VMEM against a 16 MiB limit, so it runs one launch per level.
-    (jnp.float32, 4),
-], ids=["bf16", "fp32"])
-def test_lookup_backward_compiles_at_sceneflow_crop(one_chip, dtype,
-                                                    launches):
+# ONE all-levels launch in either dtype since PR 33 (the old body's fp32
+# backward asked Mosaic for 16.32 MiB against 16 and ran a launch a level).
+# Its first result is the finest level's cotangent, (rows, W2, W1): what
+# ``sceneflow.train.b4``'s trace pattern ``= (bf16[320,180,180]`` finds at
+# batch 4 and ``corr_lookup_bwd_roofline.train`` counts lookups from.
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_lookup_backward_compiles_at_sceneflow_crop(one_chip, dtype):
     from raft_stereo_tpu.kernels.corr_lookup import lookup_pyramid_fused
 
     pyramid, coords = _pyramid(SCENEFLOW_TRAIN, dtype, one_chip)
@@ -209,7 +266,9 @@ def test_lookup_backward_compiles_at_sceneflow_crop(one_chip, dtype,
         return vjp(g)
 
     compiled = jax.jit(pullback).lower(pyramid, coords, g).compile()
-    assert len(_kernel_calls(compiled)) == launches
+    (call,) = _kernel_calls(compiled)
+    hlo_type = "bf16" if dtype == jnp.bfloat16 else "f32"
+    assert f" = ({hlo_type}[{rows},{w2s[0]},{w1}]" in call, call
 
 
 # ---------------------------------------------------------- the ConvGRU gates
