@@ -29,7 +29,8 @@ reference's OWN replay with every product's inputs rounded to that
 precision (``compare.unit``), straight-through.  ``grad_gap_units`` and
 ``grad_gap_units_<module>`` are the program's gaps over that replay's gaps.
 
-Holds the chip while it runs; the reference takes one sample at a time.
+Holds the chip while it runs; the reference takes one sample at a time a
+chip (``reference_train.spread_over`` where the cell holds several).
 """
 
 from __future__ import annotations
@@ -170,6 +171,10 @@ def main(argv) -> int:
                 host_stand_in=not p["require_accelerator"])
 
     harness.require_chips(p["chips"], p["require_accelerator"])
+    import jax
+
+    # a cell that holds several chips replays that many samples at once
+    devices = jax.local_devices()[:p["chips"]]
     model = p["config"]["model"]
     t0 = time.monotonic()
     start = weights.make_weights(model, p["seed"])
@@ -185,10 +190,10 @@ def main(argv) -> int:
             reference_train.replay_pair(
                 model, p["recipe"], start, batches,
                 reference_train.straight_through(
-                    control.LOWER[p["unit"]["precision"]])))
+                    control.LOWER[p["unit"]["precision"]]), devices))
     else:
         arrays, mu, _nu, want_steps = reference_train.replay(
-            model, p["recipe"], start, batches)
+            model, p["recipe"], start, batches, devices=devices)
     want = {"params": arrays, "mu": mu}
     nums = training_numbers(start, got, got_steps, want, want_steps)
     if p.get("unit"):

@@ -180,13 +180,55 @@ def make_sample_grad(cfg: dict, recipe: dict, lower=None):
     return jax.jit(fn)
 
 
-def batch_grad(sample_grad, arrays: dict, batch: dict, on: bool = True):
+def spread_over(sample_grad, devices):
+    """``sample_grad`` for ``len(devices)`` samples at once, one a device,
+    their terms added: ONE program under ``shard_map``, in which every
+    device runs the one-sample gradient on its own sample and a ``psum``
+    adds the gradients, numerators and counts.  For a cell that holds
+    several chips: its batch is larger and its replay, one sample at a time
+    on one chip, would outlast the window.  The sum's order is the only
+    thing that differs from the samples taken in turn (float32 rounding of
+    eight terms)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(devices), ("sample",))
+    each, same = NamedSharding(mesh, P("sample")), NamedSharding(mesh, P())
+
+    def on_a_device(arrays, image1, image2, flow, valid, on):
+        return jax.tree_util.tree_map(
+            lambda x: lax.psum(x, "sample"),
+            sample_grad(arrays, image1, image2, flow, valid, on))
+
+    mapped = jax.jit(jax.shard_map(
+        on_a_device, mesh=mesh,
+        in_specs=(P(), P("sample"), P("sample"), P("sample"), P("sample"),
+                  P()), out_specs=P(),
+        # the reference's loops start from constants, which the checker of
+        # varying values would have cast by hand in every carry
+        check_vma=False))
+
+    def fn(arrays, image1, image2, flow, valid, on=True):
+        return mapped(jax.device_put(arrays, same),
+                      *jax.device_put((image1, image2, flow, valid), each),
+                      on)
+
+    return fn
+
+
+def batch_grad(sample_grad, arrays: dict, batch: dict, on: bool = True,
+               at_once: int = 1):
     """The batch's loss, its gradient and the last prediction's mean error:
-    the samples' terms added, then ONE division by the batch's count."""
+    the samples' terms added, then ONE division by the batch's count.
+    ``at_once``: the samples a call of ``sample_grad`` takes (1, or the
+    devices of ``spread_over``; it has to divide the batch)."""
     total, num, count, last = None, 0.0, 0.0, 0.0
-    for i in range(batch["image1"].shape[0]):
+    n = batch["image1"].shape[0]
+    if n % at_once:
+        raise ValueError(f"a batch of {n} in calls of {at_once} samples")
+    for i in range(0, n, at_once):
         g, n_i, c_i, l_i = sample_grad(
-            arrays, *(jnp.asarray(batch[k][i:i + 1])
+            arrays, *(jnp.asarray(batch[k][i:i + at_once])
                       for k in ("image1", "image2", "flow", "valid")), on)
         total = g if total is None else jax.tree_util.tree_map(
             jnp.add, total, g)
@@ -247,35 +289,48 @@ def adamw_step(arrays: dict, grads: dict, mu: dict, nu: dict, step: int,
     return dict(arrays, **new), mu, nu, norm
 
 
-def replay(cfg: dict, recipe: dict, arrays: dict, batches, lower=None):
+def replay(cfg: dict, recipe: dict, arrays: dict, batches, lower=None,
+           devices=None):
     """``len(batches)`` steps from ``arrays`` (``weights.make_weights``'s
     table) on ``batches`` (dicts of image1, image2 uint8 or float 0..255,
     flow = minus the disparity, valid, each with the batch axis first).
     Returns the final table, Adam's first and second moments, and per step
     the loss, the last prediction's mean error and the gradient's norm.
     ``lower``: the control's or the unit's hook, applied to every
-    product."""
-    return _replay(make_sample_grad(cfg, recipe, lower), recipe, arrays,
-                   batches, True)
+    product.  ``devices``: more than one takes that many samples at once
+    (``spread_over``)."""
+    return _replay(*_sample_grad_on(cfg, recipe, lower, devices), recipe,
+                   arrays, batches, True)
 
 
-def replay_pair(cfg: dict, recipe: dict, arrays: dict, batches, lower):
+def replay_pair(cfg: dict, recipe: dict, arrays: dict, batches, lower,
+                devices=None):
     """``(replay(...), replay(..., lower))`` from ONE compiled program: the
     plain replay is the lowered one's program with its hook switched
     off."""
-    sample_grad = make_sample_grad(cfg, recipe, lower)
-    return tuple(_replay(sample_grad, recipe, arrays, batches, on)
+    sample_grad, at_once = _sample_grad_on(cfg, recipe, lower, devices)
+    return tuple(_replay(sample_grad, at_once, recipe, arrays, batches, on)
                  for on in (False, True))
 
 
-def _replay(sample_grad, recipe: dict, arrays: dict, batches, on: bool):
+def _sample_grad_on(cfg: dict, recipe: dict, lower, devices):
+    """(the gradient's program, the samples a call of it takes)."""
+    sample_grad = make_sample_grad(cfg, recipe, lower)
+    if len(devices or ()) < 2:
+        return sample_grad, 1
+    return spread_over(sample_grad, devices), len(devices)
+
+
+def _replay(sample_grad, at_once: int, recipe: dict, arrays: dict, batches,
+            on: bool):
     params = [k for k in arrays if k.startswith("params/")]
     mu = {k: jnp.zeros_like(arrays[k]) for k in params}
     nu = {k: jnp.zeros_like(arrays[k]) for k in params}
     steps = []
     with jax.default_matmul_precision("highest"):
         for step, batch in enumerate(batches):
-            grads, loss, epe = batch_grad(sample_grad, arrays, batch, on)
+            grads, loss, epe = batch_grad(sample_grad, arrays, batch, on,
+                                          at_once)
             arrays, mu, nu, norm = adamw_step(arrays, grads, mu, nu, step,
                                               recipe)
             steps.append({"loss": float(loss), "epe": float(epe),

@@ -6,11 +6,10 @@ and the program's own "kernel path" log lines.  Compile facts, never chip
 runs; the source of PERF.md section 4's bytes.
 
     JAX_PLATFORMS=cpu python3 benchmark/tests/compile_train_for_v5e.py \
-        [sceneflow.train.b4] [--published_batch]
+        [sceneflow.train.b4] [sceneflow.train.b8-dp4]
 
-``--published_batch`` compiles the same cell's job at the published batch 8
-over ``data=4`` (2 pairs a chip): the four-chip cell that PERF.md section 7
-still lists first.
+With no cell named, both training cells: batch 4 on one chip, and the
+published batch 8 over ``data=4`` (2 pairs a chip).
 
 The kernel gates ask ``jax.default_backend()``, which is ``cpu`` here, so
 this script opens them itself, as ``tests/test_v5e_compile.py`` does.
@@ -27,7 +26,7 @@ sys.path.insert(0, ROOT)
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-CELLS = ("sceneflow.train.b4",)
+CELLS = ("sceneflow.train.b4", "sceneflow.train.b8-dp4")
 
 
 def compile_step(cell: dict):
@@ -107,12 +106,8 @@ def main(argv) -> int:
     import jax
 
     jax.config.update("jax_enable_compilation_cache", False)
-    published = "--published_batch" in argv
-    for name in [a for a in argv if not a.startswith("--")] or CELLS:
+    for name in argv or CELLS:
         cell = harness.load_cell(name)
-        if published:
-            cell = harness.TestRig(sizes={"traffic": {
-                "batch_size": 8, "data_parallel": 4}}).resized(cell)
         t0 = time.monotonic()
         compiled, n = compile_step(cell)
         m = compiled.memory_analysis()

@@ -4,7 +4,8 @@ boot, one open-loop window at each fixed rate.
     python3 benchmark/tests/knee_sweep.py <workload> --rates 1,2,3 [--seconds 20]
 
 The knee is the highest rate at which the backlog does not grow through the
-window; the workload files then carry 0.8 and 1.15 of it as numbers.
+window; the workload files then carry it (``knee_pairs_per_s``) beside the
+rate set from it (PERF.md section 4, Knee).
 """
 
 import argparse
