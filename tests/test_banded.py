@@ -6,16 +6,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raft_stereo_tpu.models import extractor
 from raft_stereo_tpu.models.banded import banded_trunk_apply
 from raft_stereo_tpu.models.extractor import _Trunk
 
 
 @pytest.mark.parametrize("norm_fn", ["instance", "batch", "none"])
 @pytest.mark.parametrize("h,w,band", [(64, 96, 32), (70, 96, 32)])
-def test_banded_matches_trunk(rng, norm_fn, h, w, band):
+def test_banded_matches_trunk(rng, monkeypatch, norm_fn, h, w, band):
     trunk = _Trunk(norm_fn, downsample=2, dtype=jnp.float32)
     x = jnp.asarray(rng.uniform(-1, 1, (2, h, w, 3)), jnp.float32)
     variables = trunk.init(jax.random.PRNGKey(0), x)
+    # the banded segment streams the plain stage; at two images the trunk
+    # would pack it (tests/test_trunk_packing.py holds that to this)
+    monkeypatch.setattr(extractor, "LANE_BATCH_IMAGES", 0)
     want = trunk.apply(variables, x)
 
     got = banded_trunk_apply(variables["params"],

@@ -372,6 +372,89 @@ def test_bulk_crop_compiles_to_one_flat_result(one_chip, dtype, pairs):
     assert memory.temp_size_in_bytes < 0.3e9 * n / BULK_CALL[0]
 
 
+# ------------------------------------------------- the trunk's 64-channel stage
+def _trunk_text(one_chip, monkeypatch, n, lanes):
+    """The realtime program's trunk (cnet: batch norm, the stem at stride
+    2) on ``n`` KITTI-bucket images in bf16, compiled with packing allowed
+    below ``lanes`` images."""
+    from raft_stereo_tpu.models import extractor
+
+    monkeypatch.setattr(extractor, "LANE_BATCH_IMAGES", lanes)
+    trunk = extractor._Trunk("batch", 3, jnp.bfloat16)
+    hp, wp = BULK_CALL[1]
+    variables = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: trunk.init(
+            jax.random.PRNGKey(0), jnp.zeros((2, 64, 64, 3), jnp.bfloat16))))
+    x = _sds((n, hp, wp, 3), jnp.bfloat16, one_chip)
+    return jax.jit(trunk.apply).lower(variables, x).compile().as_text()
+
+
+def _estimated_cycles(text):
+    import re
+
+    return sum(int(c) for c in re.findall(r'"estimated_cycles":"(\d+)"',
+                                          text))
+
+
+def test_trunk_stage_packs_at_realtime_bulk(one_chip, monkeypatch):
+    """At the realtime cell's sub-batch (16 pairs on the shared backbone:
+    32 images) the plain stage holds 64 channels on the 128 lanes
+    (``{3,...}``: the channels minor); the packed stage fills them, and the
+    compiler's own estimate for the whole trunk is the smaller."""
+    from raft_stereo_tpu.models.extractor import (LANE_BATCH_IMAGES,
+                                                  packs_two_images)
+
+    n = 2 * (BULK_CALL[0] // _bulk_call_sub_batches())
+    assert n == 32 and packs_two_images(n)
+    plain = _trunk_text(one_chip, monkeypatch, n, 0)
+    packed = _trunk_text(one_chip, monkeypatch, n, LANE_BATCH_IMAGES)
+    assert "bf16[32,192,624,64]{3," in plain
+    assert "bf16[16,192,624,128]{3," in packed
+    assert "bf16[32,192,624,64]" not in packed
+    assert _estimated_cycles(packed) < _estimated_cycles(plain)
+
+
+def test_trunk_stage_batch_takes_the_lanes_from_the_threshold(one_chip,
+                                                              monkeypatch):
+    """``LANE_BATCH_IMAGES`` from the compiler: at that many images the
+    plain stage already lays the batch on the lanes (``{0,...}``: the batch
+    minor), so there is nothing for packing to fill."""
+    from raft_stereo_tpu.models.extractor import LANE_BATCH_IMAGES
+
+    plain = _trunk_text(one_chip, monkeypatch, LANE_BATCH_IMAGES, 0)
+    assert f"bf16[{LANE_BATCH_IMAGES},192,624,64]{{0," in plain
+
+
+# The trunk calls of each cell's programs: images a call, whether the stage
+# packs.  Serving cells: the ladder's rungs of b pairs run cnet on b images
+# and fnet on 2b; realtime's shared backbone takes 2 x 16 pairs; the full-
+# resolution cell runs one image a call (fnet scans the two, cnet image1);
+# IGEV's cnet takes image1 of 16 pairs; the training steps run cnet on
+# image1 and fnet on both images of the global batch (4 pairs on one chip,
+# 8 over four).
+CELL_TRUNKS = [
+    ("accuracy.serve.kitti-steady", 1, False), ("accuracy.serve.kitti-steady",
+                                                2, True),
+    ("accuracy.serve.kitti-saturated", 8, True),
+    ("accuracy.serve.kitti-saturated", 16, True),
+    ("realtime.bulk.kitti", 32, True),
+    ("fullres.bulk.middlebury-f", 1, False),
+    ("igev.bulk.kitti", 16, True),
+    ("sceneflow.train.b4", 4, True), ("sceneflow.train.b4", 8, True),
+    ("sceneflow.train.b8-dp4", 8, True), ("sceneflow.train.b8-dp4", 16, True),
+    ("a realtime program of 128 pairs", 256, False),
+]
+
+
+@pytest.mark.parametrize("cell,images,packs", CELL_TRUNKS,
+                         ids=[f"{c}-{n}" for c, n, _ in CELL_TRUNKS])
+def test_trunk_packing_rule_at_each_cells_shapes(cell, images, packs):
+    from raft_stereo_tpu.models.extractor import packs_two_images
+
+    assert packs_two_images(images) is packs
+
+
 def test_data_parallel_train_step_compiles(data_mesh, kernels_on):
     """``make_train_step(train_cfg, mesh=<data=4>)`` with the default
     ``corr_backend="reg_fused"`` at the published SceneFlow crop (2 of the
