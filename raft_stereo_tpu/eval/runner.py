@@ -23,6 +23,7 @@ from raft_stereo_tpu.config import RaftStereoConfig
 from raft_stereo_tpu.kernels.corr_lookup import path_choices
 from raft_stereo_tpu.models.raft_stereo import RAFTStereo, create_model
 from raft_stereo_tpu.ops.padding import InputPadder
+from raft_stereo_tpu.profiling import annotate
 from raft_stereo_tpu.telemetry.spans import Phases
 
 log = logging.getLogger(__name__)
@@ -206,8 +207,9 @@ def make_forward(model: RAFTStereo, iters: int, fetch_dtype=None,
             raise ValueError(f"ctx={ctx!r}: use None, 'save', or 'reuse'")
 
         def fwd_stream(variables, images1, images2, *extra):
-            img1 = images1.astype(jnp.float32)
-            img2 = images2.astype(jnp.float32)
+            with annotate("prep"):
+                img1 = images1.astype(jnp.float32)
+                img2 = images2.astype(jnp.float32)
             pos = 0
             flow_init = None
             if warm_start:
@@ -229,7 +231,8 @@ def make_forward(model: RAFTStereo, iters: int, fetch_dtype=None,
                 **kwargs)
             flow_up = out[1]
             if fetch_dtype is not None:
-                flow_up = flow_up.astype(fetch_dtype)
+                with annotate("upsample"):
+                    flow_up = flow_up.astype(fetch_dtype)
             # flow_low stays float32 regardless of fetch_dtype: it is the
             # next frame's init, and a half-precision state would compound
             # rounding frame over frame.  (The hidden tree rides in the
@@ -262,8 +265,9 @@ def make_forward(model: RAFTStereo, iters: int, fetch_dtype=None,
         return jax.jit(fwd_stream, donate_argnums=donate)
 
     def fwd(variables, images1, images2):  # (N, Hp, Wp, 3)
-        img1 = images1.astype(jnp.float32)
-        img2 = images2.astype(jnp.float32)
+        with annotate("prep"):
+            img1 = images1.astype(jnp.float32)
+            img2 = images2.astype(jnp.float32)
         kwargs = {"return_confidence": True} if return_confidence else {}
         out = model.apply(variables if not quantized
                           else prepare(variables),
@@ -271,7 +275,8 @@ def make_forward(model: RAFTStereo, iters: int, fetch_dtype=None,
                           **kwargs)
         flow_up = out[1]
         if fetch_dtype is not None:
-            flow_up = flow_up.astype(fetch_dtype)
+            with annotate("upsample"):
+                flow_up = flow_up.astype(fetch_dtype)
         if return_confidence:
             # Base-signature confidence: (flow_up[, iters_used], conf) —
             # the conf element is the model's (conf_low, conf_up) tuple.
@@ -369,13 +374,15 @@ def make_forward_mesh(model: RAFTStereo, iters: int, mesh,
                             donate_images=donate_images)
 
     def fwd(variables, images1, images2):  # (N, Hp, Wp, 3)
-        img1 = images1.astype(jnp.float32)
-        img2 = images2.astype(jnp.float32)
+        with annotate("prep"):
+            img1 = images1.astype(jnp.float32)
+            img2 = images2.astype(jnp.float32)
         out = model.apply(variables, img1, img2, iters=iters,
                           test_mode=True)
         flow_up = out[1]
         if fetch_dtype is not None:
-            flow_up = flow_up.astype(fetch_dtype)
+            with annotate("upsample"):
+                flow_up = flow_up.astype(fetch_dtype)
         return flow_up
 
     # Replicated in, gathered out: the host uploads each image once per

@@ -427,23 +427,25 @@ class IGEVStereo(nn.Module):
         cfg, dtype = self.config, self.compute_dtype
         f32 = jnp.float32
         b = image1.shape[0]
-        images = jnp.concatenate([image1, image2]).astype(f32)
-        images = (2.0 * (images / 255.0) - 1.0).astype(dtype)
+        with annotate("prep"):
+            images = jnp.concatenate([image1, image2]).astype(f32)
+            images = (2.0 * (images / 255.0) - 1.0).astype(dtype)
         with annotate("features"):
             x4, x8, x16, x32 = self.feature(images)
             stem2 = self.stem_2(images)
             f4 = jnp.concatenate([x4, self.stem_4(stem2)], axis=-1)
             match = self.desc(self.conv(f4))
-        ml, mr = match[:b], match[b:]
         bins, groups = cfg.disp_bins, cfg.gwc_groups
         with annotate("gev"):
+            ml, mr = match[:b], match[b:]
             corr_lookup.log_path_once(
                 f"IGEV 3-D stage {jnp.dtype(dtype).name}: {bins} bins x "
                 f"{groups} groups at {ml.shape[1]}x{ml.shape[2]}, a pair at "
                 f"a time")
             disp, gev_pyr, vol_pyr = _over_pairs(IGEVStereo._volumes)(
                 self, ml, mr, f4[:b], x8[:b], x16[:b], x32[:b])
-        lookup = self._lookup(gev_pyr, vol_pyr)
+        with annotate("gru_iter"):
+            lookup = self._lookup(gev_pyr, vol_pyr)
 
         with annotate("cnet"):
             levels, _ = self.cnet(images[:b])
@@ -463,12 +465,13 @@ class IGEVStereo(nn.Module):
         scan = nn.scan(body, variable_broadcast=("params", "batch_stats"),
                        split_rngs={"params": False}, length=iters)
         h4, w4 = disp.shape[1:]
-        mask0 = jnp.zeros((b, h4, w4, 32), dtype)
-        (_, disp, mask), _ = scan(self, (tuple(net), disp, mask0), None)
+        with annotate("gru_iter"):
+            mask0 = jnp.zeros((b, h4, w4, 32), dtype)
+            (_, disp, mask), _ = scan(self, (tuple(net), disp, mask0), None)
         with annotate("upsample"):
             up = _over_pairs(IGEVStereo._upsample)(self, disp, mask,
                                                     stem2[:b])
-        return -disp, -up
+            return -disp, -up
 
     def _volumes(self, ml, mr, f4, f8, f16, f32):
         """One pair's steps 3-7: the group-wise volume, the 3-D stage, the

@@ -256,8 +256,9 @@ class RAFTStereo(nn.Module):
             raise ValueError("ctx_init/return_ctx are unsupported with "
                              "rows_gru (the sharded loop executor owns "
                              "its own context layout)")
-        image1 = (2 * (image1 / 255.0) - 1.0).astype(dtype)
-        image2 = (2 * (image2 / 255.0) - 1.0).astype(dtype)
+        with annotate("prep"):
+            image1 = (2 * (image1 / 255.0) - 1.0).astype(dtype)
+            image2 = (2 * (image2 / 255.0) - 1.0).astype(dtype)
 
         # Alternative executors for the encoders' full-resolution segment:
         # banded streams it (one-chip memory ceiling), rows-sharded splits
@@ -306,12 +307,12 @@ class RAFTStereo(nn.Module):
                     mvars.get("batch_stats", {}).get("trunk", {}),
                     x, norm_fn, dtype, mesh=rows_mesh, axis=rows_axis)
 
-        # Phase annotations (profiling.annotate = TraceAnnotation +
-        # jax.named_scope): device traces break out these phases (only
-        # Mosaic calls keep the scope's name on the TPU: PERF.md section 3).
+        # Layer scopes (profiling.annotate, profiling.LAYERS): every op
+        # staged below carries one in its HLO metadata, which the
+        # benchmark joins to the trace's operations (PERF.md section 3).
         if cfg.shared_backbone:
-            both = jnp.concatenate([image1, image2], axis=0)
             with annotate("cnet"):
+                both = jnp.concatenate([image1, image2], axis=0)
                 if custom_trunk is not None:
                     levels, v = self.cnet(
                         both, trunk_out=custom_trunk(self.cnet, both,
@@ -358,23 +359,26 @@ class RAFTStereo(nn.Module):
                 both = self.fnet(jnp.concatenate([image1, image2], axis=0))
                 fmap1, fmap2 = jnp.split(both, 2, axis=0)
 
-        if reuse_ctx:
-            # The per-session ctx cache: the GRU's initial hidden states
-            # and context biases come from an earlier frame's bundle —
-            # cnet and the context_zqr convs never run in this program.
-            net_list = [jnp.asarray(n).astype(dtype) for n in ctx_init[0]]
-            context = [tuple(jnp.asarray(c).astype(dtype) for c in cs)
-                       for cs in ctx_init[1]]
-        else:
-            # levels[l] = [hidden_head, context_head] at level l
-            # (fine→coarse)
-            net_list = [jnp.tanh(lv[0]) for lv in levels]
-            # Precompute GRU context biases cz, cr, cq once
-            # (reference: core/raft_stereo.py:87-88).
-            context = []
-            for l, lv in enumerate(levels):
-                biases = self.context_zqr_convs[l](nn.relu(lv[1]))
-                context.append(tuple(jnp.split(biases, 3, axis=-1)))
+        with annotate("cnet"):
+            if reuse_ctx:
+                # The per-session ctx cache: the GRU's initial hidden
+                # states and context biases come from an earlier frame's
+                # bundle — cnet and the context_zqr convs never run in
+                # this program.
+                net_list = [jnp.asarray(n).astype(dtype)
+                            for n in ctx_init[0]]
+                context = [tuple(jnp.asarray(c).astype(dtype) for c in cs)
+                           for cs in ctx_init[1]]
+            else:
+                # levels[l] = [hidden_head, context_head] at level l
+                # (fine→coarse)
+                net_list = [jnp.tanh(lv[0]) for lv in levels]
+                # Precompute GRU context biases cz, cr, cq once
+                # (reference: core/raft_stereo.py:87-88).
+                context = []
+                for l, lv in enumerate(levels):
+                    biases = self.context_zqr_convs[l](nn.relu(lv[1]))
+                    context.append(tuple(jnp.split(biases, 3, axis=-1)))
         # The carry-forward bundle: captured BEFORE the refinement loop
         # (the initial states, not the evolved ones) so a later frame
         # reusing it starts exactly where a cold frame would.
@@ -390,12 +394,15 @@ class RAFTStereo(nn.Module):
                 raise ValueError(
                     f"hidden_init carries {len(hidden_init)} levels, "
                     f"model has {len(net_list)} GRU levels")
-            net_list = [jnp.asarray(h).astype(dtype) for h in hidden_init]
+            with annotate("gru_iter"):
+                net_list = [jnp.asarray(h).astype(dtype)
+                            for h in hidden_init]
 
         b, h8, w8, _ = net_list[0].shape
-        disp = jnp.zeros((b, h8, w8), jnp.float32)
-        if flow_init is not None:
-            disp = disp + flow_init
+        with annotate("gru_iter"):
+            disp = jnp.zeros((b, h8, w8), jnp.float32)
+            if flow_init is not None:
+                disp = disp + flow_init
 
         if cfg.rows_gru and not self.is_initializing():
             # Context parallelism through the WHOLE refinement loop: the
@@ -413,7 +420,8 @@ class RAFTStereo(nn.Module):
 
         with annotate("corr_pyramid"):
             corr_fn = make_corr_fn(cfg, fmap1, fmap2)
-        grid_x = coords_grid_x(b, h8, w8, dtype=jnp.float32)
+        with annotate("gru_iter"):
+            grid_x = coords_grid_x(b, h8, w8, dtype=jnp.float32)
 
         n = cfg.n_gru_layers
 
@@ -456,26 +464,30 @@ class RAFTStereo(nn.Module):
         def hidden_tail(net_fin):
             return (tuple(net_fin),) if return_hidden else ()
 
+        # Each loop below runs under ``gru_iter`` with its set-up: the
+        # loop's own operations (the ``while``, its counter, the carries,
+        # in training the weight gradients it accumulates) are the loop's.
+        # The upsampling after it is not.
         if test_mode and unroll_gru:
-            mask = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
-            if return_confidence:
-                dmag = jnp.zeros((b, h8, w8), jnp.float32)
-                ewma = jnp.zeros((b, h8, w8), jnp.float32)
+            with annotate("gru_iter"):
+                mask = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
+                if return_confidence:
+                    dmag = jnp.zeros((b, h8, w8), jnp.float32)
+                    ewma = jnp.zeros((b, h8, w8), jnp.float32)
                 for _ in range(iters):
                     net_list, new_disp, mask = gru_step(self, net_list,
                                                         disp)
-                    dmag = jnp.abs(new_disp - disp)
-                    ewma = (CONFIDENCE_EWMA_DECAY * ewma
-                            + (1.0 - CONFIDENCE_EWMA_DECAY) * dmag)
+                    if return_confidence:
+                        dmag = jnp.abs(new_disp - disp)
+                        ewma = (CONFIDENCE_EWMA_DECAY * ewma
+                                + (1.0 - CONFIDENCE_EWMA_DECAY) * dmag)
                     disp = new_disp
-                flow_up = self._upsample(disp, mask)
+            flow_up = self._upsample(disp, mask)
+            if return_confidence:
                 conf = self._confidence_maps(dmag, ewma, mask,
                                              jnp.float32(1.0))
                 return ((disp, flow_up, conf)
                         + hidden_tail(net_list) + ctx_tail)
-            for _ in range(iters):
-                net_list, disp, mask = gru_step(self, net_list, disp)
-            flow_up = self._upsample(disp, mask)
             return (disp, flow_up) + hidden_tail(net_list) + ctx_tail
 
         if (test_mode and cfg.exit_threshold_px > 0
@@ -518,16 +530,18 @@ class RAFTStereo(nn.Module):
                     return (tuple(net_list), new_disp, up_mask,
                             it + jnp.int32(1), delta, dmag, ewma)
 
-                mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
-                zero_px = jnp.zeros((b, h8, w8), jnp.float32)
-                carry = (tuple(net_list), disp, mask0, jnp.int32(0),
-                         jnp.float32(jnp.inf), zero_px, zero_px)
-                (net_fin, disp_fin, mask_fin, iters_used, _delta,
-                 dmag_fin, ewma_fin) = (
-                    nn.while_loop(cond_exit_conf, body_exit_conf, self,
-                                  carry))
+                with annotate("gru_iter"):
+                    mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels),
+                                      dtype)
+                    zero_px = jnp.zeros((b, h8, w8), jnp.float32)
+                    carry = (tuple(net_list), disp, mask0, jnp.int32(0),
+                             jnp.float32(jnp.inf), zero_px, zero_px)
+                    (net_fin, disp_fin, mask_fin, iters_used, _delta,
+                     dmag_fin, ewma_fin) = (
+                        nn.while_loop(cond_exit_conf, body_exit_conf, self,
+                                      carry))
+                    depth_frac = iters_used.astype(jnp.float32) / limit
                 flow_up = self._upsample(disp_fin, mask_fin)
-                depth_frac = iters_used.astype(jnp.float32) / limit
                 conf = self._confidence_maps(dmag_fin, ewma_fin,
                                              mask_fin, depth_frac)
                 return ((disp_fin, flow_up, iters_used, conf)
@@ -551,11 +565,12 @@ class RAFTStereo(nn.Module):
                 return (tuple(net_list), new_disp, up_mask,
                         it + jnp.int32(1), delta)
 
-            mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
-            carry = (tuple(net_list), disp, mask0, jnp.int32(0),
-                     jnp.float32(jnp.inf))
-            (net_fin, disp_fin, mask_fin, iters_used, _delta) = (
-                nn.while_loop(cond_exit, body_exit, self, carry))
+            with annotate("gru_iter"):
+                mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
+                carry = (tuple(net_list), disp, mask0, jnp.int32(0),
+                         jnp.float32(jnp.inf))
+                (net_fin, disp_fin, mask_fin, iters_used, _delta) = (
+                    nn.while_loop(cond_exit, body_exit, self, carry))
             flow_up = self._upsample(disp_fin, mask_fin)
             return ((disp_fin, flow_up, iters_used)
                     + hidden_tail(net_fin) + ctx_tail)
@@ -584,11 +599,14 @@ class RAFTStereo(nn.Module):
                     body_test_conf,
                     variable_broadcast=("params", "batch_stats"),
                     split_rngs={"params": False}, length=iters)
-                mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
-                zero_px = jnp.zeros((b, h8, w8), jnp.float32)
-                (net_fin, disp_fin, mask_fin, dmag_fin, ewma_fin), _ = (
-                    scan_conf(self, (tuple(net_list), disp, mask0,
-                                     zero_px, zero_px), None))
+                with annotate("gru_iter"):
+                    mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels),
+                                      dtype)
+                    zero_px = jnp.zeros((b, h8, w8), jnp.float32)
+                    (net_fin, disp_fin, mask_fin, dmag_fin,
+                     ewma_fin), _ = scan_conf(
+                        self, (tuple(net_list), disp, mask0, zero_px,
+                               zero_px), None)
                 flow_up = self._upsample(disp_fin, mask_fin)
                 conf = self._confidence_maps(dmag_fin, ewma_fin,
                                              mask_fin, jnp.float32(1.0))
@@ -602,9 +620,10 @@ class RAFTStereo(nn.Module):
 
             scan_test = nn.scan(body_test, variable_broadcast=("params", "batch_stats"),
                                 split_rngs={"params": False}, length=iters)
-            mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
-            (net_fin, disp_fin, mask_fin), _ = scan_test(
-                self, (tuple(net_list), disp, mask0), None)
+            with annotate("gru_iter"):
+                mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
+                (net_fin, disp_fin, mask_fin), _ = scan_test(
+                    self, (tuple(net_list), disp, mask0), None)
             flow_up = self._upsample(disp_fin, mask_fin)
             return (disp_fin, flow_up) + hidden_tail(net_fin) + ctx_tail
 
@@ -632,8 +651,9 @@ class RAFTStereo(nn.Module):
                     *cfg.remat_save))
         scan_train = nn.scan(body_train, variable_broadcast=("params", "batch_stats"),
                              split_rngs={"params": False}, length=iters)
-        (net_fin, disp_fin), flow_ups = scan_train(
-            self, (tuple(net_list), disp), None)
+        with annotate("gru_iter"):
+            (net_fin, disp_fin), flow_ups = scan_train(
+                self, (tuple(net_list), disp), None)
         return flow_ups  # (iters, B, H, W)
 
     def _upsample(self, disp: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:

@@ -63,6 +63,26 @@ def trace(log_dir: str = "profiles", host_tracer_level: int = 2):
         jax.profiler.stop_trace()
 
 
+# The layer scopes of the device path, each one ``annotate`` name.  Every
+# operation the forwards and the training step stage with device cost sits
+# under one of them (tests/test_layer_scopes.py); the benchmark joins each
+# traced operation to its scope through the HLO the trace carries
+# (benchmark/layer_scopes.py, which keeps its own copy of this list).
+LAYERS = (
+    "prep",          # input normalisation (and photometric jitter) at the head
+    "fnet",          # RAFT-Stereo's feature encoder
+    "cnet",          # the context encoder, its tanh and context-bias convs
+    "features",      # IGEV-Stereo's MobileNetV2 trunk and match descriptor
+    "gev",           # IGEV-Stereo's 3-D stage: volume, hourglass, regression
+    "corr_pyramid",  # RAFT-Stereo's correlation volume and its pyramid
+    "gru_iter",      # the refinement loop: its set-up, loop and iterations
+    "upsample",      # the upsampling to full resolution after the loop
+    "confidence",    # the confidence maps (``return_confidence``)
+    "loss",          # the training step's sequence loss
+    "optimizer",     # gradient norm, clip, schedule and the AdamW update
+)
+
+
 @contextlib.contextmanager
 def annotate(name: str):
     """Named span context; nests.
@@ -70,10 +90,11 @@ def annotate(name: str):
     Two effects, one name: a host-timeline span (``TraceAnnotation``) for
     code that RUNS inside the block, and — because model code is traced,
     not run — an XLA op-name scope (``jax.named_scope``) so every op staged
-    out inside the block carries ``name/`` in its metadata.  Device traces
-    then break out the model's phases: it wraps
-    ``fnet``/``cnet``/``corr_pyramid``/``gru_iter``/``upsample``
-    (models/raft_stereo.py)."""
+    out inside the block carries ``name/`` in its metadata.  On the device
+    path the names are ``LAYERS``.  A scope never becomes the innermost one
+    of a Mosaic call it did not name before: the TPU names such a call after
+    its innermost scope (``%gru_iter.N``, ``%gru16.N``), and the
+    benchmark's kernel patterns match those names."""
     with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
         yield
 

@@ -5,8 +5,7 @@ See docs/architecture.md §Observability."""
 
 from raft_stereo_tpu.telemetry.costs import (DEVICE_PEAK_TFLOPS,
                                              CompileRecord, CompileRegistry,
-                                             MfuMeter, aot_cost_summary,
-                                             classify_bound,
+                                             MfuMeter, classify_bound,
                                              executable_cost,
                                              peak_bytes_per_s_for,
                                              peak_flops_for,
@@ -34,7 +33,7 @@ from raft_stereo_tpu.telemetry.watchdog import (ANOMALY_VERSION, AnomalySink,
 
 __all__ = [
     "DEVICE_PEAK_TFLOPS", "CompileRecord", "CompileRegistry", "MfuMeter",
-    "aot_cost_summary", "classify_bound", "executable_cost",
+    "classify_bound", "executable_cost",
     "peak_bytes_per_s_for", "peak_flops_for", "ridge_flops_per_byte",
     "SCHEMA_VERSION", "EventLog", "bench_record", "replay", "run_metadata",
     "write_record", "FlightRecorder", "dump_all_stacks",

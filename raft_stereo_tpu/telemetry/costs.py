@@ -231,28 +231,6 @@ def executable_cost(compiled) -> Dict[str, Any]:
     return out
 
 
-def aot_cost_summary(jitted, *args, **kwargs) -> Dict[str, Any]:
-    """One-shot helper: AOT-compile ``jitted`` for
-    ``args`` and return ``{flops, bytes_accessed, arithmetic_intensity,
-    compile_s, memory, degraded}`` — the cost denominator a record
-    carries next to its measured time (telemetry/events.py
-    ``bench_record(rec, cost=...)``).  ``{"degraded": True}`` alone when
-    even lowering fails."""
-    try:
-        t0 = time.perf_counter()
-        compiled = jitted.lower(*args, **kwargs).compile()
-        compile_s = time.perf_counter() - t0
-    except Exception:
-        log.warning("AOT lowering unavailable; bench record carries no "
-                    "cost denominator", exc_info=True)
-        return {"degraded": True}
-    out = executable_cost(compiled)
-    out["compile_s"] = round(compile_s, 4)
-    flops, ba = out.get("flops"), out.get("bytes_accessed")
-    out["arithmetic_intensity"] = (flops / ba if flops and ba else None)
-    return out
-
-
 # ----------------------------------------------------------------- registry
 class CompileRegistry:
     """Instruments every AOT compile it is handed: per-executable cost

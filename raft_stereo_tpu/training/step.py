@@ -24,6 +24,7 @@ from raft_stereo_tpu.data.device_jitter import (JitterParams,
                                                 params_for_datasets)
 from raft_stereo_tpu.parallel.data_sharded import data_sharding
 from raft_stereo_tpu.parallel.mesh import DATA_AXIS
+from raft_stereo_tpu.profiling import annotate
 from raft_stereo_tpu.training.anomaly import (SKIP_KEY, SKIP_NONFINITE_KEY,
                                               SKIP_SPIKE_KEY, AnomalyPolicy)
 from raft_stereo_tpu.training.loss import sequence_loss
@@ -52,43 +53,51 @@ def train_step(state: TrainState, batch: Dict[str, jnp.ndarray],
     # Tolerate states built without create_train_state (batch_stats=None).
     batch_stats = state.batch_stats if state.batch_stats is not None else {}
 
-    if jitter is not None:
-        key = jax.random.fold_in(jax.random.PRNGKey(jitter_seed), state.step)
-        img1, img2 = apply_photometric(batch["image1"], batch["image2"],
-                                       key, jitter)
-        batch = dict(batch, image1=img1, image2=img2)
+    with annotate("prep"):
+        if jitter is not None:
+            key = jax.random.fold_in(jax.random.PRNGKey(jitter_seed),
+                                     state.step)
+            img1, img2 = apply_photometric(batch["image1"], batch["image2"],
+                                           key, jitter)
+            batch = dict(batch, image1=img1, image2=img2)
 
-    # compact uploads arrive fp16/uint8; all loss math runs f32 on device
-    flow_gt = batch["flow"].astype(jnp.float32)
-    valid_gt = batch["valid"].astype(jnp.float32)
+        # compact uploads arrive fp16/uint8; all loss math runs f32 on
+        # device
+        flow_gt = batch["flow"].astype(jnp.float32)
+        valid_gt = batch["valid"].astype(jnp.float32)
 
     def loss_fn(params):
         preds = state.apply_fn(
             {"params": params, "batch_stats": batch_stats},
             batch["image1"], batch["image2"], iters=iters)
-        loss, metrics = sequence_loss(preds, flow_gt, valid_gt,
-                                      loss_gamma=loss_gamma, max_flow=max_flow)
-        if gru_telemetry and iters > 1:
-            # GRU convergence curve (TrainConfig.gru_telemetry): mean
-            # |disparity update| per refinement iteration, a (iters-1,)
-            # vector riding the metrics dict — fetched with the buffered
-            # drain, never a per-step sync.  stop_gradient: telemetry must
-            # not perturb the backward.
-            p = jax.lax.stop_gradient(preds)
-            metrics = dict(metrics, gru_delta_px=jnp.mean(
-                jnp.abs(p[1:] - p[:-1]), axis=(1, 2, 3)))
+        with annotate("loss"):
+            loss, metrics = sequence_loss(preds, flow_gt, valid_gt,
+                                          loss_gamma=loss_gamma,
+                                          max_flow=max_flow)
+            if gru_telemetry and iters > 1:
+                # GRU convergence curve (TrainConfig.gru_telemetry): mean
+                # |disparity update| per refinement iteration, a (iters-1,)
+                # vector riding the metrics dict — fetched with the
+                # buffered drain, never a per-step sync.  stop_gradient:
+                # telemetry must not perturb the backward.
+                p = jax.lax.stop_gradient(preds)
+                metrics = dict(metrics, gru_delta_px=jnp.mean(
+                    jnp.abs(p[1:] - p[:-1]), axis=(1, 2, 3)))
         return loss, metrics
 
     (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
         state.params)
-    new_state = state.apply_gradients(grads=grads)
-    # Global gradient norm rides the metrics dict: the optimizer computes
-    # the same reduction for clipping (XLA dedups it), it reaches the host
-    # through the existing buffered drain — no extra sync — and it is the
-    # grad half of the non-finite sentinel (telemetry/watchdog.py): a
-    # diverging run's grad_norm goes non-finite a window before the loss
-    # does when clipping masks the blow-up.
-    metrics = dict(metrics, loss=loss, grad_norm=optax.global_norm(grads))
+    with annotate("optimizer"):
+        new_state = state.apply_gradients(grads=grads)
+        # Global gradient norm rides the metrics dict: the optimizer
+        # computes the same reduction for clipping (XLA dedups it), it
+        # reaches the host through the existing buffered drain — no extra
+        # sync — and it is the grad half of the non-finite sentinel
+        # (telemetry/watchdog.py): a diverging run's grad_norm goes
+        # non-finite a window before the loss does when clipping masks the
+        # blow-up.
+        metrics = dict(metrics, loss=loss,
+                       grad_norm=optax.global_norm(grads))
     return new_state, metrics
 
 
@@ -116,34 +125,36 @@ def anomaly_train_step(state: TrainState, batch: Dict[str, jnp.ndarray],
     new_state, metrics = train_step(
         state, batch, iters=iters, loss_gamma=loss_gamma, max_flow=max_flow,
         jitter=jitter, jitter_seed=jitter_seed, gru_telemetry=gru_telemetry)
-    loss = metrics["loss"]
-    grad_norm = metrics["grad_norm"]
-    nonfinite = jnp.logical_not(jnp.logical_and(jnp.isfinite(loss),
-                                                jnp.isfinite(grad_norm)))
-    if policy.spike_factor > 0:
-        spike = jnp.logical_and(
-            jnp.logical_not(nonfinite),
-            jnp.logical_and(loss_ewma > 0,
-                            loss > loss_ewma * policy.spike_factor))
-    else:
-        spike = jnp.zeros((), jnp.bool_)
-    skip = jnp.logical_or(nonfinite, spike)
-    # where() selects, never mixes: a NaN in the discarded branch cannot
-    # leak (no arithmetic with it), and the kept branch is bit-identical
-    # to whichever state survives.
-    merged = jax.tree_util.tree_map(
-        lambda old, new: jnp.where(skip, old, new), state, new_state)
-    beta = policy.ewma_beta
-    updated_ewma = jnp.where(loss_ewma > 0,
-                             beta * loss_ewma + (1.0 - beta) * loss,
-                             loss)
-    new_ewma = jnp.where(skip, loss_ewma, updated_ewma)
-    f32 = jnp.float32
-    metrics = dict(metrics, **{
-        SKIP_KEY: skip.astype(f32),
-        SKIP_NONFINITE_KEY: nonfinite.astype(f32),
-        SKIP_SPIKE_KEY: spike.astype(f32)})
-    return merged, metrics, new_ewma
+    # the skip gate merges the update: the optimizer's, as a layer
+    with annotate("optimizer"):
+        loss = metrics["loss"]
+        grad_norm = metrics["grad_norm"]
+        nonfinite = jnp.logical_not(jnp.logical_and(jnp.isfinite(loss),
+                                                    jnp.isfinite(grad_norm)))
+        if policy.spike_factor > 0:
+            spike = jnp.logical_and(
+                jnp.logical_not(nonfinite),
+                jnp.logical_and(loss_ewma > 0,
+                                loss > loss_ewma * policy.spike_factor))
+        else:
+            spike = jnp.zeros((), jnp.bool_)
+        skip = jnp.logical_or(nonfinite, spike)
+        # where() selects, never mixes: a NaN in the discarded branch cannot
+        # leak (no arithmetic with it), and the kept branch is bit-identical
+        # to whichever state survives.
+        merged = jax.tree_util.tree_map(
+            lambda old, new: jnp.where(skip, old, new), state, new_state)
+        beta = policy.ewma_beta
+        updated_ewma = jnp.where(loss_ewma > 0,
+                                 beta * loss_ewma + (1.0 - beta) * loss,
+                                 loss)
+        new_ewma = jnp.where(skip, loss_ewma, updated_ewma)
+        f32 = jnp.float32
+        metrics = dict(metrics, **{
+            SKIP_KEY: skip.astype(f32),
+            SKIP_NONFINITE_KEY: nonfinite.astype(f32),
+            SKIP_SPIKE_KEY: spike.astype(f32)})
+        return merged, metrics, new_ewma
 
 
 def make_train_step(train_cfg: TrainConfig, mesh: Optional[Mesh] = None,

@@ -94,7 +94,7 @@ def test_the_cells_files_state_the_model_as_run(cell, model):
     assert {m["name"] for m in cell["per_layer"]} == {
         "step_mfu_pct.full", "gru_loop_share_pct.full",
         "encoder_share_pct.full", "corr_alt_roofline.full",
-        "device_idle_pct.full"}
+        "device_idle_pct.full", "fnet_cnet_share_pct.full"}
 
 
 def test_the_works_arithmetic_at_full_size(model):

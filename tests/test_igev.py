@@ -249,7 +249,7 @@ def test_the_cells_files_load_and_state_what_runs(cell):
     assert {m["name"] for m in cell["per_layer"]} == {
         "geo_lookup_roofline.igev", "gev_share_pct.igev",
         "gru_loop_share_pct.igev", "step_mfu_pct.igev",
-        "device_idle_pct.igev"}
+        "device_idle_pct.igev", "gev_stage_share_pct.igev"}
 
 
 def test_the_works_arithmetic_at_kitti_size(cell):

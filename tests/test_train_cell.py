@@ -40,7 +40,9 @@ def test_a_rehearsed_run_is_correct(capsys, one_weight_build, in_tmp_work,
     assert line["compared"]["steps_compared"]["value"] == 2
     if trace:
         assert {"step_mfu_pct.train", "device_idle_pct.train",
-                "data_wait_ms.train"} <= set(line["metrics"])
+                "data_wait_ms.train", "backward_share_pct.train",
+                "recompute_share_pct.train",
+                "optimizer_share_pct.train"} <= set(line["metrics"])
         assert 0 < line["device"]["busy_s"] < line["device"]["window_s"]
     else:
         assert set(line["metrics"]) == {"pairs_per_s", "setup_s"}

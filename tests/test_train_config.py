@@ -160,7 +160,9 @@ def test_the_cells_files_state_model_and_recipe_as_run(cell):
                                                        "setup_s"}
     assert {m["name"] for m in cell["per_layer"]} == {
         "step_mfu_pct.train", "device_idle_pct.train",
-        "data_wait_ms.train", "corr_lookup_bwd_roofline.train"}
+        "data_wait_ms.train", "corr_lookup_bwd_roofline.train",
+        "backward_share_pct.train", "recompute_share_pct.train",
+        "optimizer_share_pct.train"}
 
 
 def test_the_works_arithmetic_at_full_size(model):
