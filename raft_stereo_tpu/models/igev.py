@@ -335,10 +335,18 @@ class MotionEncoder(nn.Module):
 class UpdateBlock(nn.Module):
     """The source's ``BasicMultiUpdateBlock``: RAFT-Stereo's three coupled
     ConvGRUs (level 0 the finest: ``gru04`` at 1/4, ``gru08``, ``gru16``),
-    the disparity head and the 32 mask features at 1/4."""
+    the disparity head, and ``mask_features``: the 32 mask features at 1/4,
+    which only the upsampler after the loop reads."""
 
     config: IgevStereoConfig
     dtype: Optional[Any] = None
+
+    def setup(self):
+        self.mask_feat_4 = conv(32, 3, 1, dtype=self.dtype, name=None)
+
+    def mask_features(self, h):
+        """The upsampler's features of the final finest hidden state."""
+        return nn.relu(self.mask_feat_4(h))
 
     @nn.compact
     def __call__(self, net, context, corr, disp):
@@ -359,9 +367,7 @@ class UpdateBlock(nn.Module):
             net[0], context[0], *fine)
         delta = conv(1, 3, 1, dtype=d, name="disp_head_conv2")(nn.relu(
             conv(256, 3, 1, dtype=d, name="disp_head_conv1")(net[0])))
-        mask_feat = nn.relu(conv(32, 3, 1, dtype=d, name="mask_feat_4")(
-            net[0]))
-        return net, mask_feat, delta
+        return net, delta
 
 
 def _over_pairs(method):
@@ -454,21 +460,23 @@ class IGEVStereo(nn.Module):
                        for zqr, lv in zip(self.context_zqr_convs, levels)]
 
         def body(module, carry, _):
-            net, disp, _mask = carry
+            net, disp = carry
             with annotate("gru_iter"):
                 corr = lookup(lax.stop_gradient(disp)).astype(dtype)
-                net, mask, delta = module.update_block(
+                net, delta = module.update_block(
                     net, context, corr, disp[..., None].astype(dtype))
                 disp = disp + delta[..., 0].astype(f32)
-            return (tuple(net), disp, mask), None
+            return (tuple(net), disp), None
 
         scan = nn.scan(body, variable_broadcast=("params", "batch_stats"),
                        split_rngs={"params": False}, length=iters)
-        h4, w4 = disp.shape[1:]
         with annotate("gru_iter"):
-            mask0 = jnp.zeros((b, h4, w4, 32), dtype)
-            (_, disp, mask), _ = scan(self, (tuple(net), disp, mask0), None)
+            (net, disp), _ = scan(self, (tuple(net), disp), None)
+        if not self.is_initializing():
+            corr_lookup.log_path_once(f"upsampling mask: once after the "
+                                      f"loop ({iters} iterations)")
         with annotate("upsample"):
+            mask = self.update_block.mask_features(net[0])
             up = _over_pairs(IGEVStereo._upsample)(self, disp, mask,
                                                     stem2[:b])
             return -disp, -up

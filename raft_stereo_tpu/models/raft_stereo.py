@@ -425,12 +425,15 @@ class RAFTStereo(nn.Module):
 
         n = cfg.n_gru_layers
 
-        def gru_step(module, net_list, disp):
-            """One refinement iteration (reference: core/raft_stereo.py:108-123)."""
+        def gru_step(module, net_list, disp, mask=False):
+            """One refinement iteration (reference: core/raft_stereo.py:108-123):
+            ``(net_list, disp)``, and with ``mask`` the upsampling mask of
+            the new state last.  Only training reads every iteration's mask;
+            an inference loop takes ``final_mask`` of its last state."""
             with annotate("gru_iter"):
-                return _gru_step_body(module, net_list, disp)
+                return _gru_step_body(module, net_list, disp, mask)
 
-        def _gru_step_body(module, net_list, disp):
+        def _gru_step_body(module, net_list, disp, mask):
             disp = jax.lax.stop_gradient(disp)
             # Named so the remat policy below can SAVE this lookup's output:
             # the backward then reuses it instead of re-running the Pallas
@@ -450,14 +453,25 @@ class RAFTStereo(nn.Module):
                                                iter_fine=False,
                                                iter_coarse=(n == 3),
                                                update=False)
-            net_list, up_mask, delta_flow = module.update_block(
+            net_list, *up_mask, delta_flow = module.update_block(
                 net_list, context, corr, flow2,
-                iter_mid=(n >= 2), iter_coarse=(n == 3))
+                iter_mid=(n >= 2), iter_coarse=(n == 3), mask=mask)
 
             # Epipolar projection: only the x component updates
             # (reference: core/raft_stereo.py:120).
             disp = disp + delta_flow[..., 0].astype(jnp.float32)
-            return net_list, disp, up_mask
+            return (net_list, disp, *up_mask)
+
+        def final_mask(net_fin, loop):
+            """The upsampling mask of the loop's final state, computed once
+            after it: the inference loops carry no mask, since only the
+            last iteration's would be read."""
+            if not self.is_initializing():
+                from raft_stereo_tpu.kernels.corr_lookup import log_path_once
+                log_path_once(f"upsampling mask: once after the loop "
+                              f"({loop})")
+            with annotate("upsample"):
+                return self.update_block.mask_head(net_fin[0])
 
         ctx_tail = (ctx_out,) if return_ctx else ()
 
@@ -470,18 +484,17 @@ class RAFTStereo(nn.Module):
         # The upsampling after it is not.
         if test_mode and unroll_gru:
             with annotate("gru_iter"):
-                mask = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
                 if return_confidence:
                     dmag = jnp.zeros((b, h8, w8), jnp.float32)
                     ewma = jnp.zeros((b, h8, w8), jnp.float32)
                 for _ in range(iters):
-                    net_list, new_disp, mask = gru_step(self, net_list,
-                                                        disp)
+                    net_list, new_disp = gru_step(self, net_list, disp)
                     if return_confidence:
                         dmag = jnp.abs(new_disp - disp)
                         ewma = (CONFIDENCE_EWMA_DECAY * ewma
                                 + (1.0 - CONFIDENCE_EWMA_DECAY) * dmag)
                     disp = new_disp
+            mask = final_mask(net_list, f"{iters} iterations, unrolled")
             flow_up = self._upsample(disp, mask)
             if return_confidence:
                 conf = self._confidence_maps(dmag, ewma, mask,
@@ -506,6 +519,7 @@ class RAFTStereo(nn.Module):
                      else min(iters, cfg.exit_max_iters))
             min_iters = max(1, min(cfg.exit_min_iters, limit))
             threshold = jnp.float32(cfg.exit_threshold_px)
+            exit_loop = f"{min_iters} to {limit} iterations, exit-gated"
 
             if return_confidence:
                 # Confidence variant: the carry additionally tracks the
@@ -514,33 +528,31 @@ class RAFTStereo(nn.Module):
                 # decaying EWMA.  A distinct program by construction; the
                 # plain branch below stays bitwise-untouched.
                 def cond_exit_conf(module, carry):
-                    _net, _disp, _mask, it, delta, _dm, _ew = carry
+                    _net, _disp, it, delta, _dm, _ew = carry
                     return jnp.logical_or(
                         it < min_iters,
                         jnp.logical_and(it < limit, delta >= threshold))
 
                 def body_exit_conf(module, carry):
-                    net_list, disp, _mask, it, _delta, _dm, ewma = carry
-                    net_list, new_disp, up_mask = gru_step(
-                        module, list(net_list), disp)
+                    net_list, disp, it, _delta, _dm, ewma = carry
+                    net_list, new_disp = gru_step(module, list(net_list),
+                                                  disp)
                     dmag = jnp.abs(new_disp - disp).astype(jnp.float32)
                     delta = jnp.max(jnp.mean(dmag, axis=(1, 2)))
                     ewma = (CONFIDENCE_EWMA_DECAY * ewma
                             + (1.0 - CONFIDENCE_EWMA_DECAY) * dmag)
-                    return (tuple(net_list), new_disp, up_mask,
-                            it + jnp.int32(1), delta, dmag, ewma)
+                    return (tuple(net_list), new_disp, it + jnp.int32(1),
+                            delta, dmag, ewma)
 
                 with annotate("gru_iter"):
-                    mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels),
-                                      dtype)
                     zero_px = jnp.zeros((b, h8, w8), jnp.float32)
-                    carry = (tuple(net_list), disp, mask0, jnp.int32(0),
+                    carry = (tuple(net_list), disp, jnp.int32(0),
                              jnp.float32(jnp.inf), zero_px, zero_px)
-                    (net_fin, disp_fin, mask_fin, iters_used, _delta,
-                     dmag_fin, ewma_fin) = (
-                        nn.while_loop(cond_exit_conf, body_exit_conf, self,
-                                      carry))
+                    (net_fin, disp_fin, iters_used, _delta, dmag_fin,
+                     ewma_fin) = nn.while_loop(cond_exit_conf,
+                                               body_exit_conf, self, carry)
                     depth_frac = iters_used.astype(jnp.float32) / limit
+                mask_fin = final_mask(net_fin, exit_loop)
                 flow_up = self._upsample(disp_fin, mask_fin)
                 conf = self._confidence_maps(dmag_fin, ewma_fin,
                                              mask_fin, depth_frac)
@@ -548,37 +560,36 @@ class RAFTStereo(nn.Module):
                         + hidden_tail(net_fin) + ctx_tail)
 
             def cond_exit(module, carry):
-                _net, _disp, _mask, it, delta = carry
+                _net, _disp, it, delta = carry
                 return jnp.logical_or(
                     it < min_iters,
                     jnp.logical_and(it < limit, delta >= threshold))
 
             def body_exit(module, carry):
-                net_list, disp, _mask, it, _delta = carry
-                net_list, new_disp, up_mask = gru_step(module,
-                                                       list(net_list), disp)
+                net_list, disp, it, _delta = carry
+                net_list, new_disp = gru_step(module, list(net_list), disp)
                 # Mean update magnitude per image, worst over the batch.
                 # Feeds only the loop predicate — the disparity chain is
                 # the same op sequence the fixed-depth scan runs.
                 delta = jnp.max(jnp.mean(jnp.abs(new_disp - disp),
                                          axis=(1, 2)))
-                return (tuple(net_list), new_disp, up_mask,
-                        it + jnp.int32(1), delta)
+                return (tuple(net_list), new_disp, it + jnp.int32(1),
+                        delta)
 
             with annotate("gru_iter"):
-                mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
-                carry = (tuple(net_list), disp, mask0, jnp.int32(0),
+                carry = (tuple(net_list), disp, jnp.int32(0),
                          jnp.float32(jnp.inf))
-                (net_fin, disp_fin, mask_fin, iters_used, _delta) = (
-                    nn.while_loop(cond_exit, body_exit, self, carry))
+                (net_fin, disp_fin, iters_used, _delta) = nn.while_loop(
+                    cond_exit, body_exit, self, carry)
+            mask_fin = final_mask(net_fin, exit_loop)
             flow_up = self._upsample(disp_fin, mask_fin)
             return ((disp_fin, flow_up, iters_used)
                     + hidden_tail(net_fin) + ctx_tail)
 
         if test_mode:
-            # No per-iteration outputs needed; the scan carries state (plus
-            # the latest mask) and upsampling happens once at the end
-            # (reference skips intermediate upsampling in test mode —
+            # No per-iteration outputs needed; the scan carries state, and
+            # the mask and the upsampling run once at the end (reference
+            # skips intermediate upsampling in test mode —
             # core/raft_stereo.py:126-127).
             if return_confidence:
                 # Confidence variant of the fixed-depth scan: the carry
@@ -586,27 +597,23 @@ class RAFTStereo(nn.Module):
                 # its EWMA.  Fixed depth spends the whole budget, so the
                 # depth fraction is 1 by construction.
                 def body_test_conf(module, carry, _):
-                    net_list, disp, _mask, _dm, ewma = carry
-                    net_list, new_disp, up_mask = gru_step(module,
-                                                           net_list, disp)
+                    net_list, disp, _dm, ewma = carry
+                    net_list, new_disp = gru_step(module, net_list, disp)
                     dmag = jnp.abs(new_disp - disp).astype(jnp.float32)
                     ewma = (CONFIDENCE_EWMA_DECAY * ewma
                             + (1.0 - CONFIDENCE_EWMA_DECAY) * dmag)
-                    return (tuple(net_list), new_disp, up_mask,
-                            dmag, ewma), None
+                    return (tuple(net_list), new_disp, dmag, ewma), None
 
                 scan_conf = nn.scan(
                     body_test_conf,
                     variable_broadcast=("params", "batch_stats"),
                     split_rngs={"params": False}, length=iters)
                 with annotate("gru_iter"):
-                    mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels),
-                                      dtype)
                     zero_px = jnp.zeros((b, h8, w8), jnp.float32)
-                    (net_fin, disp_fin, mask_fin, dmag_fin,
-                     ewma_fin), _ = scan_conf(
-                        self, (tuple(net_list), disp, mask0, zero_px,
-                               zero_px), None)
+                    (net_fin, disp_fin, dmag_fin, ewma_fin), _ = scan_conf(
+                        self, (tuple(net_list), disp, zero_px, zero_px),
+                        None)
+                mask_fin = final_mask(net_fin, f"{iters} iterations")
                 flow_up = self._upsample(disp_fin, mask_fin)
                 conf = self._confidence_maps(dmag_fin, ewma_fin,
                                              mask_fin, jnp.float32(1.0))
@@ -614,22 +621,22 @@ class RAFTStereo(nn.Module):
                         + hidden_tail(net_fin) + ctx_tail)
 
             def body_test(module, carry, _):
-                net_list, disp, _mask = carry
-                net_list, disp, up_mask = gru_step(module, net_list, disp)
-                return (tuple(net_list), disp, up_mask), None
+                net_list, disp = gru_step(module, *carry)
+                return (tuple(net_list), disp), None
 
             scan_test = nn.scan(body_test, variable_broadcast=("params", "batch_stats"),
                                 split_rngs={"params": False}, length=iters)
             with annotate("gru_iter"):
-                mask0 = jnp.zeros((b, h8, w8, cfg.mask_channels), dtype)
-                (net_fin, disp_fin, mask_fin), _ = scan_test(
-                    self, (tuple(net_list), disp, mask0), None)
+                (net_fin, disp_fin), _ = scan_test(
+                    self, (tuple(net_list), disp), None)
+            mask_fin = final_mask(net_fin, f"{iters} iterations")
             flow_up = self._upsample(disp_fin, mask_fin)
             return (disp_fin, flow_up) + hidden_tail(net_fin) + ctx_tail
 
         def body_train(module, carry, _):
             net_list, disp = carry
-            net_list, disp, up_mask = gru_step(module, net_list, disp)
+            net_list, disp, up_mask = gru_step(module, net_list, disp,
+                                               mask=True)
             # Upsample inside the scan so per-iteration masks never
             # accumulate in HBM.
             flow_up = module._upsample(disp, up_mask)

@@ -162,13 +162,29 @@ class BasicMultiUpdateBlock(nn.Module):
     # whole-tensor ``interp_like``.  No effect on parameters.
     interp_fn: Optional[Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]] = None
 
+    def setup(self):
+        # The mask head is defined here, not inline, so that ``mask_head``
+        # can run it alone: an inference loop reads only the last
+        # iteration's mask, and computes it once, after the loop.
+        self.mask_conv1 = conv(256, 3, 1, dtype=self.dtype, name=None)
+        self.mask_conv2 = conv(self.config.mask_channels, 1, 1,
+                               dtype=self.dtype, name=None)
+
+    def mask_head(self, h: jnp.ndarray) -> jnp.ndarray:
+        """The convex-upsampling mask of a finest hidden state ``net[0]``,
+        scaled ×0.25 "to balance gradients" (core/update.py:136-137)."""
+        return 0.25 * self.mask_conv2(nn.relu(self.mask_conv1(h)))
+
     @nn.compact
     def __call__(self, net: Sequence[jnp.ndarray],
                  context: Sequence[Tuple[jnp.ndarray, ...]],
                  corr: Optional[jnp.ndarray] = None,
                  flow: Optional[jnp.ndarray] = None,
                  iter_fine: bool = True, iter_mid: bool = True,
-                 iter_coarse: bool = True, update: bool = True):
+                 iter_coarse: bool = True, update: bool = True,
+                 mask: bool = True):
+        """``(net, mask, delta_flow)``; ``(net, delta_flow)`` without
+        ``mask``; the GRUs' ``net`` alone without ``update``."""
         cfg = self.config
         hd = cfg.hidden_dims  # fine → coarse
         n = cfg.n_gru_layers
@@ -210,9 +226,6 @@ class BasicMultiUpdateBlock(nn.Module):
             return net
 
         delta_flow = FlowHead(256, 2, dtype=self.dtype, name="flow_head")(net[0])
-
-        # mask scaled ×0.25 "to balance gradients" (core/update.py:136-137)
-        m = nn.relu(conv(256, 3, 1, dtype=self.dtype, name="mask_conv1")(net[0]))
-        mask = 0.25 * conv(cfg.mask_channels, 1, 1, dtype=self.dtype,
-                           name="mask_conv2")(m)
-        return net, mask, delta_flow
+        if not mask:
+            return net, delta_flow
+        return net, self.mask_head(net[0]), delta_flow
